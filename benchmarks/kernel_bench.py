@@ -1035,9 +1035,9 @@ def tune_arm(
     ``layout=bcsr`` and the tuned plan must bill strictly fewer steps
     (and, recorded but not asserted: run faster).
 
-    **RadiX-net stack**: sized so the f32 fused panel (16 MiB at
+    **RadiX-net stack**: sized so the f32 fused panel (24 MiB at
     ``neurons=8192``) busts ``VMEM_SOFT_LIMIT_BYTES`` while the bf16
-    panel (8 MiB) fits — the tuned config moves the route from
+    panel (12 MiB) fits — the tuned config moves the route from
     fused-tiled back to resident fused. The resident plan is *built*
     for the route assertion but never executed here: interpret-mode
     compilation of the resident kernel at this size takes minutes,

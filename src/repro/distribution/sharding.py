@@ -73,7 +73,6 @@ def embed_lookup(table, ids):
     if not _ACTIVE:
         return table[ids]
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     mesh, rules = _ACTIVE[-1]
     tbl_spec = tuple(_resolve_spec(_TOP["embed"], table.shape, mesh, rules))
@@ -117,12 +116,12 @@ def embed_lookup(table, ids):
         rows = jnp.where(ok[..., None], rows, jnp.zeros((), rows.dtype))
         return jax.lax.psum(rows, axes)
 
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(*( (vocab_axes, tbl_spec[1]) )), P(*ids_spec)),
         out_specs=P(*(ids_spec + (tbl_spec[1],))),
-        check_rep=False,
+        check_vma=False,
     )(table, ids)
     # re-shard the rows onto the batch axes for the downstream layers
     final = tuple(_resolve_spec(("batch", "seq"), ids.shape, mesh, rules))

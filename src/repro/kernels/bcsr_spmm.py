@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import DEFAULT_BLOCK_N, _compat
+from repro.kernels import DEFAULT_BLOCK_N, prefetch_fits
 
 from repro.kernels.semirings import accumulate_tile, kernel_semiring
 from repro.sparse.bcsr import BlockCSRMatrix
@@ -60,6 +60,12 @@ Array = jax.Array
 def grid_steps(a: BlockCSRMatrix, n: int, block_n: int = DEFAULT_BLOCK_N) -> int:
     """Grid steps this kernel executes — ∝ stored blocks, not the ELL pad."""
     return a.total_blocks * -(-n // block_n)
+
+
+def smem_fits(a: BlockCSRMatrix) -> bool:
+    """Do the three (T,) prefetch tables (row, column, valid) fit SMEM?
+    That caps one call at 86016 stored blocks."""
+    return prefetch_fits(*[(a.total_blocks,)] * 3)
 
 
 def _kernel(
@@ -166,7 +172,7 @@ def bcsr_spmm(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

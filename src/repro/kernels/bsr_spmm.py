@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import DEFAULT_BLOCK_N, _compat
+from repro.kernels import DEFAULT_BLOCK_N, prefetch_fits
 
 from repro.kernels.semirings import accumulate_tile, kernel_semiring
 from repro.sparse.bsr import BlockSparseMatrix
@@ -50,6 +50,14 @@ def grid_steps(a: BlockSparseMatrix, n: int, block_n: int = DEFAULT_BLOCK_N) -> 
     weight's own layout."""
     nrb, mbpr = a.col_idx.shape
     return nrb * mbpr * (-(-n // block_n))
+
+
+def smem_fits(a: BlockSparseMatrix) -> bool:
+    """Do the (nrb, mbpr) column table and mask fit SMEM? Each pads to
+    (8, 128) tiles there, so with mbpr ≤ 128 every block-row costs
+    1 KiB: the ELL kernel stops at 1008 block-rows (16128 rows at
+    block 16)."""
+    return prefetch_fits(a.col_idx.shape, a.col_idx.shape)
 
 
 def _kernel(
@@ -143,7 +151,7 @@ def bsr_spmm(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -14,10 +14,16 @@ writes ``(l+1) % 2`` row-block by row-block, applying the per-layer
 ``max(W·Y + b, 0)`` epilogue in-register. Only y0 is read from HBM and
 only Y[L] is written back.
 
-VMEM budget: 2·m·block_n f32 panels + the streamed-in y0/out blocks +
-one (bs_r, block_n) accumulator — callers check
-:func:`fused_mlp_vmem_bytes` before dispatching (``repro.core.dnn``
-falls back to the layered path when the panel would not fit).
+VMEM budget: the 2 ping-pong panels + the y0 and out stripes, each
+double-buffered by the Pallas pipeline — 6 (m, block_n) stripes — plus
+small per-step tiles. Callers check :func:`fused_mlp_vmem_bytes` before
+dispatching (``repro.plan.routes`` falls back to the tiled or layered
+route when the panel would not fit).
+
+Scalar prefetch: both fused kernels take ONE flat int32 slot table of
+L·nrb·mbpr entries — the block column, or -1 for an ELL pad slot —
+because SMEM is 1 MiB and Mosaic pads a 3-D (L, nrb, mbpr) table to
+(8, 128) tiles. :func:`fused_prefetch_fits` is the route layer's check.
 
 Weights use the ELL layout (the stack shares one static
 ``max_blocks_per_row``); the occupancy-exact CSR grid and the resident
@@ -45,14 +51,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import DEFAULT_BLOCK_N, _compat
+from repro.kernels import DEFAULT_BLOCK_N, prefetch_fits
+from repro.kernels.semirings import mxu_dot
 
 from repro.sparse.bsr import BlockSparseMatrix
 
 Array = jax.Array
 
-# Stay well inside the ~16 MiB/core VMEM so the streamed blocks and
-# double-buffering slack fit alongside the resident panels.
+# Mosaic's default scoped-VMEM limit on v5e. The resident stripes get
+# VMEM_SOFT_LIMIT_BYTES of it; the rest holds the per-step weight, bias
+# and accumulator tiles.
+VMEM_SCOPED_LIMIT_BYTES = 16 * 1024 * 1024
 VMEM_SOFT_LIMIT_BYTES = 12 * 1024 * 1024
 
 
@@ -65,14 +74,15 @@ def _panel_np_dtype(panel_dtype) -> np.dtype:
 def fused_mlp_vmem_bytes(
     m: int, block_n: int = DEFAULT_BLOCK_N, panel_dtype=None
 ) -> int:
-    """Scratch bytes the resident panel needs (2 panels + in/out tiles).
+    """VMEM bytes of the resident kernel's (m, block_n) stripes, as
+    the compiler allocates them: the ping-pong ybuf pair, plus the y0
+    and out stripes, which the Pallas pipeline double-buffers.
 
-    All four (m, block_n) stripes — the ping-pong ybuf pair, the y0
-    stripe and the out stripe — are held in ``panel_dtype``, so bf16
-    panels halve this bill and move the resident↔tiled boundary
-    (accumulation stays f32 in a block-sized register tile)."""
+    All six are held in ``panel_dtype``, so bf16 panels halve this bill
+    and move the resident↔tiled boundary (accumulation stays f32 in a
+    block-sized register tile)."""
     panel = m * block_n * _panel_np_dtype(panel_dtype).itemsize
-    return 4 * panel  # ybuf×2 + y0 stripe + out stripe
+    return 6 * panel  # ybuf×2 + y0 stripe×2 + out stripe×2
 
 
 def fused_mlp_eligible(
@@ -88,20 +98,33 @@ def fused_mlp_eligible(
     return m == k and fused_mlp_vmem_bytes(m, block_n, panel_dtype) <= limit
 
 
+def fused_prefetch_fits(w: BlockSparseMatrix, n_layers: int) -> bool:
+    """Does an ``n_layers`` stack of ``w``-shaped layers fit the fused
+    kernels' flat slot table into SMEM?"""
+    nrb, mbpr = w.col_idx.shape[-2:]
+    return prefetch_fits((n_layers * nrb * mbpr,))
+
+
+def _slot_table(stacked_w: BlockSparseMatrix) -> Array:
+    """The flat (L·nrb·mbpr,) prefetch: block column, -1 on pad slots."""
+    valid = stacked_w.block_mask.astype(bool)
+    return jnp.where(valid, stacked_w.col_idx, -1).astype(jnp.int32).reshape(-1)
+
+
 def fused_mlp_tiled_eligible(
     w: BlockSparseMatrix, block_n: int = DEFAULT_BLOCK_N
 ) -> bool:
     """Square stack of ANY height — the tiled variant keeps the panel in
-    HBM scratch and holds only per-block tiles in VMEM, so there is no
-    panel-size ceiling. (Dispatch still prefers the fully resident kernel
+    HBM and holds only per-block tiles in VMEM, so there is no
+    panel-size ceiling (the slot table's SMEM bill is checked apart,
+    by :func:`fused_prefetch_fits`). (Dispatch still prefers the fully resident kernel
     whenever :func:`fused_mlp_eligible` says the panel fits.)"""
     m, k = w.shape
     return m == k
 
 
 def _kernel(
-    col_idx_ref,  # scalar-prefetch (L, nrb, mbpr) int32
-    mask_ref,  # scalar-prefetch (L, nrb, mbpr) int32
+    slot_ref,  # scalar-prefetch (L·nrb·mbpr,) int32 block column, -1 = pad
     blocks_ref,  # (1, 1, 1, bs_r, bs_c)
     y0_ref,  # (m, bn) — this j-stripe of the input panel
     bias_ref,  # (1, bs_r, 1)
@@ -110,6 +133,7 @@ def _kernel(
     acc_ref,  # VMEM scratch (bs_r, bn) f32
     *,
     n_layers: int,
+    n_row_blocks: int,
     t_steps: int,
     bs_r: int,
     bs_c: int,
@@ -118,6 +142,7 @@ def _kernel(
     l = pl.program_id(1)
     i = pl.program_id(2)
     t = pl.program_id(3)
+    c = slot_ref[(l * n_row_blocks + i) * t_steps + t]
 
     @pl.when((l == 0) & (i == 0) & (t == 0))
     def _load_input_panel():
@@ -127,12 +152,11 @@ def _kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(mask_ref[l, i, t] != 0)
+    @pl.when(c >= 0)
     def _accumulate():
         w = blocks_ref[0, 0, 0].astype(jnp.float32)
-        c = col_idx_ref[l, i, t]
         y = ybuf_ref[l % 2, pl.ds(c * bs_c, bs_c), :]
-        acc_ref[...] += jnp.dot(w, y, preferred_element_type=jnp.float32)
+        acc_ref[...] += mxu_dot(w, y)
 
     @pl.when(t == t_steps - 1)
     def _close_row_block():
@@ -183,29 +207,28 @@ def fused_mlp_forward(
     kernel = functools.partial(
         _kernel,
         n_layers=n_layers,
+        n_row_blocks=nrb,
         t_steps=mbpr,
         bs_r=bs_r,
         bs_c=bs_c,
         panel_dtype=pdt,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(n // block_n, n_layers, nrb, mbpr),
         in_specs=[
             # stored block (l, i, t)
             pl.BlockSpec(
                 (1, 1, 1, bs_r, bs_c),
-                lambda j, l, i, t, ci, mk: (l, i, t, 0, 0),
+                lambda j, l, i, t, sl: (l, i, t, 0, 0),
             ),
             # the full input column stripe for this j
-            pl.BlockSpec((m, block_n), lambda j, l, i, t, ci, mk: (0, j)),
+            pl.BlockSpec((m, block_n), lambda j, l, i, t, sl: (0, j)),
             # bias row-tile of layer l, row-block i
-            pl.BlockSpec(
-                (1, bs_r, 1), lambda j, l, i, t, ci, mk: (l, i, 0)
-            ),
+            pl.BlockSpec((1, bs_r, 1), lambda j, l, i, t, sl: (l, i, 0)),
         ],
         # the full output column stripe — written once per j, on layer L-1
-        out_specs=pl.BlockSpec((m, block_n), lambda j, l, i, t, ci, mk: (0, j)),
+        out_specs=pl.BlockSpec((m, block_n), lambda j, l, i, t, sl: (0, j)),
         scratch_shapes=[
             pltpu.VMEM((2, m, block_n), pdt),
             pltpu.VMEM((bs_r, block_n), jnp.float32),
@@ -215,18 +238,24 @@ def fused_mlp_forward(
         kernel,
         grid_spec=grid_spec,
         # bf16 panels: the streamed y0/out stripes are bf16 too (that is
-        # what makes the VMEM bill exactly 4 panels × itemsize); the
+        # what makes the VMEM bill exactly 6 panels × itemsize); the
         # wrapper casts back to out_dtype below.
         out_shape=jax.ShapeDtypeStruct(
             (m, n), out_dtype if default_panels else pdt
         ),
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
+            # a tuned budget past the soft limit asks the compiler for it
+            vmem_limit_bytes=max(
+                VMEM_SCOPED_LIMIT_BYTES,
+                fused_mlp_vmem_bytes(m, block_n, pdt)
+                + VMEM_SCOPED_LIMIT_BYTES
+                - VMEM_SOFT_LIMIT_BYTES,
+            ),
         ),
         interpret=interpret,
     )(
-        stacked_w.col_idx,
-        stacked_w.block_mask.astype(jnp.int32),
+        _slot_table(stacked_w),
         stacked_w.blocks,
         y0 if default_panels else y0.astype(pdt),
         stacked_b[:, :, None],
@@ -240,13 +269,12 @@ def fused_mlp_forward(
 
 
 def _tiled_kernel(
-    col_idx_ref,  # scalar-prefetch (L, nrb, mbpr) int32
-    mask_ref,  # scalar-prefetch (L, nrb, mbpr) int32
+    slot_ref,  # scalar-prefetch (L·nrb·mbpr,) int32 block column, -1 = pad
     blocks_ref,  # (1, 1, mbpr, bs_r, bs_c) — row-block i's stored blocks
     y0_ref,  # full (m, n) panel_dtype, HBM (never pulled into VMEM whole)
     bias_ref,  # (1, bs_r, 1)
     o_ref,  # full (m, n) panel_dtype, HBM
-    panel_ref,  # HBM scratch (2, m, bn) panel_dtype ping-pong activation panel
+    panel_ref,  # HBM (2, m, bn) panel_dtype ping-pong activation panel
     ybuf_ref,  # VMEM scratch (2, bs_c, bn) panel_dtype double-buffered gather
     acc_ref,  # VMEM scratch (bs_r, bn) f32
     vout_ref,  # VMEM scratch (bs_r, bn) panel_dtype outgoing row-block stage
@@ -255,6 +283,7 @@ def _tiled_kernel(
     out_sem,  # DMA semaphore: vout → panel/output
     *,
     n_layers: int,
+    n_row_blocks: int,
     t_steps: int,
     bs_r: int,
     bs_c: int,
@@ -264,6 +293,7 @@ def _tiled_kernel(
     l = pl.program_id(1)
     i = pl.program_id(2)
     src = l % 2  # panel slot layer l reads; (l+1)%2 == 1-src is written
+    row = (l * n_row_blocks + i) * t_steps
 
     @pl.when((l == 0) & (i == 0))
     def _stage_input_stripe():
@@ -277,7 +307,7 @@ def _tiled_kernel(
         cp.wait()
 
     def gather(t, slot):
-        c = col_idx_ref[l, i, t]
+        c = jnp.maximum(slot_ref[row + t], 0)  # pad slots gather block 0
         return pltpu.make_async_copy(
             panel_ref.at[src, pl.ds(c * bs_c, bs_c), :],
             ybuf_ref.at[slot],
@@ -296,12 +326,10 @@ def _tiled_kernel(
 
         gather(t, slot).wait()
 
-        @pl.when(mask_ref[l, i, t] != 0)
+        @pl.when(slot_ref[row + t] >= 0)
         def _accumulate():
             w = blocks_ref[0, 0, t].astype(jnp.float32)
-            acc_ref[...] += jnp.dot(
-                w, ybuf_ref[slot], preferred_element_type=jnp.float32
-            )
+            acc_ref[...] += mxu_dot(w, ybuf_ref[slot])
 
         return carry
 
@@ -345,7 +373,8 @@ def fused_mlp_tiled_forward(
 
     The resident kernel's (2, m, block_n) VMEM scratch caps m at
     ``VMEM_SOFT_LIMIT_BYTES``; past it this variant keeps the ping-pong
-    activation panel in **HBM scratch** and tiles the m dimension over
+    activation panel in **HBM** (an extra kernel output the wrapper
+    drops) and tiles the m dimension over
     the row-block grid: grid = (n_tiles, L, nrb) — each step DMAs the
     row's ≤ ``max_blocks_per_row`` input blocks into a double-buffered
     (bs_c, block_n) VMEM window (overlapping the gather of block t+1
@@ -376,28 +405,34 @@ def fused_mlp_tiled_forward(
     kernel = functools.partial(
         _tiled_kernel,
         n_layers=n_layers,
+        n_row_blocks=nrb,
         t_steps=mbpr,
         bs_r=bs_r,
         bs_c=bs_c,
         block_n=block_n,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(n // block_n, n_layers, nrb),
         in_specs=[
             # all stored blocks of (layer l, row-block i)
             pl.BlockSpec(
                 (1, 1, mbpr, bs_r, bs_c),
-                lambda j, l, i, ci, mk: (l, i, 0, 0, 0),
+                lambda j, l, i, sl: (l, i, 0, 0, 0),
             ),
             # the input panel stays in HBM; the kernel DMAs slices
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # bias row-tile of layer l, row-block i
-            pl.BlockSpec((1, bs_r, 1), lambda j, l, i, ci, mk: (l, i, 0)),
+            pl.BlockSpec((1, bs_r, 1), lambda j, l, i, sl: (l, i, 0)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        # Y[L], plus the ping-pong activation panel. Mosaic allocates
+        # scratch only in VMEM/SMEM, so the HBM panel is a second output
+        # that the wrapper drops.
+        out_specs=(
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ),
         scratch_shapes=[
-            pltpu.ANY((2, m, block_n), pdt),
             pltpu.VMEM((2, bs_c, block_n), pdt),
             pltpu.VMEM((bs_r, block_n), jnp.float32),
             pltpu.VMEM((bs_r, block_n), pdt),
@@ -406,19 +441,21 @@ def fused_mlp_tiled_forward(
             pltpu.SemaphoreType.DMA,
         ],
     )
-    out = pl.pallas_call(
+    out, _panel = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), pdt),
-        compiler_params=_compat.CompilerParams(
-            # The HBM panel scratch is shared across ALL grid steps —
+        out_shape=(
+            jax.ShapeDtypeStruct((m, n), pdt),
+            jax.ShapeDtypeStruct((2, m, block_n), pdt),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            # The HBM panel is shared across ALL grid steps —
             # even the j stripes must run sequentially on one core.
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
     )(
-        stacked_w.col_idx,
-        stacked_w.block_mask.astype(jnp.int32),
+        _slot_table(stacked_w),
         stacked_w.blocks,
         y0.astype(pdt),
         stacked_b[:, :, None],

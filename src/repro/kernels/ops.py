@@ -291,7 +291,7 @@ def fused_mlp_tiled_forward(
 
     The route for homogeneous square stacks whose activation panel
     exceeds ``VMEM_SOFT_LIMIT_BYTES``: the ping-pong panel lives in HBM
-    scratch and the m dimension is tiled over the row-block grid
+    (a second kernel output) and the m dimension is tiled over the row-block grid
     (``repro.kernels.fused_mlp.fused_mlp_tiled_forward``). Same
     forward-only contract as ``fused_mlp_forward``, including bf16
     activation panels via ``panel_dtype``.

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import DEFAULT_BLOCK_N, _compat
+from repro.kernels import DEFAULT_BLOCK_N
 from repro.kernels.semirings import accumulate_tile, kernel_semiring
 
 Array = jax.Array
@@ -120,7 +120,7 @@ def semiring_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -174,6 +174,17 @@ def vpu_tile_product(
     return jax.lax.fori_loop(0, n_chunks, body, acc)
 
 
+def mxu_dot(a: Array, b: Array) -> Array:
+    """``a @ b`` accumulated in f32. Two f32 operands multiply at full
+    f32 precision: Mosaic's default rounds them to bf16 on the MXU,
+    which on a v5e turned the 1040 RadiX-net 16384×120 categories of
+    2048 inputs into 1090. A bf16 operand (bf16 activation panels)
+    keeps the one-pass product."""
+    full = a.dtype == jnp.float32 and b.dtype == jnp.float32
+    precision = jax.lax.Precision.HIGHEST if full else jax.lax.Precision.DEFAULT
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=precision)
+
+
 def accumulate_tile(
     spec: KernelSemiring, a: Array, b: Array, acc: Array
 ) -> Array:
@@ -181,5 +192,5 @@ def accumulate_tile(
     broadcast for everything else. The shared inner reduce of all three
     kernels (dense / ELL / block-CSR)."""
     if spec.mxu:
-        return acc + jnp.dot(a, b, preferred_element_type=jnp.float32)
+        return acc + mxu_dot(a, b)
     return vpu_tile_product(spec, a, b, acc)

@@ -10,17 +10,33 @@ Axis semantics:
           crosses it (DCI traffic), never TP/EP collectives.
   data  — FSDP: batch + parameter/optimizer sharding (intra-pod ICI).
   model — TP/EP: heads, mlp, experts, vocab shards (intra-pod ICI).
+
+Every mesh here has **Auto** axes: ``jax.make_mesh`` defaults to
+Explicit axes, under which ``with_sharding_constraint`` rejects specs
+naming the mesh's axes and jit-level sharding propagation changes. The
+code in this repo places data with ``NamedSharding`` /
+``with_sharding_constraint`` and ``shard_map``, which is the Auto-axis
+contract.
 """
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(
@@ -30,7 +46,7 @@ def make_local_mesh(
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_row_blocks_mesh(shards: int | None = None) -> jax.sharding.Mesh:
@@ -44,4 +60,4 @@ def make_row_blocks_mesh(shards: int | None = None) -> jax.sharding.Mesh:
     """
     n = len(jax.devices())
     shards = n if shards is None else shards
-    return jax.make_mesh((shards,), ("row_blocks",))
+    return _auto_mesh((shards,), ("row_blocks",))

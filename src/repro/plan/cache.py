@@ -184,6 +184,10 @@ class PlanCache:
             if key.fingerprint == fingerprint
         }
 
+    def plans(self) -> list:
+        """The cached plans, least recently used first (no LRU touch)."""
+        return list(self._entries.values())
+
     def clear(self) -> None:
         self._entries.clear()
 

@@ -38,8 +38,14 @@ def preferred_layout(w: BlockSparseMatrix) -> str:
     """``"ell"`` or ``"bcsr"`` — which kernel grid wastes less work.
 
     Choose CSR once the pad's wasted fraction crosses
-    :data:`ELL_WASTE_THRESHOLD` (host-side: reads the mask).
+    :data:`ELL_WASTE_THRESHOLD` (host-side: reads the mask), or when the
+    ELL kernel's prefetch tables would not fit SMEM (the TPU compiler
+    refuses that kernel).
     """
+    from repro.kernels import bsr_spmm as _ell
+
+    if not _ell.smem_fits(w):
+        return "bcsr"
     nrb, mbpr = w.col_idx.shape
     # numpy, not w.nnz_blocks: a jnp reduction would turn into a tracer
     # inside a trace context even on a concrete (closed-over) mask,

@@ -4,19 +4,21 @@ One decision, made once per (topology, width-class, differentiable?)
 key at plan-build time and never re-derived per call:
 
     homogeneous square BSR stack AND not differentiable AND fused
-    allowed?
+    allowed AND the stack's slot table fits SMEM?
       └─ panel fits VMEM → **fused**: ONE VMEM-resident ``pallas_call``
                for the whole stack (``repro.kernels.fused_mlp``)
       └─ panel past ``VMEM_SOFT_LIMIT_BYTES`` → **fused-tiled**: still
                ONE ``pallas_call``, but the ping-pong activation panel
-               lives in HBM scratch and the m dimension is tiled over
+               lives in HBM and the m dimension is tiled over
                the row-block grid
                (``repro.kernels.fused_mlp.fused_mlp_tiled_forward``)
       └─ no  → per-layer dispatch, by execution layout:
                block-CSR → **kernel-bcsr** (occupancy-exact grid; the
                            differentiable backward reuses the plan's
                            cached transpose)
-               ELL-BSR   → **kernel-ell**
+               ELL-BSR   → **kernel-ell** (inference plans re-lay an ELL
+                           layer to block-CSR when its pad is wasteful
+                           or its prefetch tables overflow SMEM)
                dense     → **kernel-dense** (Pallas tiled matmul), or
                            **xla-dense** when the plan must stay
                            ``jax.grad``-compatible (the dense Pallas
@@ -31,7 +33,7 @@ from __future__ import annotations
 
 from typing import Sequence
 
-from repro.kernels import DEFAULT_BLOCK_N
+from repro.kernels import DEFAULT_BLOCK_N, SMEM_BYTES
 from repro.plan.layout import Weight, layer_layout
 from repro.sparse.bsr import BlockSparseMatrix
 
@@ -45,11 +47,15 @@ ROUTE_XLA = "xla"
 ROUTE_SHARDED = "sharded"
 
 
-def _homogeneous_bsr_stack(weights: Sequence[Weight]) -> bool:
+def _fusable_bsr_stack(weights: Sequence[Weight]) -> bool:
     """≥1 layer, all BSR with identical shape / block shape / pad width
-    — the structural precondition both fused kernels share.
+    — the structural precondition both fused kernels share — whose
+    whole-stack slot table fits SMEM (the TPU compiler refuses the fused
+    kernels otherwise: 4096 neurons × 120 layers needs 3.75 MiB).
     (BlockCSRMatrix stacks take the layered path — per-layer
     ``total_blocks`` varies, so there is no static stacked layout.)"""
+    from repro.kernels import fused_mlp as _fmlp
+
     if not weights:
         return False
     first = weights[0]
@@ -61,7 +67,7 @@ def _homogeneous_bsr_stack(weights: Sequence[Weight]) -> bool:
         and w.block_shape == first.block_shape
         and w.max_blocks_per_row == first.max_blocks_per_row
         for w in weights
-    )
+    ) and _fmlp.fused_prefetch_fits(first, len(weights))
 
 
 def resident_eligible(
@@ -73,16 +79,17 @@ def resident_eligible(
 ) -> bool:
     """Can this stack run through the single-call VMEM-resident kernel?
 
-    Requires: a homogeneous square BSR stack whose activation panel (at
-    this ``block_n`` and ``panel_dtype``) fits the VMEM budget. Stacks
-    past the budget are NOT resident-eligible but may still be
+    Requires: a homogeneous square BSR stack, its slot table within
+    SMEM, whose activation panel (at this ``block_n`` and
+    ``panel_dtype``) fits the VMEM budget. Stacks past the budget are
+    NOT resident-eligible but may still be
     ``fused-tiled``-eligible — :func:`fused_route` makes the three-way
     call. bf16 panels halve the panel bill, so the same stack can be
     resident under ``panel_dtype="bfloat16"`` and tiled under f32.
     """
     from repro.kernels import fused_mlp as _fmlp
 
-    if not _homogeneous_bsr_stack(weights):
+    if not _fusable_bsr_stack(weights):
         return False
     return _fmlp.fused_mlp_eligible(
         weights[0], block_n, panel_dtype=panel_dtype, vmem_limit=vmem_limit
@@ -100,9 +107,10 @@ def fused_route(
 
     ``ROUTE_FUSED`` when the activation panel fits VMEM
     (:func:`resident_eligible`), ``ROUTE_FUSED_TILED`` for a homogeneous
-    square BSR stack past the VMEM budget (panel ping-pongs through HBM
-    scratch, m tiled over the row-block grid), ``None`` when only the
-    per-layer routes apply. The boundary is exact:
+    square BSR stack past the VMEM budget (panel ping-pongs through HBM,
+    m tiled over the row-block grid), ``None`` when only the per-layer
+    routes apply — including every stack whose slot table overflows
+    SMEM. The boundary is exact:
     ``fused_mlp_vmem_bytes(m, block_n, panel_dtype) == vmem_limit``
     (default ``VMEM_SOFT_LIMIT_BYTES``) is the last resident m; one
     block-row more tips into fused-tiled. The autotuner moves this
@@ -111,7 +119,7 @@ def fused_route(
     """
     from repro.kernels import fused_mlp as _fmlp
 
-    if not _homogeneous_bsr_stack(weights):
+    if not _fusable_bsr_stack(weights):
         return None
     first = weights[0]
     if not _fmlp.fused_mlp_tiled_eligible(first, block_n):  # square check
@@ -124,10 +132,22 @@ def fused_route(
 
 
 def layer_path(w: Weight, *, differentiable: bool) -> str:
-    """The per-layer execution path for the layered route."""
+    """The per-layer execution path for the layered route.
+
+    Raises ValueError for a sparse layer whose kernel the TPU compiler
+    would refuse (its prefetch tables overflow SMEM) — there is no
+    kernel left to route it to."""
+    from repro.kernels import bcsr_spmm as _bcsr
+    from repro.kernels import bsr_spmm as _ell
+
     layout = layer_layout(w)
-    if layout == "bcsr":
-        return "kernel-bcsr"
-    if layout == "ell":
-        return "kernel-ell"
-    return "xla-dense" if differentiable else "kernel-dense"
+    if layout == "dense":
+        return "xla-dense" if differentiable else "kernel-dense"
+    fits = _bcsr.smem_fits(w) if layout == "bcsr" else _ell.smem_fits(w)
+    if not fits:
+        raise ValueError(
+            f"no kernel fits this {layout} layer: its scalar-prefetch "
+            f"tables overflow the {SMEM_BYTES // 1024} KiB SMEM "
+            f"(shape {w.shape}, block {w.block_shape})"
+        )
+    return "kernel-bcsr" if layout == "bcsr" else "kernel-ell"

@@ -222,6 +222,14 @@ class ShardedStackPlan:
         )
         return out[:, :k]
 
+    def lower(self, dtype=jnp.float32):
+        """The SPMD executable lowered for one panel of this width class,
+        ahead of time (same contract as ``StackPlan.lower``)."""
+        y = jax.ShapeDtypeStruct((self.weights[0].shape[1], self.width), dtype)
+        return self._fn.lower(
+            self.weights, self.transpose_plans, self.biases, y
+        )
+
     def forward_trainable(
         self,
         weights: Sequence[Weight],
@@ -284,8 +292,6 @@ def _make_sharded_body(plan: ShardedStackPlan) -> Callable:
     products over the shard axes → bias + ReLU post-collective. Weights
     ride as pytree arguments (training substitutes fresh values); the
     in_specs come from the ``repro.distribution.sharding`` rule table."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.distribution.sharding import sharded_csr_pspecs
     from repro.kernels import ops as kernel_ops
     from repro.sparse import ops as sparse_ops
@@ -344,7 +350,7 @@ def _make_sharded_body(plan: ShardedStackPlan) -> Callable:
             w_specs.append(P())
             tp_specs.append(None)
 
-    return shard_map(
+    return jax.shard_map(
         local_forward,
         mesh=mesh,
         in_specs=(
@@ -354,7 +360,7 @@ def _make_sharded_body(plan: ShardedStackPlan) -> Callable:
             P(),
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -385,7 +391,12 @@ def build_sharded_plan(
     per-shard transposes are shared by reference, only the bills and the
     executable are per-width (``PlanCache.get`` supplies this).
     """
-    from repro.distribution.sharding import mesh_shard_count, row_block_axes
+    from repro.distribution.sharding import (
+        mesh_shard_count,
+        row_block_axes,
+        sharded_csr_pspecs,
+        shardings_for,
+    )
 
     weights = tuple(weights)
     biases = tuple(biases)
@@ -419,6 +430,8 @@ def build_sharded_plan(
 
     layer_plans = []
     exec_weights = []
+    # one partition per weight object, however many layers repeat it
+    parts: dict[int, tuple] = {}
     for i, w in enumerate(weights):
         src_layout = _layout.layer_layout(w)
         if isinstance(w, BlockSparseMatrix) and differentiable:
@@ -435,6 +448,8 @@ def build_sharded_plan(
                 # shared by reference; only bills are per-width
                 dlp = donor.layers[i]
                 sharded, tp = dlp.sharded, dlp.transpose
+            elif id(w) in parts:
+                sharded, tp = parts[id(w)]
             else:
                 ew = (
                     BlockCSRMatrix.from_bsr(w)
@@ -442,11 +457,17 @@ def build_sharded_plan(
                     else w
                 )
                 sharded = partition_block_csr(ew, n_shards)
+                # placed on the mesh once, not re-sent with every panel
+                sharded = jax.device_put(
+                    sharded,
+                    shardings_for(None, mesh, sharded_csr_pspecs(sharded, mesh)),
+                )
                 tp = (
                     stack_transpose_plans(sharded)
                     if differentiable
                     else None
                 )
+                parts[id(w)] = (sharded, tp)
             bills = tuple(
                 _cost.layer_grid_steps(sharded.shard(s), width)
                 for s in range(n_shards)

@@ -233,6 +233,15 @@ class StackPlan:
             out = self._fn(self.weights, self.biases, y0)
         return out[:, :k]
 
+    def lower(self, dtype=jnp.float32):
+        """The executable lowered for one panel of this width class, ahead
+        of time: ``.compile().as_text()`` shows what the device runs
+        (``tpu_custom_call`` for each Pallas kernel)."""
+        y = jax.ShapeDtypeStruct((self.weights[0].shape[1], self.width), dtype)
+        if self.is_fused_route:
+            return self._fn.lower(self._stacked[0], self._stacked[1], y)
+        return self._fn.lower(self.weights, self.biases, y)
+
     def forward_trainable(
         self,
         weights: Sequence[Weight],
@@ -409,7 +418,7 @@ def build_plan(
 
     # fused_ok: which single-pallas_call route structurally fits —
     # ROUTE_FUSED (panel resident in VMEM), ROUTE_FUSED_TILED (panel
-    # past the VMEM budget, ping-ponged through HBM scratch), or None.
+    # past the VMEM budget, ping-ponged through HBM), or None.
     fused_ok = (
         None
         if differentiable
@@ -473,10 +482,16 @@ def build_plan(
         )
         exec_weights = []
         layer_plans = []
+        # A stack may repeat one weight object (RadiX-net cycles a few
+        # phase matrices through 120 layers): re-lay each object once,
+        # so the device holds one copy per object, not one per layer.
+        relaid: dict[int, Weight] = {}
         for i, w in enumerate(weights):
             src_layout = _layout.layer_layout(w)
             ew = w
-            if not fused_family and relayout:
+            if not fused_family and relayout and id(w) in relaid:
+                ew = relaid[id(w)]
+            elif not fused_family and relayout:
                 if t_layout is not None:
                     ew = _force_layout(w, t_layout)
                 else:
@@ -485,6 +500,7 @@ def build_plan(
                     bs = getattr(ew, "block_shape", (t_block_size,))[0]
                     if bs != t_block_size:
                         ew = _reblock(ew, t_block_size)
+                relaid[id(w)] = ew
             exec_layout = _layout.layer_layout(ew)
             path = (
                 route

@@ -50,6 +50,9 @@ class ChallengeResult:
     levels: tuple[str, ...]  # distinct ladder levels seen, in order
     width_classes: tuple[int, ...]  # distinct padded widths seen
     grid_steps: int  # summed kernel grid-step bill
+    warmup_seconds: float  # the untimed warmup panel: plan build + compile
+    panels: tuple[dict, ...]  # each timed panel's plan stats (route, level, ...)
+    engine: SparseDNNEngine = dataclasses.field(repr=False, compare=False)
 
     @property
     def edges(self) -> int:
@@ -86,7 +89,8 @@ def run_challenge(
     the driver builds one from :func:`repro.data.radixnet
     .radixnet_weights` with the given ``mesh``/``use_resident``.
     ``warmup`` runs one untimed panel of the same width class first so
-    the metric bills steady-state serving, not plan compilation.
+    the metric bills steady-state serving, not plan compilation; its
+    wall time (plan build, compile and one panel) is ``warmup_seconds``.
     ``tuning_table``/``panel_dtype`` thread straight into the default
     engine (``repro.tune``): a table hit on this spec's fingerprint —
     or an explicit bf16-panel override — retunes every panel's plan.
@@ -107,11 +111,13 @@ def run_challenge(
             spec.neurons, n_inputs, density=density, seed=seed
         )
     )
+    t0 = time.perf_counter()
     if warmup:
         engine.submit(panel[:, : min(panel_width, n_inputs)])
         out, _ = engine.step(pad_to=panel_width)
         if out is not None:
             jax.block_until_ready(out)
+    warmup_seconds = time.perf_counter() - t0
 
     active = np.zeros((n_inputs,), dtype=bool)
     step_stats: list[dict] = []
@@ -155,4 +161,7 @@ def run_challenge(
             s["padded_batch"] for s in step_stats
         ),
         grid_steps=grid_steps,
+        warmup_seconds=warmup_seconds,
+        panels=tuple(s["plan"] for s in step_stats),
+        engine=engine,
     )

@@ -251,9 +251,11 @@ class SparseDNNEngine:
             resident_ok if self.use_resident is None else self.use_resident
         )
         if self.validate:
+            checked: set[int] = set()  # layers may repeat one object
             for i, w in enumerate(self.weights):
-                if hasattr(w, "validate"):
+                if hasattr(w, "validate") and id(w) not in checked:
                     w.validate(name=f"SparseDNNEngine layer {i} weight")
+                    checked.add(id(w))
         if self.plan_cache is None:
             self.plan_cache = PlanCache(max_size=16)
         # The degradation ladder owns execution-level health: sharded →

@@ -96,6 +96,22 @@ def test_weights_stack_is_homogeneous_and_fused_eligible():
     assert len(bs) == 5 and bs[0].shape == (256,)
 
 
+def test_reference_matches_the_gather_definition():
+    """The reference's sparse product, run in column chunks on a thread
+    pool, is bit-identical to the plain per-layer gather definition
+    (same terms, added in ``conn`` order, in float32)."""
+    spec = rx.RadixNetSpec(256, 7)
+    y0 = rx.radixnet_input_panel(256, 300, density=0.3, seed=4)  # 3 chunks
+    conns = [rx.radixnet_connectivity(256, l) for l in range(spec.layers)]
+    biases = [spec.bias] * spec.layers
+    whole = rx.reference_forward(conns, biases, y0)
+    y = y0
+    for conn, b in zip(conns, biases):
+        z = (y[conn] * np.float32(rx.WEIGHT_VALUE)).sum(axis=1, dtype=np.float32)
+        y = np.maximum(z + np.float32(b), np.float32(0.0))
+    np.testing.assert_array_equal(whole, y)
+
+
 def test_input_panel_is_seeded_and_sparse():
     a = rx.radixnet_input_panel(256, 40, density=0.3, seed=7)
     b = rx.radixnet_input_panel(256, 40, density=0.3, seed=7)
@@ -162,6 +178,16 @@ def test_challenge_driver_small():
     assert res.edges == spec.edges
     assert res.edge_inputs_per_sec > 0
     assert res.grid_steps > 0
+    # per-panel plan stats, the warmup time, and the serving engine
+    assert [(p["route"], p["level"]) for p in res.panels] == [
+        ("fused", "resident")
+    ] * 3
+    assert not any(p["degraded"] for p in res.panels)
+    assert res.warmup_seconds > 0
+    assert not res.engine.ladder.events
+    (plan,) = res.engine.plan_cache.plans()
+    assert plan.width == 24 and plan.route == P.ROUTE_FUSED
+    assert plan.lower().compile() is not None
 
 
 def test_challenge_driver_layered_and_failure():

@@ -83,9 +83,9 @@ _SUBPROC = textwrap.dedent(
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.distribution import sharding as sh
     from repro.distribution.collectives import compressed_psum_mean
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import make_local_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_local_mesh(4, 2)
 
     # --- embed_lookup == table[ids] under sharding -----------------------
     key = jax.random.key(0)
@@ -101,7 +101,7 @@ _SUBPROC = textwrap.dedent(
     print("embed_lookup OK")
 
     # --- compressed psum: int8 error feedback ----------------------------
-    gmesh = jax.make_mesh((8,), ("data",))
+    gmesh = make_local_mesh(8)
     x = jax.random.normal(jax.random.key(2), (8, 256))
 
     def body(xl, el):
@@ -109,7 +109,7 @@ _SUBPROC = textwrap.dedent(
         return m[None], e[None]
 
     with gmesh:
-        mfn = shard_map(
+        mfn = jax.shard_map(
             body, mesh=gmesh,
             in_specs=(P("data", None), P("data", None)),
             out_specs=(P("data", None), P("data", None)),

@@ -215,12 +215,29 @@ def test_plan_forward_pads_to_width_class_and_rejects_overflow():
         plan.forward(jnp.zeros((32, 17)))
 
 
+def test_repeated_layer_objects_are_relaid_once():
+    """RadiX-net repeats its phase matrices through the stack: the plan
+    re-lays each distinct weight object once and reuses it, so the device
+    holds one execution copy per object, not one per layer."""
+    from repro.data import radixnet as rx
+
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 6))
+    assert len({id(w) for w in ws}) == 2
+    plan = P.build_plan(ws, bs, 16, use_resident=False)
+    assert plan.layouts == ("bcsr", "ell") * 3
+    assert len({id(w) for w in plan.weights}) == 2
+    assert all(plan.weights[i] is plan.weights[i % 2] for i in range(6))
+
+
 def test_vmem_boundary_tips_fused_into_tiled_exactly():
     """Regression for the route boundary: the last m whose activation
     panel exactly fills ``VMEM_SOFT_LIMIT_BYTES`` still takes the
     resident fused route; ONE block-row more must tip into fused-tiled
     (never layered). Asserted through the plan layer's decision tree,
-    not the kernel."""
+    not the kernel. The panel bill counts the six (m, block_n) stripes
+    the v5e compiler allocates, so the boundary sits at m = 4096 (at
+    6144, where four stripes put it, the compiler refused the resident
+    kernel: 18 MiB scoped VMEM against a 16 MiB limit)."""
     from repro.kernels.fused_mlp import (
         VMEM_SOFT_LIMIT_BYTES,
         fused_mlp_vmem_bytes,

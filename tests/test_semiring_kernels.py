@@ -168,6 +168,29 @@ def test_unknown_semiring_fails_fast():
         kernel_ops.bsr_spmm(a, b, semiring_name="no_such_algebra")
 
 
+@pytest.mark.parametrize(
+    "b_dtype,precision",
+    [(jnp.float32, "HIGHEST"), (jnp.bfloat16, "DEFAULT")],
+    ids=["f32", "bf16-panel"],
+)
+def test_mxu_dot_precision(b_dtype, precision):
+    """The MXU product asks for full f32 precision whenever both
+    operands are f32, whatever the process-wide matmul precision: the
+    kernel default rounds f32 operands to bf16, which changed RadiX-net
+    categories on a v5e. A bf16 panel keeps the one-pass product."""
+    from repro.kernels.semirings import mxu_dot
+
+    with jax.default_matmul_precision("bfloat16"):
+        jaxpr = jax.make_jaxpr(mxu_dot)(
+            jnp.zeros((16, 16), jnp.float32), jnp.zeros((16, 128), b_dtype)
+        )
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert eqn.params["precision"] == (
+        getattr(jax.lax.Precision, precision),
+    ) * 2
+    assert eqn.params["preferred_element_type"] == jnp.float32
+
+
 # --- graphblas façade routing -------------------------------------------
 
 

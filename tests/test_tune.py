@@ -236,8 +236,8 @@ class TestPlanIntegration:
         )
 
     def test_bf16_moves_resident_boundary_at_plan_layer(self):
-        # fused_mlp_vmem_bytes(8192, 128, f32) = 16 MiB > the 12 MiB
-        # soft limit → fused-tiled; bf16 halves it to 8 MiB → fused.
+        # fused_mlp_vmem_bytes(8192, 128, f32) = 24 MiB > the 12 MiB
+        # soft limit → fused-tiled; bf16 halves it to 12 MiB → fused.
         # Probe with route logic only (no 8192-wide build): the plan
         # layer's fused_route is the decision the builder obeys.
         from repro.kernels.fused_mlp import (
