@@ -170,6 +170,7 @@ def bcsr_spmm(
     )
     return pl.pallas_call(
         kernel,
+        name="bcsr_spmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=pltpu.CompilerParams(

@@ -149,6 +149,7 @@ def bsr_spmm(
     )
     return pl.pallas_call(
         kernel,
+        name="bsr_spmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=pltpu.CompilerParams(

@@ -236,6 +236,7 @@ def fused_mlp_forward(
     )
     out = pl.pallas_call(
         kernel,
+        name="fused_mlp_forward",
         grid_spec=grid_spec,
         # bf16 panels: the streamed y0/out stripes are bf16 too (that is
         # what makes the VMEM bill exactly 6 panels × itemsize); the
@@ -443,6 +444,7 @@ def fused_mlp_tiled_forward(
     )
     out, _panel = pl.pallas_call(
         kernel,
+        name="fused_mlp_tiled_forward",
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((m, n), pdt),

@@ -111,6 +111,7 @@ def semiring_matmul(
     )
     return pl.pallas_call(
         kernel,
+        name="semiring_matmul",
         grid=(m // block_m, n // block_n, k_steps),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),

@@ -18,6 +18,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Sequence
 
+from repro import spans
 from repro.plan import sharded as _sharded
 from repro.plan.layout import Weight
 from repro.plan.stack_plan import (
@@ -139,29 +140,31 @@ class PlanCache:
             ):
                 donor = cand
                 break
-        if mesh is not None:
-            plan = _sharded.build_sharded_plan(
-                weights,
-                biases,
-                width,
-                mesh,
-                differentiable=differentiable,
-                use_resident=use_resident,
-                fingerprint=fingerprint,
-                donor=donor,
-            )
-        else:
-            plan = build_plan(
-                weights,
-                biases,
-                width,
-                differentiable=differentiable,
-                use_resident=use_resident,
-                relayout=relayout,
-                fingerprint=fingerprint,
-                donor=donor,
-                tuned=tuned,
-            )
+        with spans.span("plan.build", width=width) as s:
+            if mesh is not None:
+                plan = _sharded.build_sharded_plan(
+                    weights,
+                    biases,
+                    width,
+                    mesh,
+                    differentiable=differentiable,
+                    use_resident=use_resident,
+                    fingerprint=fingerprint,
+                    donor=donor,
+                )
+            else:
+                plan = build_plan(
+                    weights,
+                    biases,
+                    width,
+                    differentiable=differentiable,
+                    use_resident=use_resident,
+                    relayout=relayout,
+                    fingerprint=fingerprint,
+                    donor=donor,
+                    tuned=tuned,
+                )
+            s.set(route=plan.route)
         self.builds += 1
         self._entries[key] = plan
         self._entries.move_to_end(key)
@@ -169,20 +172,6 @@ class PlanCache:
             self._entries.popitem(last=False)
             self.evictions += 1
         return plan
-
-    def compiled_widths(self, fingerprint: str) -> set[int]:
-        """Width classes this cache already holds a plan for, for one
-        topology fingerprint. The fleet router's affinity signal
-        (``repro.serve.fleet``): a replica whose cache lists a request's
-        width class serves it without a fresh compile, so routing by
-        this set keeps the fleet-wide hit rate at single-engine levels.
-        Cheap (walks the ≤ max_size entries; no building, no LRU
-        touch)."""
-        return {
-            key.width
-            for key in self._entries
-            if key.fingerprint == fingerprint
-        }
 
     def plans(self) -> list:
         """The cached plans, least recently used first (no LRU touch)."""

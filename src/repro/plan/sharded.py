@@ -111,7 +111,6 @@ class ShardedStackPlan:
     _body: Callable | None = None  # un-jitted shard_map'd forward
     _fn: Callable | None = None  # jitted serving executable
     _compiles: int = 0
-    calls: int = 0
 
     # StackPlan-compatible surface ------------------------------------
     route: str = _routes.ROUTE_SHARDED
@@ -196,7 +195,6 @@ class ShardedStackPlan:
             "shard_pad_blocks": self.shard_pad_blocks(),
             "pallas_calls": self.pallas_calls,
             "compiles": self.compile_count,
-            "calls": self.calls,
         }
 
     # ------------------------------------------------------------------
@@ -216,7 +214,6 @@ class ShardedStackPlan:
             )
         if k < self.width:
             y0 = jnp.pad(y0, ((0, 0), (0, self.width - k)))
-        self.calls += 1
         out = self._fn(
             self.weights, self.transpose_plans, self.biases, y0
         )

@@ -157,7 +157,6 @@ class StackPlan:
     _stacked: tuple | None = None  # (stacked_w, stacked_b) for fused
     _fn: Callable | None = None
     _compiles: int = 0
-    calls: int = 0
 
     @property
     def n_layers(self) -> int:
@@ -206,7 +205,6 @@ class StackPlan:
                 1 for lp in self.layers if lp.transpose_plan is not None
             ),
             "compiles": self.compile_count,
-            "calls": self.calls,
             "tuned": self.key.tuned,
         }
 
@@ -226,7 +224,6 @@ class StackPlan:
             )
         if k < self.width:
             y0 = jnp.pad(y0, ((0, 0), (0, self.width - k)))
-        self.calls += 1
         if self.is_fused_route:
             out = self._fn(self._stacked[0], self._stacked[1], y0)
         else:

@@ -38,6 +38,12 @@ layouts, grid-step bill, and the jitted executable are all amortized
 across requests. ``step(pad_to=...)`` lets a scheduler quantize panel
 widths to a small set of classes so a handful of compiled plans serve
 every panel (``ContinuousBatcher(width_classes=...)``).
+
+While ``jax.profiler`` traces, ``step`` and the plan cache open host
+spans (``repro.spans``): ``engine.step`` around a dispatching step, and
+inside it ``engine.stage``, ``engine.plan`` (``plan.build`` on a miss),
+``engine.dispatch`` and ``engine.finite_sync`` — see ``docs/serving.md``,
+"Tracing".
 """
 
 from __future__ import annotations
@@ -49,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import dnn
 from repro.models.model import Model
 from repro.plan import DegradationLadder, PlanCache, topology_fingerprint
@@ -296,15 +303,18 @@ class SparseDNNEngine:
         compatibility under ``differentiable=True`` via the XLA form);
         the ladder only decides WHICH level of them to serve at when the
         mesh or the resident path is marked unhealthy."""
-        return self._ladder.get_plan(
-            tuple(self.weights),
-            tuple(self.biases),
-            width,
-            differentiable=self.differentiable,
-            fingerprint=self._fingerprint,
-            step=step,
-            compile_hook=compile_hook,
-        )
+        with spans.span("engine.plan", width=width) as s:
+            plan, level, hit = self._ladder.get_plan(
+                tuple(self.weights),
+                tuple(self.biases),
+                width,
+                differentiable=self.differentiable,
+                fingerprint=self._fingerprint,
+                step=step,
+                compile_hook=compile_hook,
+            )
+            s.set(level=level, hit=hit)
+        return plan, level, hit
 
     # ------------------------------------------------------------------
     # step-level API (driven by serve.scheduler.ContinuousBatcher)
@@ -384,6 +394,18 @@ class SparseDNNEngine:
         )
         if batch == 0:
             return None, self._idle_stats()
+        width = batch + (-batch) % self.batch_align
+        if pad_to is not None:
+            width = max(width, pad_to + (-pad_to) % self.batch_align)
+        ordinal = self._dispatches
+        self._dispatches += 1
+        with spans.span(
+            "engine.step", ordinal=ordinal, batch=batch, width=width
+        ):
+            return self._dispatch(batch, width, ordinal)
+
+    def _pop_staged(self, batch: int) -> list[tuple[list, Array]]:
+        """The chunks holding the first ``batch`` staged columns, FIFO."""
         need = batch
         take: list[tuple[list, Array]] = []
         while need:
@@ -397,18 +419,22 @@ class SparseDNNEngine:
                 self._staged[0] = (rids[need:], arr[:, need:])
                 need = 0
         self._staged_count -= batch
-        ids = [rid for rids, _ in take for rid in rids]
-        width = batch + (-batch) % self.batch_align
-        if pad_to is not None:
-            width = max(width, pad_to + (-pad_to) % self.batch_align)
-        yp = (
-            take[0][1]
-            if len(take) == 1
-            else jnp.concatenate([arr for _, arr in take], axis=1)
-        )
+        return take
+
+    def _dispatch(
+        self, batch: int, width: int, ordinal: int
+    ) -> tuple[Array | None, dict]:
+        """The body of ``step`` once the panel's width is known."""
+        with spans.span("engine.stage") as s:
+            take = self._pop_staged(batch)
+            s.set(chunks=len(take))
+            ids = [rid for rids, _ in take for rid in rids]
+            yp = (
+                take[0][1]
+                if len(take) == 1
+                else jnp.concatenate([arr for _, arr in take], axis=1)
+            )
         # ---- fault sites (docs/robustness.md), keyed by dispatch ordinal
-        ordinal = self._dispatches
-        self._dispatches += 1
         inj = self.fault_injector
         compile_spec = transient_spec = None
         if inj is not None:
@@ -455,7 +481,10 @@ class SparseDNNEngine:
                     raise _faults.TransientFault(
                         "injected transient step failure"
                     )
-                out = plan.forward(yp)
+                with spans.span("engine.dispatch") as s:
+                    compiles = plan.compile_count
+                    out = plan.forward(yp)
+                    s.set(compiled=plan.compile_count > compiles)
                 break
             except _faults.TransientFault as e:
                 last_err = e
@@ -485,9 +514,14 @@ class SparseDNNEngine:
         self._steps += 1
         res = out[:, :batch]
         quarantined: list = []
-        if self.quarantine_nonfinite and not bool(jnp.isfinite(res).all()):
-            col_ok = np.asarray(jnp.isfinite(res).all(axis=0))
-            quarantined = [ids[j] for j in range(batch) if not col_ok[j]]
+        if self.quarantine_nonfinite:
+            # The host waits here for the device to finish the panel.
+            with spans.span("engine.finite_sync"):
+                if not bool(jnp.isfinite(res).all()):
+                    col_ok = np.asarray(jnp.isfinite(res).all(axis=0))
+                    quarantined = [
+                        ids[j] for j in range(batch) if not col_ok[j]
+                    ]
         plan_stats = {
             "width_class": width,
             "cache_hit": cache_hit,

@@ -79,11 +79,11 @@ def test_single_pallas_call():
         return y
 
     # (the jitted wrapper dedups the shared kernel jaxpr, so count the
-    # per-layer call sites rather than the pallas_call primitive itself;
-    # the word boundary skips the shared binding's inner
-    # ``name=bsr_spmm_diff``)
+    # per-layer ``jit`` call sites rather than the pallas_call primitive
+    # itself, whose own ``name=bsr_spmm`` sits in the shared binding; the
+    # word boundary skips its inner ``name=bsr_spmm_diff``)
     jaxpr_layered = jax.make_jaxpr(layered)(ws, bs, y0)
-    assert len(re.findall(r"name=bsr_spmm\b", str(jaxpr_layered))) == L
+    assert len(re.findall(r"jit\[name=bsr_spmm\b", str(jaxpr_layered))) == L
 
 
 def test_relu_and_sparsity_semantics():
