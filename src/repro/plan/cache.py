@@ -164,7 +164,10 @@ class PlanCache:
                     donor=donor,
                     tuned=tuned,
                 )
-            s.set(route=plan.route)
+            s.set(
+                route=plan.route,
+                component_layers=0 if mesh is not None else plan.component_layers,
+            )
         self.builds += 1
         self._entries[key] = plan
         self._entries.move_to_end(key)

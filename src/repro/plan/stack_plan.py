@@ -9,7 +9,9 @@ key (the GraphChallenge amortization pattern: the topology is fixed,
 the per-topology analysis should be too) and carries:
 
 * the chosen layout per layer (the ELL-pad waste heuristic of
-  ``repro.plan.layout``, applied at build time instead of per call);
+  ``repro.plan.layout``, applied at build time instead of per call, or
+  the component layout of ``repro.plan.components`` with the row
+  gathers that carry activations between its row and column orders);
 * the route — fused / layered / XLA fallback (``repro.plan.routes``);
 * the exact grid-step bill for the plan's panel width
   (``repro.plan.cost``);
@@ -34,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import DEFAULT_BLOCK_N
+from repro.plan import components as _components
 from repro.plan import cost as _cost
 from repro.plan import layout as _layout
 from repro.plan import routes as _routes
@@ -125,10 +128,13 @@ class LayerPlan:
 
     index: int
     source_layout: str  # layout of the caller's weight ("dense"/"ell"/"bcsr")
-    layout: str  # execution layout after the waste heuristic
+    layout: str  # execution layout: "dense"/"ell"/"bcsr"/"component"
     path: str  # routes.layer_path value, or "fused"/"fused-tiled"
     grid_steps: int  # exact bill at the plan's width
     transpose_plan: BcsrTransposePlan | None  # cached backward transpose
+    # Row gather into this layer's column order, applied to the held
+    # activations before the layer (None: they are in order already).
+    gather: Array | None = None
 
 
 @dataclasses.dataclass
@@ -154,6 +160,9 @@ class StackPlan:
     source_weights: tuple  # caller's objects — cache identity check
     source_biases: tuple
     tuned: object | None = None  # the TunedConfig the plan was built under
+    # Row gather back to natural order after the last layer (None: the
+    # last layer's rows are in natural order).
+    out_gather: Array | None = None
     _stacked: tuple | None = None  # (stacked_w, stacked_b) for fused
     _fn: Callable | None = None
     _compiles: int = 0
@@ -190,6 +199,16 @@ class StackPlan:
     def transpose_plans(self) -> tuple[BcsrTransposePlan | None, ...]:
         return tuple(lp.transpose_plan for lp in self.layers)
 
+    @property
+    def component_layers(self) -> int:
+        """Layers that run in the component layout."""
+        return sum(1 for lp in self.layers if lp.layout == "component")
+
+    @property
+    def gathers(self) -> tuple[Array | None, ...]:
+        """The executable's row gathers: one per layer, then the last."""
+        return tuple(lp.gather for lp in self.layers) + (self.out_gather,)
+
     def describe(self) -> dict:
         """JSON-ready summary (docs/architecture.md shows one)."""
         return {
@@ -199,6 +218,7 @@ class StackPlan:
             "route": self.route,
             "layouts": list(self.layouts),
             "paths": [lp.path for lp in self.layers],
+            "component_layers": self.component_layers,
             "grid_steps": self.grid_steps,
             "pallas_calls": self.pallas_calls,
             "cached_transposes": sum(
@@ -224,20 +244,20 @@ class StackPlan:
             )
         if k < self.width:
             y0 = jnp.pad(y0, ((0, 0), (0, self.width - k)))
+        return self._fn(*self._bound(), y0)[:, :k]
+
+    def _bound(self) -> tuple:
+        """The executable's arguments besides the panel."""
         if self.is_fused_route:
-            out = self._fn(self._stacked[0], self._stacked[1], y0)
-        else:
-            out = self._fn(self.weights, self.biases, y0)
-        return out[:, :k]
+            return self._stacked
+        return self.weights, self.biases, self.gathers
 
     def lower(self, dtype=jnp.float32):
         """The executable lowered for one panel of this width class, ahead
         of time: ``.compile().as_text()`` shows what the device runs
         (``tpu_custom_call`` for each Pallas kernel)."""
         y = jax.ShapeDtypeStruct((self.weights[0].shape[1], self.width), dtype)
-        if self.is_fused_route:
-            return self._fn.lower(self._stacked[0], self._stacked[1], y)
-        return self._fn.lower(self.weights, self.biases, y)
+        return self._fn.lower(*self._bound(), y)
 
     def forward_trainable(
         self,
@@ -306,9 +326,15 @@ def _make_executable(plan: StackPlan) -> Callable:
     paths = tuple(lp.path for lp in plan.layers)
     tps = plan.transpose_plans
 
-    def run_layered(weights, biases, y):
+    def rows(y, g):
+        if g is None:
+            return y
+        return y.at[g].get(mode="promise_in_bounds", unique_indices=True)
+
+    def run_layered(weights, biases, gathers, y):
         plan._compiles += 1
-        for path, tp, w, b in zip(paths, tps, weights, biases):
+        for path, tp, g, w, b in zip(paths, tps, gathers, weights, biases):
+            y = rows(y, g)
             if path == "kernel-bcsr":
                 y = kernel_ops.bcsr_spmm(
                     w, y, b, tp, fuse_bias_relu=True, block_n=block_n
@@ -323,7 +349,7 @@ def _make_executable(plan: StackPlan) -> Callable:
                 )
             else:  # xla-dense: grad-compatible fused XLA form
                 y = sparse_ops.dense_matmul_fused_relu(w, y, b)
-        return y
+        return rows(y, gathers[-1])
 
     return jax.jit(run_layered)
 
@@ -358,6 +384,37 @@ def _force_layout(w: Weight, layout: str) -> Weight:
     return w
 
 
+def _row_gathers(comps: Sequence) -> tuple[list, Array | None]:
+    """The row gathers of a layered stack, from each layer's
+    :class:`~repro.plan.components.ComponentLayout` (None for a layer in
+    natural order): one before each layer, then one back to natural
+    order. A component layer leaves its activations in its row order
+    ``R``; the next layer reads them in its column order ``C``, so its
+    gather is ``R⁻¹[C]``. Identity gathers are None, and equal ones are
+    one device array."""
+    made: dict[bytes, Array] = {}
+
+    def gather(held, want):
+        if held is None and want is None:
+            return None
+        idx = np.arange(want.size) if held is None else np.argsort(held)
+        if want is not None:
+            idx = idx[want]
+        if np.array_equal(idx, np.arange(idx.size)):
+            return None
+        key = idx.astype(np.int32).tobytes()
+        if key not in made:
+            made[key] = jnp.asarray(idx, jnp.int32)
+        return made[key]
+
+    held = None  # the row order the activations are held in
+    gathers = []
+    for comp in comps:
+        gathers.append(gather(held, None if comp is None else comp.cols))
+        held = None if comp is None else comp.rows
+    return gathers, gather(held, None)
+
+
 def build_plan(
     weights: Sequence[Weight],
     biases: Sequence[Array],
@@ -375,17 +432,19 @@ def build_plan(
     ``use_resident``: None auto-detects fused eligibility, True demands
     it (ValueError when ineligible), False forces the layered route —
     the ``SparseDNNEngine`` tri-state, verbatim. ``relayout`` applies
-    the ELL→CSR waste heuristic to the bound execution weights; default
-    on for inference plans, always off for differentiable plans (their
+    the component layout (``repro.plan.components``) or else the
+    ELL→CSR waste heuristic to the bound execution weights; default on
+    for inference plans, always off for differentiable plans (their
     cotangents must mirror the caller's layout).
 
     ``donor``: an existing plan for the SAME stack (same fingerprint,
     differentiability, and residency request) at a different width
     class. Only the width-dependent pieces (grid-step bill, executable)
     are rebuilt; the width-independent topology artifacts — relayouted
-    execution weights, cached transposes (so the topology is still
-    sorted exactly once no matter how many width classes serve it), and
-    the fused weight stack — are shared by reference.
+    execution weights and biases, row gathers, cached transposes (so
+    the topology is still sorted exactly once no matter how many width
+    classes serve it), and the fused weight stack — are shared by
+    reference.
     ``PlanCache.get`` supplies this automatically.
 
     ``tuned``: a :class:`repro.tune.TunedConfig` (duck-typed — the plan
@@ -393,7 +452,8 @@ def build_plan(
     defaults: ``block_n`` feeds every kernel call and the grid bill,
     ``panel_dtype``/``vmem_limit_bytes`` move the resident↔tiled
     boundary, ``layout`` overrides the ELL-waste heuristic, and
-    ``block_size`` re-blocks layered execution weights. The config's
+    ``block_size`` re-blocks layered execution weights; either of the
+    last two keeps the component layout off. The config's
     token lands in :attr:`PlanKey.tuned` so tuned and untuned plans
     never collide in a :class:`~repro.plan.PlanCache`.
     """
@@ -463,6 +523,8 @@ def build_plan(
             )
         route = donor.route
         exec_weights = list(donor.weights)
+        exec_biases = donor.biases
+        out_gather = donor.out_gather
         layer_plans = [
             dataclasses.replace(
                 lp,
@@ -478,18 +540,24 @@ def build_plan(
             _routes.ROUTE_FUSED_TILED,
         )
         exec_weights = []
+        exec_biases = []
+        comps = []  # each layer's ComponentLayout, or None
         layer_plans = []
         # A stack may repeat one weight object (RadiX-net cycles a few
         # phase matrices through 120 layers): re-lay each object once,
         # so the device holds one copy per object, not one per layer.
-        relaid: dict[int, Weight] = {}
-        for i, w in enumerate(weights):
+        relaid: dict[int, tuple] = {}
+        row_biases: dict[tuple[int, int], Array] = {}
+        for i, (w, b) in enumerate(zip(weights, biases)):
             src_layout = _layout.layer_layout(w)
-            ew = w
-            if not fused_family and relayout and id(w) in relaid:
-                ew = relaid[id(w)]
-            elif not fused_family and relayout:
-                if t_layout is not None:
+            ew, comp = w, None
+            if not fused_family and relayout and id(w) not in relaid:
+                if t_layout is None and t_block_size is None:
+                    # A tuned layout or block size wins over it.
+                    comp = _components.component_layout(w)
+                if comp is not None:
+                    ew = comp.weight
+                elif t_layout is not None:
                     ew = _force_layout(w, t_layout)
                 else:
                     ew = _layout.to_preferred_layout(w)
@@ -497,8 +565,19 @@ def build_plan(
                     bs = getattr(ew, "block_shape", (t_block_size,))[0]
                     if bs != t_block_size:
                         ew = _reblock(ew, t_block_size)
-                relaid[id(w)] = ew
-            exec_layout = _layout.layer_layout(ew)
+                relaid[id(w)] = (ew, comp)
+            if not fused_family and relayout:
+                ew, comp = relaid[id(w)]
+            if comp is not None:
+                # The bias follows the layer's rows: b[R].
+                if (id(w), id(b)) not in row_biases:
+                    row_biases[id(w), id(b)] = jnp.asarray(
+                        np.asarray(jax.device_get(b))[comp.rows]
+                    )
+                b = row_biases[id(w), id(b)]
+            exec_layout = (
+                "component" if comp is not None else _layout.layer_layout(ew)
+            )
             path = (
                 route
                 if fused_family
@@ -511,6 +590,8 @@ def build_plan(
                 # donor sharing — reuses this plan's permutation.
                 tp = ew.transpose_plan()
             exec_weights.append(ew)
+            exec_biases.append(b)
+            comps.append(comp)
             layer_plans.append(
                 LayerPlan(
                     index=i,
@@ -523,6 +604,11 @@ def build_plan(
                     transpose_plan=tp,
                 )
             )
+        layer_gathers, out_gather = _row_gathers(comps)
+        layer_plans = [
+            dataclasses.replace(lp, gather=g)
+            for lp, g in zip(layer_plans, layer_gathers)
+        ]
         if route == _routes.ROUTE_LAYERED and all(
             lp.path == "xla-dense" for lp in layer_plans
         ):
@@ -538,10 +624,11 @@ def build_plan(
         differentiable=differentiable,
         grid_steps=sum(lp.grid_steps for lp in layer_plans),
         weights=tuple(exec_weights),
-        biases=biases,
+        biases=tuple(exec_biases),
         source_weights=weights,
         source_biases=biases,
         tuned=tuned,
+        out_gather=out_gather,
     )
     if plan.is_fused_route:
         if donor is not None:

@@ -224,7 +224,7 @@ def test_repeated_layer_objects_are_relaid_once():
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 6))
     assert len({id(w) for w in ws}) == 2
     plan = P.build_plan(ws, bs, 16, use_resident=False)
-    assert plan.layouts == ("bcsr", "ell") * 3
+    assert plan.layouts == ("component",) * 6
     assert len({id(w) for w in plan.weights}) == 2
     assert all(plan.weights[i] is plan.weights[i % 2] for i in range(6))
 

@@ -179,36 +179,53 @@ def test_semiring_matmul_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_radix_16384x120_plan_layer_kernels_compile(one_chip):
+def test_radix_16384x120_plan_layer_kernels_compile(one_chip, monkeypatch):
     """The official 16384-neuron × 120-layer stack: the engine's plan
     routes it layered on block-CSR kernels (the fused slot table and the
     ELL prefetch tables overflow SMEM), re-lays each of the three phase
-    matrices once, and every distinct layer kernel compiles."""
+    matrices once in the component layout (512 blocks of 1024×32: 32
+    whole 32×32 components a block-row, one entry per row a block), and
+    the layer kernel and the whole executable, gathers included,
+    compile."""
     spec = rx.RadixNetSpec(16384, 120)
     ws, bs = rx.radixnet_weights(spec)
     assert P.fused_route(ws) is None
     plan = P.build_plan(ws, bs, WIDTH)
     assert plan.route == P.ROUTE_LAYERED
     assert {lp.path for lp in plan.layers} == {"kernel-bcsr"}
+    assert plan.component_layers == 120
     distinct = list({id(w): w for w in plan.weights}.values())
     assert len(distinct) == rx.num_phases(16384) == 3
+    assert all(
+        w.total_blocks == 512 and w.block_shape == (1024, 32) for w in distinct
+    )
     fn = jax.jit(
         lambda w, y, b: kernel_ops.bcsr_spmm(
             w, y, b, fuse_bias_relu=True, interpret=False
         )
     )
-    for w in distinct:
-        shapes = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), w)
-        text = (
-            fn.lower(
-                shapes,
-                _s(one_chip, (16384, WIDTH)),
-                _s(one_chip, (16384,)),
-            )
-            .compile()
-            .as_text()
+    shapes = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), distinct[0])
+    text = (
+        fn.lower(
+            shapes,
+            _s(one_chip, (16384, WIDTH)),
+            _s(one_chip, (16384,)),
         )
-        assert "tpu_custom_call" in text, w.total_blocks
+        .compile()
+        .as_text()
+    )
+    assert "tpu_custom_call" in text
+    # the executable calls the kernels with the backend's own choice
+    monkeypatch.setattr(kernel_ops, "auto_interpret", lambda: False)
+    bound = jax.tree.map(
+        lambda a: _s(one_chip, a.shape, a.dtype),
+        (plan.weights, plan.biases, plan.gathers),
+    )
+    y = _s(one_chip, (16384, WIDTH))
+    text = plan._fn.lower(*bound, y).compile().as_text()
+    jax.clear_caches()  # drop the kernels traced for the chip
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 120
+    assert "gather" in text
 
 
 # ---------------------------------------------------------------------
