@@ -167,6 +167,9 @@ class PlanCache:
             s.set(
                 route=plan.route,
                 component_layers=0 if mesh is not None else plan.component_layers,
+                weight_args=(
+                    len(plan.weights) if mesh is not None else plan.weight_args
+                ),
             )
         self.builds += 1
         self._entries[key] = plan

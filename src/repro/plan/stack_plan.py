@@ -164,6 +164,8 @@ class StackPlan:
     # last layer's rows are in natural order).
     out_gather: Array | None = None
     _stacked: tuple | None = None  # (stacked_w, stacked_b) for fused
+    # The layered executable's (weights, biases, gathers): each object once
+    _args: tuple | None = None
     _fn: Callable | None = None
     _compiles: int = 0
 
@@ -203,6 +205,12 @@ class StackPlan:
     def component_layers(self) -> int:
         """Layers that run in the component layout."""
         return sum(1 for lp in self.layers if lp.layout == "component")
+
+    @property
+    def weight_args(self) -> int:
+        """Weight arrays the executable takes: one stacked weight on a
+        fused route, else each distinct layer weight once."""
+        return 1 if self.is_fused_route else len(self._args[0])
 
     @property
     def gathers(self) -> tuple[Array | None, ...]:
@@ -250,7 +258,7 @@ class StackPlan:
         """The executable's arguments besides the panel."""
         if self.is_fused_route:
             return self._stacked
-        return self.weights, self.biases, self.gathers
+        return self._args
 
     def lower(self, dtype=jnp.float32):
         """The executable lowered for one panel of this width class, ahead
@@ -325,6 +333,14 @@ def _make_executable(plan: StackPlan) -> Callable:
 
     paths = tuple(lp.path for lp in plan.layers)
     tps = plan.transpose_plans
+    # A stack that repeats a few phase weights through its layers passes
+    # each object once, and each layer reads its own by index: the
+    # compiler counts every argument in device memory, and 120 copies of
+    # 65536-neuron phase weights (30 GB) would not fit one chip.
+    (ws, w_at), (bs, b_at), (gs, g_at) = (
+        _distinct(x) for x in (plan.weights, plan.biases, plan.gathers)
+    )
+    plan._args = (ws, bs, gs)
 
     def rows(y, g):
         if g is None:
@@ -333,8 +349,9 @@ def _make_executable(plan: StackPlan) -> Callable:
 
     def run_layered(weights, biases, gathers, y):
         plan._compiles += 1
-        for path, tp, g, w, b in zip(paths, tps, gathers, weights, biases):
-            y = rows(y, g)
+        for i, (path, tp) in enumerate(zip(paths, tps)):
+            y = rows(y, gathers[g_at[i]])
+            w, b = weights[w_at[i]], biases[b_at[i]]
             if path == "kernel-bcsr":
                 y = kernel_ops.bcsr_spmm(
                     w, y, b, tp, fuse_bias_relu=True, block_n=block_n
@@ -349,9 +366,17 @@ def _make_executable(plan: StackPlan) -> Callable:
                 )
             else:  # xla-dense: grad-compatible fused XLA form
                 y = sparse_ops.dense_matmul_fused_relu(w, y, b)
-        return rows(y, gathers[-1])
+        return rows(y, gathers[g_at[-1]])
 
     return jax.jit(run_layered)
+
+
+def _distinct(items: Sequence) -> tuple[tuple, tuple[int, ...]]:
+    """The distinct objects of ``items`` by identity, in first-seen
+    order, and the index of each item among them."""
+    seen: dict[int, tuple[int, object]] = {}
+    at = tuple(seen.setdefault(id(x), (len(seen), x))[0] for x in items)
+    return tuple(x for _, x in seen.values()), at
 
 
 def _tuned_attr(tuned, name: str):

@@ -45,8 +45,10 @@ def _block_diagonal(sizes, seed=0, m=None):
 
 @pytest.mark.parametrize(
     "neurons,phase",
-    [(1024, 0), (1024, 1), (16384, 0), (16384, 1), (16384, 2)],
-    ids=["1024-p0", "1024-p1", "16384-p0", "16384-p1", "16384-p2"],
+    [(1024, 0), (1024, 1), (16384, 0), (16384, 1), (16384, 2),
+     (65536, 0), (65536, 1), (65536, 2), (65536, 3)],
+    ids=["1024-p0", "1024-p1", "16384-p0", "16384-p1", "16384-p2",
+         "65536-p0", "65536-p1", "65536-p2", "65536-p3"],
 )
 def test_each_radixnet_phase_splits_into_complete_32x32_components(neurons, phase):
     w = _phases(neurons)[0][phase]
@@ -150,6 +152,24 @@ def test_component_plan_matches_the_reference_and_the_plain_layout():
     )
     assert np.array_equal(rx.reference_categories(out), ref_cats)
     assert plan.forward(yj[:, :5]).shape == (1024, 5)  # pads, slices back
+
+
+def test_a_mixed_radix_2_phase_matches_the_reference_bit_for_bit():
+    """2048 = 32**2 * 2: its last phase mixes radix 2 at stride 1024 with
+    radix 16, as 65536's does at stride 32768. Forced onto the layered
+    route at the 65536 configuration's bias and density, every layer
+    takes the component layout and the answers equal the reference's."""
+    spec = rx.RadixNetSpec(2048, 6, bias=-0.45)
+    assert rx.num_phases(2048) == 3
+    ws, bs = rx.radixnet_weights(spec)
+    y0 = rx.radixnet_input_panel(2048, 40, density=0.45, seed=5)
+    ref_y, ref_cats = rx.radixnet_reference(spec, y0)
+    plan = P.build_plan(ws, bs, 40, use_resident=False)
+    assert plan.route == P.ROUTE_LAYERED
+    assert plan.component_layers == 6
+    out = np.asarray(plan.forward(jnp.asarray(y0)))
+    np.testing.assert_array_equal(out, ref_y)
+    assert 0 < len(ref_cats) < 40  # some inputs live, not all
 
 
 def test_an_identity_gather_is_skipped():

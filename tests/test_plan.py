@@ -229,6 +229,39 @@ def test_repeated_layer_objects_are_relaid_once():
     assert all(plan.weights[i] is plan.weights[i % 2] for i in range(6))
 
 
+def test_the_executable_takes_each_distinct_weight_once():
+    """The layered executable's arguments hold each distinct weight, bias
+    and gather once, and every layer reads its own by index: six layers
+    of two phases lower to two weight parameters, and the answers equal
+    a stack of six distinct copies of the same weights."""
+    from repro.data import radixnet as rx
+
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 6))
+    plan = P.build_plan(ws, bs, 16, use_resident=False)
+    assert plan.weight_args == 2
+    weights, biases, gathers = plan._bound()
+    assert [w is plan.weights[i] for i, w in enumerate(weights)] == [True, True]
+    assert len(biases) == 2  # the bias in each phase's row order
+    copies = [jax.tree.map(jnp.copy, w) for w in ws]  # six distinct objects
+    apart = P.build_plan(copies, bs, 16, use_resident=False)
+    assert apart.weight_args == 6
+
+    def params(p):  # the compiled program's parameters
+        text = p.lower().as_text()
+        head = text[text.index("func.func public @main(") :].split("\n", 1)[0]
+        return head.count("%arg")
+
+    per_weight = len(jax.tree.leaves(weights[0]))
+    assert params(plan) == len(jax.tree.leaves(plan._bound())) + 1
+    assert params(apart) - params(plan) == 4 * per_weight + 4  # weights, biases
+    y0 = jnp.asarray(rx.radixnet_input_panel(256, 16, density=0.3, seed=2))
+    np.testing.assert_array_equal(
+        np.asarray(plan.forward(y0)), np.asarray(apart.forward(y0))
+    )
+    fused = P.build_plan(ws, bs, 16)
+    assert fused.is_fused_route and fused.weight_args == 1
+
+
 def test_vmem_boundary_tips_fused_into_tiled_exactly():
     """Regression for the route boundary: the last m whose activation
     panel exactly fills ``VMEM_SOFT_LIMIT_BYTES`` still takes the

@@ -182,7 +182,8 @@ def test_a_traced_engine_step_records_its_parts(log, tracing):
     assert [(r.parent, r.attrs) for r in builds] == [
         (
             "engine.plan",
-            {"width": 8, "route": stats["plan"]["route"], "component_layers": 0},
+            {"width": 8, "route": stats["plan"]["route"], "component_layers": 0,
+             "weight_args": 1},
         )
     ]
     assert steps[0].start_ns <= builds[0].start_ns <= builds[0].end_ns <= steps[0].end_ns
@@ -195,7 +196,9 @@ def test_plan_build_counts_the_component_layers(log, tracing):
     eng = SparseDNNEngine(ws, bs, batch_align=8, use_resident=False)
     eng.infer(jnp.ones((256, 4)))
     (build,) = [r for r in spans.recorded() if r.name == "plan.build"]
-    assert build.attrs == {"width": 8, "route": "layered", "component_layers": 5}
+    assert build.attrs == {
+        "width": 8, "route": "layered", "component_layers": 5, "weight_args": 2
+    }
 
 
 def test_an_idle_step_opens_no_span(log, tracing):

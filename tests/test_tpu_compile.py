@@ -179,37 +179,36 @@ def test_semiring_matmul_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_radix_16384x120_plan_layer_kernels_compile(one_chip, monkeypatch):
-    """The official 16384-neuron × 120-layer stack: the engine's plan
-    routes it layered on block-CSR kernels (the fused slot table and the
-    ELL prefetch tables overflow SMEM), re-lays each of the three phase
-    matrices once in the component layout (512 blocks of 1024×32: 32
-    whole 32×32 components a block-row, one entry per row a block), and
-    the layer kernel and the whole executable, gathers included,
-    compile."""
-    spec = rx.RadixNetSpec(16384, 120)
-    ws, bs = rx.radixnet_weights(spec)
+def _radix_plan_compiles(one_chip, monkeypatch, neurons, blocks, grid_steps):
+    """The engine's plan of the official ``neurons`` × 120 stack routes it
+    layered on block-CSR kernels in the component layout, each distinct
+    phase matrix re-laid once as ``blocks`` blocks of 1024×32 (32 whole
+    32×32 components a block-row, one entry per row a block); the layer
+    kernel at the challenge width and the whole executable, gathers
+    included, compile for one chip."""
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, 120))
     assert P.fused_route(ws) is None
     plan = P.build_plan(ws, bs, WIDTH)
     assert plan.route == P.ROUTE_LAYERED
     assert {lp.path for lp in plan.layers} == {"kernel-bcsr"}
     assert plan.component_layers == 120
+    assert plan.grid_steps == grid_steps
     distinct = list({id(w): w for w in plan.weights}.values())
-    assert len(distinct) == rx.num_phases(16384) == 3
+    assert len(distinct) == plan.weight_args == rx.num_phases(neurons)
     assert all(
-        w.total_blocks == 512 and w.block_shape == (1024, 32) for w in distinct
+        w.total_blocks == blocks and w.block_shape == (1024, 32) for w in distinct
     )
     fn = jax.jit(
         lambda w, y, b: kernel_ops.bcsr_spmm(
             w, y, b, fuse_bias_relu=True, interpret=False
         )
     )
-    shapes = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), distinct[0])
+    shapes = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), distinct[-1])
     text = (
         fn.lower(
             shapes,
-            _s(one_chip, (16384, WIDTH)),
-            _s(one_chip, (16384,)),
+            _s(one_chip, (neurons, WIDTH)),
+            _s(one_chip, (neurons,)),
         )
         .compile()
         .as_text()
@@ -217,15 +216,27 @@ def test_radix_16384x120_plan_layer_kernels_compile(one_chip, monkeypatch):
     assert "tpu_custom_call" in text
     # the executable calls the kernels with the backend's own choice
     monkeypatch.setattr(kernel_ops, "auto_interpret", lambda: False)
-    bound = jax.tree.map(
-        lambda a: _s(one_chip, a.shape, a.dtype),
-        (plan.weights, plan.biases, plan.gathers),
-    )
-    y = _s(one_chip, (16384, WIDTH))
+    bound = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), plan._bound())
+    y = _s(one_chip, (neurons, WIDTH))
     text = plan._fn.lower(*bound, y).compile().as_text()
     jax.clear_caches()  # drop the kernels traced for the chip
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 120
     assert "gather" in text
+
+
+def test_radix_16384x120_plan_layer_kernels_compile(one_chip, monkeypatch):
+    """16384 × 120: three phases of 512 components (the fused slot table
+    and the ELL prefetch tables overflow SMEM)."""
+    _radix_plan_compiles(one_chip, monkeypatch, 16384, 512, 245_760)
+
+
+def test_radix_65536x120_plan_layer_kernels_compile(one_chip, monkeypatch):
+    """65536 × 120, the largest official stack: four phases (radix-32
+    butterflies at strides 1, 32 and 1024, then a mixed radix-2 ⊗
+    radix-16 phase at stride 32768) of 2,048 components, 64 block-rows
+    of 32 blocks each. Passed once per layer, its 120 weights would need
+    30 GB of arguments; passed once per phase, 1.2 GB."""
+    _radix_plan_compiles(one_chip, monkeypatch, 65536, 2048, 983_040)
 
 
 # ---------------------------------------------------------------------
@@ -313,9 +324,10 @@ def test_ell_prefetch_guard(one_chip, neurons, fits):
 )
 def test_bcsr_prefetch_guard(one_chip, total_blocks, fits):
     """The block-CSR kernel's three flat (T,) tables: 86016 stored blocks
-    fit SMEM; a 65536-neuron RadiX-net layer (131072 blocks) does not,
-    and the plan refuses to route it rather than hand the compiler a
-    kernel it rejects."""
+    fit SMEM; 131072 (a 65536-neuron RadiX-net layer in 16×16 blocks)
+    do not, and the plan refuses to route such a layer rather than hand
+    the compiler a kernel it rejects. The plan's component layout stores
+    that layer as 2,048 blocks of 1024×32 instead."""
     w = _csr(one_chip, 65536, total_blocks)
     y = _s(one_chip, (65536, WIDTH))
 
