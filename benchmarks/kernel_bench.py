@@ -825,8 +825,10 @@ def challenge_arm(
     weight 1/16, the official per-size bias) streamed through the
     serving engine in width-classed panels (``repro.serve.challenge``),
     reporting the challenge's official rate metric **edges × inputs /
-    second**. The stack is sized past ``VMEM_SOFT_LIMIT_BYTES`` so the
-    plan layer must route it through the multi-panel tiled fused kernel
+    second**. The stack is sized past ``VMEM_SOFT_LIMIT_BYTES`` and the
+    engine demands residency, so the plan layer must route it through
+    the multi-panel tiled fused kernel (left to auto-detection, a
+    RadiX-net stack takes the layered route in the component layout)
     — the run doubles as a conformance check: the engine's answer set
     must equal the pure-numpy reference's ground-truth categories
     bit-for-bit. Deterministic topology + seeded inputs → all
@@ -848,6 +850,7 @@ def challenge_arm(
         batch_align=batch_align,
         density=density,
         seed=seed,
+        use_resident=True,
     )
     return {
         "neurons": neurons,
@@ -1038,7 +1041,9 @@ def tune_arm(
     **RadiX-net stack**: sized so the f32 fused panel (24 MiB at
     ``neurons=8192``) busts ``VMEM_SOFT_LIMIT_BYTES`` while the bf16
     panel (12 MiB) fits — the tuned config moves the route from
-    fused-tiled back to resident fused. The resident plan is *built*
+    fused-tiled back to resident fused (each plan demands residency, as
+    auto-detection would route the stack's complete components
+    layered). The resident plan is *built*
     for the route assertion but never executed here: interpret-mode
     compilation of the resident kernel at this size takes minutes,
     and the tiled bf16 kernel computes the identical panels (same
@@ -1110,9 +1115,11 @@ def tune_arm(
     vmem_bf16 = fused_mlp_vmem_bytes(neurons, panel_dtype="bfloat16")
 
     bf16_cfg = tune.TunedConfig(panel_dtype="bfloat16")
-    default_plan = plan_mod.build_plan(rws, rbs, radix_width)
+    default_plan = plan_mod.build_plan(
+        rws, rbs, radix_width, use_resident=True
+    )
     bf16_plan = plan_mod.build_plan(
-        rws, rbs, radix_width, tuned=bf16_cfg
+        rws, rbs, radix_width, use_resident=True, tuned=bf16_cfg
     )  # built for the route assertion only — never forwarded here
     # Tiled twin of the bf16 resident plan: identical kernel math,
     # forced off the resident route by an under-cutting budget.
@@ -1120,6 +1127,7 @@ def tune_arm(
         rws,
         rbs,
         radix_width,
+        use_resident=True,
         tuned=tune.TunedConfig(
             panel_dtype="bfloat16", vmem_limit_bytes=vmem_bf16 - 1
         ),

@@ -51,6 +51,7 @@ from repro.plan.routes import (  # noqa: F401
     fused_route,
     layer_path,
     resident_eligible,
+    takes_component_layout,
 )
 from repro.plan.sharded import (  # noqa: F401
     ShardedLayerPlan,
@@ -102,6 +103,7 @@ __all__ = [
     "reset_default_cache",
     "resident_eligible",
     "stack_grid_steps",
+    "takes_component_layout",
     "to_preferred_layout",
     "topology_fingerprint",
 ]

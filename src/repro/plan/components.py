@@ -34,12 +34,14 @@ The plan (``repro.plan.stack_plan``) runs the re-laid block-CSR weight
 through the same ``bcsr_spmm`` kernel, holds each layer's activations in
 that layer's row order, and gathers them into the next component
 layer's column order in between. Host-side, once per distinct weight
-object at plan build.
+object: the route choice (``repro.plan.routes.takes_component_layout``)
+and the plan build read the same result.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -75,9 +77,9 @@ class ComponentLayout:
         return self.rows.size // self.shape[0]
 
 
-def _entries(w) -> tuple[np.ndarray, np.ndarray, np.ndarray] | None:
-    """(rows, cols, values) of the layer's nonzero entries in stored
-    blocks (host copies), or None for a dense weight."""
+def _entries(w) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(rows, cols, values) of a sparse layer's nonzero entries in
+    stored blocks (host copies)."""
     if isinstance(w, BlockSparseMatrix):
         blocks = np.asarray(jax.device_get(w.blocks))
         live = np.asarray(jax.device_get(w.block_mask)).astype(bool)
@@ -86,28 +88,41 @@ def _entries(w) -> tuple[np.ndarray, np.ndarray, np.ndarray] | None:
         rows = rb * w.block_shape[0] + i
         cols = col_idx[rb, slot].astype(np.int64) * w.block_shape[1] + j
         return rows, cols, blocks[rb, slot, i, j]
-    if isinstance(w, BlockCSRMatrix):
-        values = np.asarray(jax.device_get(w.values))
-        live = np.asarray(jax.device_get(w.valid)).astype(bool)
-        row_id = np.asarray(jax.device_get(w.row_id)).astype(np.int64)
-        col_idx = np.asarray(jax.device_get(w.col_idx)).astype(np.int64)
-        t, i, j = np.nonzero((values != 0) & live[:, None, None])
-        rows = row_id[t] * w.block_shape[0] + i
-        cols = col_idx[t] * w.block_shape[1] + j
-        return rows, cols, values[t, i, j]
-    return None
+    values = np.asarray(jax.device_get(w.values))
+    live = np.asarray(jax.device_get(w.valid)).astype(bool)
+    row_id = np.asarray(jax.device_get(w.row_id)).astype(np.int64)
+    col_idx = np.asarray(jax.device_get(w.col_idx)).astype(np.int64)
+    t, i, j = np.nonzero((values != 0) & live[:, None, None])
+    rows = row_id[t] * w.block_shape[0] + i
+    cols = col_idx[t] * w.block_shape[1] + j
+    return rows, cols, values[t, i, j]
+
+
+# Each sparse weight object's layout, found once: the route choice and
+# the plan build both ask. An entry leaves with its weight.
+_found: dict[int, tuple[weakref.ref, ComponentLayout | None]] = {}
 
 
 def component_layout(w) -> ComponentLayout | None:
     """The layer's component layout, or None where any of the module's
     three conditions fails (or the weight is dense, or the re-laid
-    kernel's prefetch tables would not fit SMEM)."""
+    kernel's prefetch tables would not fit SMEM). Found once per weight
+    object."""
+    if not isinstance(w, (BlockSparseMatrix, BlockCSRMatrix)):
+        return None
+    key = id(w)
+    hit = _found.get(key)
+    if hit is not None and hit[0]() is w:
+        return hit[1]
+    layout = _detect(w)
+    _found[key] = (weakref.ref(w, lambda _, key=key: _found.pop(key, None)), layout)
+    return layout
+
+
+def _detect(w) -> ComponentLayout | None:
     from repro.kernels import bcsr_spmm as _bcsr
 
-    found = _entries(w)
-    if found is None:
-        return None
-    rows, cols, vals = found
+    rows, cols, vals = _entries(w)
     m, k = w.shape
     if rows.size == 0:
         return None

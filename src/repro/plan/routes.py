@@ -5,6 +5,11 @@ key at plan-build time and never re-derived per call:
 
     homogeneous square BSR stack AND not differentiable AND fused
     allowed AND the stack's slot table fits SMEM?
+      └─ residency auto-detected, the plan re-lays its weights with no
+               tuned ``layout`` or ``block_size``, and every distinct
+               weight is a union of complete components
+               (:func:`takes_component_layout`) → **layered**, each
+               layer in the component layout (``repro.plan.components``)
       └─ panel fits VMEM → **fused**: ONE VMEM-resident ``pallas_call``
                for the whole stack (``repro.kernels.fused_mlp``)
       └─ panel past ``VMEM_SOFT_LIMIT_BYTES`` → **fused-tiled**: still
@@ -129,6 +134,32 @@ def fused_route(
     ):
         return ROUTE_FUSED
     return ROUTE_FUSED_TILED
+
+
+def takes_component_layout(
+    weights: Sequence[Weight], *, relayout: bool = True, tuned=None
+) -> bool:
+    """Does auto-detected residency send this stack to the layered route
+    in the component layout rather than to a fused route?
+
+    True when the plan re-lays its weights (``relayout``), no tuned
+    ``layout`` or ``block_size`` overrides the layout, and every
+    distinct weight object has a component layout. Such a layer runs a
+    few dense-row blocks where the fused kernels run every stored 16×16
+    block: at RadiX-net 1024 × 120, 15,360 grid steps a 512-wide panel
+    against 983,040. Callers ask only for a stack that has a fused route
+    (:func:`fused_route`); ``use_resident=True`` still demands it.
+    Detection reads each distinct weight once on the host, and the plan
+    build reuses what it found (:func:`~repro.plan.components.component_layout`).
+    """
+    from repro.plan import components as _components
+
+    if not relayout or any(
+        getattr(tuned, knob, None) is not None for knob in ("layout", "block_size")
+    ):
+        return False
+    distinct = {id(w): w for w in weights}.values()
+    return all(_components.component_layout(w) is not None for w in distinct)
 
 
 def layer_path(w: Weight, *, differentiable: bool) -> str:

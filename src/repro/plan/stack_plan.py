@@ -454,9 +454,11 @@ def build_plan(
 ) -> StackPlan:
     """Compile one :class:`StackPlan` (all the per-topology analysis).
 
-    ``use_resident``: None auto-detects fused eligibility, True demands
-    it (ValueError when ineligible), False forces the layered route —
-    the ``SparseDNNEngine`` tri-state, verbatim. ``relayout`` applies
+    ``use_resident``: None auto-detects — a fused route where one fits,
+    unless every distinct weight has a component layout
+    (``repro.plan.routes.takes_component_layout``) — True demands the
+    fused route (ValueError when ineligible), False forces the layered
+    route: the ``SparseDNNEngine`` tri-state, verbatim. ``relayout`` applies
     the component layout (``repro.plan.components``) or else the
     ELL→CSR waste heuristic to the bound execution weights; default on
     for inference plans, always off for differentiable plans (their
@@ -521,11 +523,6 @@ def build_plan(
                 else " (needs a homogeneous square BSR stack)"
             )
         )
-    if use_resident is None or use_resident:
-        route = fused_ok or _routes.ROUTE_LAYERED
-    else:
-        route = _routes.ROUTE_LAYERED
-
     if relayout is None:
         relayout = not differentiable
     if differentiable and relayout:
@@ -533,6 +530,16 @@ def build_plan(
             "relayout converts bound weights; a differentiable plan "
             "must keep the caller's layouts so cotangents line up"
         )
+
+    if (use_resident is not None and not use_resident) or (
+        use_resident is None
+        and fused_ok is not None
+        and _routes.takes_component_layout(weights, relayout=relayout, tuned=tuned)
+    ):
+        # forced, or the component layout runs the fewer grid steps
+        route = _routes.ROUTE_LAYERED
+    else:
+        route = fused_ok or _routes.ROUTE_LAYERED
 
     if donor is not None:
         if (

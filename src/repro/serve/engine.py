@@ -12,8 +12,9 @@ dry-run cells ``decode_32k``/``long_500k`` lower exactly the
 passes through a deep sparse ReLU MLP (GraphChallenge-style inference).
 Requests are feature columns; the engine right-pads each batch to the
 kernel tile, dispatches the VMEM-resident single-``pallas_call`` forward
-when the stack qualifies (square, homogeneous, panel fits VMEM) and the
-layered fused path otherwise, and reports per-batch kernel-step
+when the stack qualifies (square, homogeneous, panel fits VMEM, not
+a union of complete components) and the layered fused path otherwise,
+and reports per-batch kernel-step
 accounting so operators can see the nnz-proportional scaling live.
 
 Two call conventions on ``SparseDNNEngine``:
@@ -254,8 +255,15 @@ class SparseDNNEngine:
                 "fused whole-stack kernels (needs a homogeneous square "
                 "BSR stack); pass use_resident=None to auto-detect"
             )
+        # Auto-detection reads the plan layer's own rule, so the ladder's
+        # level and ``build_plan(use_resident=None)`` agree: a stack
+        # whose every distinct weight has a component layout leaves the
+        # fused kernels for the layered route.
         self._resident = (
-            resident_ok if self.use_resident is None else self.use_resident
+            resident_ok
+            and not _routes.takes_component_layout(self.weights, tuned=self._tuned)
+            if self.use_resident is None
+            else self.use_resident
         )
         if self.validate:
             checked: set[int] = set()  # layers may repeat one object

@@ -161,32 +161,42 @@ def test_conformance_small(neurons, layers):
         assert np.array_equal(got, ref_cats), (name, got, ref_cats)
 
 
-def test_challenge_driver_small():
+@pytest.mark.parametrize(
+    "use_resident,route,level",
+    [(None, "layered", "layered"), (True, "fused", "resident")],
+    ids=["auto-layered", "fused"],
+)
+def test_challenge_driver_small(use_resident, route, level):
+    """Auto-detected residency serves the RadiX-net stack on the layered
+    route in the component layout; ``use_resident=True`` still serves
+    it on the fused kernel."""
     spec = rx.RadixNetSpec(256, 6)
     _, ref_cats = rx.radixnet_reference(
         spec, rx.radixnet_input_panel(256, 50, density=0.3, seed=5)
     )
     res = run_challenge(
-        spec, n_inputs=50, panel_width=24, batch_align=8, seed=5
+        spec, n_inputs=50, panel_width=24, batch_align=8, seed=5,
+        use_resident=use_resident,
     )
     assert np.array_equal(res.categories, ref_cats)
     assert res.served == 50
     assert res.steps == 3  # ceil(50 / 24) width-classed panels
     assert res.width_classes == (24,)  # one compiled class, incl. tail
-    assert res.routes == ("fused",)
-    assert res.levels == ("resident",)
+    assert res.routes == (route,)
+    assert res.levels == (level,)
     assert res.edges == spec.edges
     assert res.edge_inputs_per_sec > 0
     assert res.grid_steps > 0
     # per-panel plan stats, the warmup time, and the serving engine
     assert [(p["route"], p["level"]) for p in res.panels] == [
-        ("fused", "resident")
+        (route, level)
     ] * 3
     assert not any(p["degraded"] for p in res.panels)
     assert res.warmup_seconds > 0
     assert not res.engine.ladder.events
     (plan,) = res.engine.plan_cache.plans()
-    assert plan.width == 24 and plan.route == P.ROUTE_FUSED
+    assert plan.width == 24 and plan.route == route
+    assert plan.component_layers == (6 if route == "layered" else 0)
     assert plan.lower().compile() is not None
 
 
@@ -253,10 +263,18 @@ def test_conformance_challenge_config(neurons, layers, density):
 
 
 @pytest.mark.slow
-def test_challenge_engine_routes_fused_tiled_past_vmem_budget():
-    """A 16384-neuron stack is past ``VMEM_SOFT_LIMIT_BYTES`` — the
-    engine must auto-route it through the multi-panel tiled kernel and
-    still reproduce the ground-truth categories."""
+@pytest.mark.parametrize(
+    "use_resident,route,level",
+    [(None, "layered", "layered"), (True, "fused-tiled", "resident")],
+    ids=["auto-layered", "fused-tiled"],
+)
+def test_challenge_engine_routes_fused_tiled_past_vmem_budget(
+    use_resident, route, level
+):
+    """A 16384-neuron stack is past ``VMEM_SOFT_LIMIT_BYTES``: demanding
+    residency serves it on the multi-panel tiled kernel, and
+    auto-detection on the layered route in the component layout; both
+    reproduce the ground-truth categories."""
     spec = rx.RadixNetSpec(16384, 6)
     assert spec.bias == -0.4
     y0 = rx.radixnet_input_panel(16384, 48, density=0.4, seed=2)
@@ -264,10 +282,10 @@ def test_challenge_engine_routes_fused_tiled_past_vmem_budget():
     assert 0 < len(ref_cats) < 48
     res = run_challenge(
         spec, n_inputs=48, panel_width=24, batch_align=8,
-        density=0.4, seed=2,
+        density=0.4, seed=2, use_resident=use_resident,
     )
-    assert res.routes == ("fused-tiled",)
-    assert res.levels == ("resident",)
+    assert res.routes == (route,)
+    assert res.levels == (level,)
     assert np.array_equal(res.categories, ref_cats)
 
 

@@ -191,14 +191,23 @@ def test_an_identity_gather_is_skipped():
         {"differentiable": True},
         {"tuned": TunedConfig(layout="bcsr")},
         {"tuned": TunedConfig(block_size=32)},
-        {"use_resident": None},  # the fused route wins first
+        {"use_resident": True},  # the fused route, demanded
+        # auto-detection keeps the fused route where the plan keeps the
+        # caller's layout
+        {"use_resident": None, "tuned": TunedConfig(layout="bcsr")},
+        {"use_resident": None, "tuned": TunedConfig(block_size=32)},
+        {"use_resident": None, "relayout": False},
     ],
-    ids=["differentiable", "tuned-layout", "tuned-block-size", "fused"],
+    ids=[
+        "differentiable", "tuned-layout", "tuned-block-size", "fused",
+        "auto-tuned-layout", "auto-tuned-block-size", "auto-no-relayout",
+    ],
 )
 def test_stays_off_where_the_plan_keeps_the_callers_layout(kwargs):
     ws, bs = _stack(1024, 3)
     kwargs = {"use_resident": False, **kwargs}
     plan = P.build_plan(ws, bs, 8, **kwargs)
+    assert plan.is_fused_route == (kwargs["use_resident"] is not False)
     assert plan.component_layers == 0
     assert "component" not in plan.layouts
     assert plan.gathers == (None,) * 4
@@ -234,3 +243,67 @@ def test_describe_shows_the_component_layout():
     desc = P.build_plan(ws, bs, 8, use_resident=False).describe()
     assert desc["layouts"] == ["component", "component"]
     assert desc["component_layers"] == 2
+
+
+# ---------------------------------------------------------------------
+# The route choice: auto-detected residency takes the component layout
+# ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("neurons,layers", [(1024, 3), (256, 5)], ids=["1024x3", "256x5"])
+def test_auto_residency_routes_complete_components_layered(neurons, layers):
+    """A stack the fused kernels could run, whose every weight is a union
+    of complete components, takes the layered route in the component
+    layout, bit-equal to the numpy reference."""
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, layers))
+    assert P.fused_route(ws) == P.ROUTE_FUSED
+    assert P.takes_component_layout(ws)
+    plan = P.build_plan(ws, bs, 16)
+    assert plan.route == P.ROUTE_LAYERED
+    assert plan.component_layers == layers
+    assert {lp.path for lp in plan.layers} == {"kernel-bcsr"}
+    y0 = rx.radixnet_input_panel(neurons, 16, density=0.3, seed=4)
+    ref_y, _ = rx.radixnet_reference(rx.RadixNetSpec(neurons, layers), y0)
+    np.testing.assert_array_equal(np.asarray(plan.forward(jnp.asarray(y0))), ref_y)
+
+
+@pytest.mark.parametrize("neurons,layers", [(1024, 3), (256, 5)], ids=["1024x3", "256x5"])
+def test_demanded_residency_keeps_the_fused_kernel(neurons, layers):
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, layers))
+    plan = P.build_plan(ws, bs, 16, use_resident=True)
+    assert plan.route == P.ROUTE_FUSED
+    assert plan.component_layers == 0
+    assert plan.pallas_calls == 1
+
+
+def test_a_stack_of_incomplete_components_auto_routes_fused():
+    """Random 16x16 block placement leaves rows of one column set
+    sharing columns with rows of another: no complete components, so a
+    homogeneous square BSR stack keeps the fused route."""
+    ws = [
+        BlockSparseMatrix.random(jax.random.PRNGKey(i), (256, 256), (16, 16), 3)
+        for i in range(3)
+    ]
+    bs = [jnp.zeros((256,))] * 3
+    assert all(component_layout(w) is None for w in ws)
+    assert not P.takes_component_layout(ws)
+    plan = P.build_plan(ws, bs, 16)
+    assert plan.route == P.ROUTE_FUSED
+    assert plan.component_layers == 0
+
+
+def test_detection_reads_each_weight_object_once(monkeypatch):
+    """The route choice and the plan build ask for the same weights'
+    layouts; each distinct object is read once."""
+    from repro.plan import components
+
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 4))
+    ws = [jax.tree.map(jnp.copy, w) for w in ws[:2]] * 2  # fresh objects
+    reads = []
+    detect = components._detect
+    monkeypatch.setattr(
+        components, "_detect", lambda w: reads.append(id(w)) or detect(w)
+    )
+    plan = P.build_plan(ws, bs, 8)
+    assert plan.component_layers == 4
+    assert sorted(reads) == sorted({id(w) for w in ws})

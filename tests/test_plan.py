@@ -258,8 +258,10 @@ def test_the_executable_takes_each_distinct_weight_once():
     np.testing.assert_array_equal(
         np.asarray(plan.forward(y0)), np.asarray(apart.forward(y0))
     )
-    fused = P.build_plan(ws, bs, 16)
+    fused = P.build_plan(ws, bs, 16, use_resident=True)
     assert fused.is_fused_route and fused.weight_args == 1
+    auto = P.build_plan(ws, bs, 16)  # complete components: layered
+    assert auto.route == P.ROUTE_LAYERED and auto.weight_args == 2
 
 
 def test_vmem_boundary_tips_fused_into_tiled_exactly():

@@ -201,6 +201,24 @@ def test_plan_build_counts_the_component_layers(log, tracing):
     }
 
 
+def test_an_auto_resident_engine_over_components_builds_layered(log, tracing):
+    """Auto-detected residency over a 1024 x 3 RadiX-net resolves to the
+    layered level, whose plan runs the component layout."""
+    from repro.data import radixnet as rx
+
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(1024, 3))
+    eng = SparseDNNEngine(ws, bs, batch_align=8)
+    assert eng.ladder.preferred_level == "layered"
+    out, stats = eng.infer(jnp.ones((1024, 4)))
+    assert stats["resident"] is False and stats["plan"]["route"] == "layered"
+    (plan,) = eng.plan_cache.plans()
+    assert plan.route == "layered" and plan.component_layers == 3
+    (build,) = [r for r in spans.recorded() if r.name == "plan.build"]
+    assert build.attrs == {
+        "width": 8, "route": "layered", "component_layers": 3, "weight_args": 2
+    }
+
+
 def test_an_idle_step_opens_no_span(log, tracing):
     eng, _ = _engine()
     out, _ = eng.step()

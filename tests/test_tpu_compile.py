@@ -179,15 +179,18 @@ def test_semiring_matmul_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def _radix_plan_compiles(one_chip, monkeypatch, neurons, blocks, grid_steps):
+def _radix_plan_compiles(
+    one_chip, monkeypatch, neurons, blocks, grid_steps, fused=None
+):
     """The engine's plan of the official ``neurons`` × 120 stack routes it
     layered on block-CSR kernels in the component layout, each distinct
     phase matrix re-laid once as ``blocks`` blocks of 1024×32 (32 whole
     32×32 components a block-row, one entry per row a block); the layer
     kernel at the challenge width and the whole executable, gathers
-    included, compile for one chip."""
+    included, compile for one chip. ``fused``: the fused route the stack
+    also has (None where its slot table overflows SMEM)."""
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, 120))
-    assert P.fused_route(ws) is None
+    assert P.fused_route(ws) == fused
     plan = P.build_plan(ws, bs, WIDTH)
     assert plan.route == P.ROUTE_LAYERED
     assert {lp.path for lp in plan.layers} == {"kernel-bcsr"}
@@ -222,6 +225,14 @@ def _radix_plan_compiles(one_chip, monkeypatch, neurons, blocks, grid_steps):
     jax.clear_caches()  # drop the kernels traced for the chip
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 120
     assert "gather" in text
+
+
+def test_radix_1024x120_plan_layer_kernels_compile(one_chip, monkeypatch):
+    """1024 × 120: two phases of 32 components, one block-row of 32
+    blocks each. The fused kernel could run it (983,040 grid steps a
+    512-wide panel over its 16×16 slots); the component layout runs
+    15,360."""
+    _radix_plan_compiles(one_chip, monkeypatch, 1024, 32, 15_360, P.ROUTE_FUSED)
 
 
 def test_radix_16384x120_plan_layer_kernels_compile(one_chip, monkeypatch):
