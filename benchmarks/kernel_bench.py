@@ -1,6 +1,6 @@
 """Kernel-layer benchmark: ELL grid vs occupancy-exact CSR grid vs
-VMEM-resident fused multi-layer vs dense, across inverse sparsity and
-row skew. ``python -m benchmarks.kernel_bench [--quick]``.
+dense, across inverse sparsity and row skew.
+``python -m benchmarks.kernel_bench [--quick]``.
 
 Two kinds of measurement, kept separate on purpose:
 
@@ -19,7 +19,6 @@ Two kinds of measurement, kept separate on purpose:
 Writes ``BENCH_kernels.json`` at the repo root so subsequent PRs can
 track the trajectory:
   steps:  per-topology {ell, csr} grid steps + the ratio
-  fused:  pallas_call counts (L vs 1) + layered/fused XLA wall-clock
   sweep:  inverse-sparsity × skew wall-clock for the XLA arms
   train:  the TRAINING arm — a masked sparse MLP train step with the
           kernels (and their custom VJPs) in the hot path: pallas_call
@@ -56,8 +55,8 @@ track the trajectory:
           on an 8-host-device mesh.
   challenge: the CHALLENGE arm — the GraphChallenge workload shape
           (RadiX-net topology, fan-in 32, weight 1/16, official bias)
-          streamed through the serving engine on a stack past the VMEM
-          budget (→ the multi-panel tiled fused route), reporting the
+          streamed through the serving engine on its own route (the
+          layered route in the component layout), reporting the
           official edges × inputs / sec metric plus a bit-level
           conformance check against the numpy ground-truth categories
           (tests/test_challenge.py is the full suite).
@@ -165,52 +164,6 @@ def topology_arms(m: int, block: int, total_blocks: int, skew: float, n: int):
             "csr": t_csr,
             "dense": t_dense,
         },
-    }
-
-
-def fused_arm(m: int, L: int, bpr: int, n: int):
-    """Layered vs single-call fused forward (counts + XLA wall-clock)."""
-    ws = [
-        BlockSparseMatrix.random(
-            jax.random.PRNGKey(i), (m, m), (16, 16), blocks_per_row=bpr
-        )
-        for i in range(L)
-    ]
-    bs = [jnp.zeros((m,), jnp.float32) for _ in range(L)]
-    y0 = jax.random.uniform(jax.random.PRNGKey(99), (m, n), jnp.float32)
-
-    stacked_w, stacked_b = dnn.stack_bsr(ws), jnp.stack(bs)
-    jaxpr = jax.make_jaxpr(
-        lambda w, b, y: kernel_ops.fused_mlp_forward(w, b, y)
-    )(stacked_w, stacked_b, y0)
-    fused_calls = str(jaxpr).count("pallas_call")
-
-    t_layered = timeit(
-        jax.jit(lambda ws_, bs_, y: dnn.dnn_forward(ws_, bs_, y, fused=True)),
-        ws, bs, y0,
-    )
-    t_scan = timeit(
-        jax.jit(dnn.dnn_forward_scan), stacked_w, stacked_b, y0
-    )
-    # correctness tie-in: fused kernel (interpret) == layered, one call
-    out_fused = kernel_ops.fused_mlp_forward(stacked_w, stacked_b, y0)
-    out_layered = dnn.dnn_forward(ws, bs, y0, fused=True)
-    max_rel = float(
-        jnp.max(
-            jnp.abs(out_fused - out_layered)
-            / jnp.maximum(jnp.abs(out_layered), 1.0)
-        )
-    )
-    return {
-        "m": m,
-        "layers": L,
-        "blocks_per_row": bpr,
-        "n": n,
-        "pallas_calls_fused": fused_calls,
-        "pallas_calls_layered": L,
-        "hbm_activation_roundtrips_eliminated": L - 1,
-        "max_rel_err_vs_layered": max_rel,
-        "xla_time_s": {"layered_loop": t_layered, "layered_scan": t_scan},
     }
 
 
@@ -344,7 +297,6 @@ def serve_arm(
         for i in range(L)
     ]
     bs = [jnp.zeros((m,), jnp.float32) for _ in range(L)]
-    assert dnn.resident_eligible(ws), "serve arm expects the resident path"
 
     trace = poissonish_trace(
         n_requests,
@@ -363,7 +315,6 @@ def serve_arm(
         max_wait=max_wait,
     )
     static, continuous = cmp["static"], cmp["continuous"]
-    resident_used = all(s.resident for s in continuous.steps)
     return {
         "m": m,
         "layers": L,
@@ -381,7 +332,6 @@ def serve_arm(
             "ticks": len(trace),
             "arrivals_per_tick": [len(a) for a in trace],
         },
-        "resident_path_used": resident_used,
         "static": static.summary(),
         "continuous": continuous.summary(),
         "pad_fraction_ratio_continuous_over_static": cmp[
@@ -825,15 +775,13 @@ def challenge_arm(
     weight 1/16, the official per-size bias) streamed through the
     serving engine in width-classed panels (``repro.serve.challenge``),
     reporting the challenge's official rate metric **edges × inputs /
-    second**. The stack is sized past ``VMEM_SOFT_LIMIT_BYTES`` and the
-    engine demands residency, so the plan layer must route it through
-    the multi-panel tiled fused kernel (left to auto-detection, a
-    RadiX-net stack takes the layered route in the component layout)
-    — the run doubles as a conformance check: the engine's answer set
-    must equal the pure-numpy reference's ground-truth categories
-    bit-for-bit. Deterministic topology + seeded inputs → all
-    accounting fields are exact; only wall-clock (and the metric
-    derived from it) varies by runner.
+    second**. The engine routes the stack as it routes every RadiX-net
+    stack (layered, in the component layout), and the run doubles as a
+    conformance check: the engine's answer set must equal the
+    pure-numpy reference's ground-truth categories bit-for-bit.
+    Deterministic topology + seeded inputs → all accounting fields are
+    exact; only wall-clock (and the metric derived from it) varies by
+    runner.
     """
     from repro.data import radixnet as rx
     from repro.serve import run_challenge
@@ -850,7 +798,6 @@ def challenge_arm(
         batch_align=batch_align,
         density=density,
         seed=seed,
-        use_resident=True,
     )
     return {
         "neurons": neurons,
@@ -1021,15 +968,10 @@ def tune_arm(
     block: int,
     width: int,
     reps: int,
-    neurons: int,
-    layers: int,
-    radix_width: int,
-    density: float,
-    seed: int,
 ):
-    """The TUNE arm — the autotuner sweep (``repro.tune``) on two
-    topologies where the default config is beatable, writing the winning
-    table to ``BENCH_tuning_table.json`` for the CI artifact upload.
+    """The TUNE arm — the autotuner sweep (``repro.tune``) on a topology
+    where the default config is beatable, writing the winning table to
+    ``BENCH_tuning_table.json`` for the CI artifact upload.
 
     **Skewed stack**: rectangular layers with per-row-skewed block
     counts — the default layout heuristic keeps ELL (waste stays under
@@ -1037,27 +979,8 @@ def tune_arm(
     grid-step bill, so the sweep's cost-model scoring must pick
     ``layout=bcsr`` and the tuned plan must bill strictly fewer steps
     (and, recorded but not asserted: run faster).
-
-    **RadiX-net stack**: sized so the f32 fused panel (24 MiB at
-    ``neurons=8192``) busts ``VMEM_SOFT_LIMIT_BYTES`` while the bf16
-    panel (12 MiB) fits — the tuned config moves the route from
-    fused-tiled back to resident fused (each plan demands residency, as
-    auto-detection would route the stack's complete components
-    layered). The resident plan is *built*
-    for the route assertion but never executed here: interpret-mode
-    compilation of the resident kernel at this size takes minutes,
-    and the tiled bf16 kernel computes the identical panels (same
-    per-block f32-accumulate contraction), so accuracy and wall time
-    are measured through the tiled route on the challenge-shaped
-    {0, 1} input panel.
     """
-    from repro import plan as plan_mod
     from repro import tune
-    from repro.data import radixnet as rx
-    from repro.kernels.fused_mlp import (
-        VMEM_SOFT_LIMIT_BYTES,
-        fused_mlp_vmem_bytes,
-    )
 
     table = tune.TuningTable()
 
@@ -1102,77 +1025,6 @@ def tune_arm(
         ],
     }
 
-    # --- RadiX-net stack: bf16 panels move the resident boundary -----
-    spec = rx.RadixNetSpec(neurons, layers)
-    rws, rbs = rx.radixnet_weights(spec, block_size=block)
-    probe = jnp.asarray(
-        rx.radixnet_input_panel(
-            neurons, radix_width, density=density, seed=seed
-        ),
-        jnp.float32,
-    )
-    vmem_f32 = fused_mlp_vmem_bytes(neurons)
-    vmem_bf16 = fused_mlp_vmem_bytes(neurons, panel_dtype="bfloat16")
-
-    bf16_cfg = tune.TunedConfig(panel_dtype="bfloat16")
-    default_plan = plan_mod.build_plan(
-        rws, rbs, radix_width, use_resident=True
-    )
-    bf16_plan = plan_mod.build_plan(
-        rws, rbs, radix_width, use_resident=True, tuned=bf16_cfg
-    )  # built for the route assertion only — never forwarded here
-    # Tiled twin of the bf16 resident plan: identical kernel math,
-    # forced off the resident route by an under-cutting budget.
-    bf16_tiled_plan = plan_mod.build_plan(
-        rws,
-        rbs,
-        radix_width,
-        use_resident=True,
-        tuned=tune.TunedConfig(
-            panel_dtype="bfloat16", vmem_limit_bytes=vmem_bf16 - 1
-        ),
-    )
-    ref = np.asarray(default_plan.forward(probe), np.float32)
-    out = np.asarray(bf16_tiled_plan.forward(probe), np.float32)
-    bf16_err = float(np.max(np.abs(out - ref)))
-    wall_f32 = timeit(default_plan.forward, probe)
-    wall_bf16 = timeit(bf16_tiled_plan.forward, probe)
-    table.put(
-        plan_mod.topology_fingerprint(rws),
-        jax.default_backend(),
-        "float32",
-        bf16_cfg,
-        {
-            "width": radix_width,
-            "route": bf16_plan.route,
-            "default_route": default_plan.route,
-            "grid_steps": int(bf16_plan.grid_steps),
-            "default_grid_steps": int(default_plan.grid_steps),
-            "vmem_bytes": int(vmem_bf16),
-            "default_vmem_bytes": int(vmem_f32),
-            "max_abs_err": bf16_err,
-            "accuracy_via": "fused-tiled bf16 twin",
-        },
-    )
-    radix = {
-        "neurons": neurons,
-        "layers": layers,
-        "width": radix_width,
-        "density": density,
-        "seed": seed,
-        "winner": bf16_cfg.token(),
-        "route_default": default_plan.route,
-        "route_tuned": bf16_plan.route,
-        "grid_steps_default": int(default_plan.grid_steps),
-        "grid_steps_tuned": int(bf16_plan.grid_steps),
-        "vmem_bytes_f32": int(vmem_f32),
-        "vmem_bytes_bf16": int(vmem_bf16),
-        "vmem_soft_limit": int(VMEM_SOFT_LIMIT_BYTES),
-        "bf16_max_abs_err": bf16_err,
-        "wall_s_f32_tiled": wall_f32,
-        "wall_s_bf16_tiled": wall_bf16,
-    }
-
     table.save(TUNING_TABLE_PATH)
     return {
         # Flat generator-param record: tools/check_bench.py compares
@@ -1185,14 +1037,8 @@ def tune_arm(
             "block": block,
             "width": width,
             "reps": reps,
-            "neurons": neurons,
-            "layers": layers,
-            "radix_width": radix_width,
-            "density": density,
-            "seed": seed,
         },
         "skewed": skewed,
-        "radix": radix,
         "table_entries": len(table),
         "table_path": os.path.basename(TUNING_TABLE_PATH),
     }
@@ -1349,7 +1195,7 @@ def fleet_arm(
 
 
 ALL_ARMS = (
-    "topologies", "fused", "train", "serve", "plan", "sharded", "faults",
+    "topologies", "train", "serve", "plan", "sharded", "faults",
     "challenge", "gnn", "tune", "fleet",
 )
 
@@ -1397,18 +1243,6 @@ def run(quick: bool = False, arms=None):
             if r["max_blocks_per_row"] > r["mean_blocks_per_row"]:
                 assert r["grid_steps_csr"] < r["grid_steps_ell"], r
         payload["topologies"] = topologies
-
-    if "fused" in arms:
-        fused = fused_arm(m=256, L=4 if quick else 8, bpr=3, n=128)
-        print(
-            f"fused: L={fused['layers']} pallas_calls "
-            f"{fused['pallas_calls_layered']}→{fused['pallas_calls_fused']}, "
-            f"max rel err {fused['max_rel_err_vs_layered']:.2e}",
-            flush=True,
-        )
-        assert fused["pallas_calls_fused"] == 1
-        assert fused["max_rel_err_vs_layered"] <= 1e-5
-        payload["fused"] = fused
 
     if "train" in arms:
         train = train_arm(
@@ -1462,12 +1296,11 @@ def run(quick: bool = False, arms=None):
             f"{serve['continuous']['latency_max']} ticks",
             flush=True,
         )
-        # serving arm: every request served, the resident path engaged,
-        # and continuous batching strictly beats static aligned batching
-        # on pad waste AND total kernel grid steps for the same trace
+        # serving arm: every request served, and continuous batching
+        # strictly beats static aligned batching on pad waste AND total
+        # kernel grid steps for the same trace
         assert serve["static"]["requests"] == serve["requests"]
         assert serve["continuous"]["requests"] == serve["requests"]
-        assert serve["resident_path_used"]
         assert (
             serve["continuous"]["pad_slot_fraction"]
             < serve["static"]["pad_slot_fraction"]
@@ -1591,8 +1424,7 @@ def run(quick: bool = False, arms=None):
 
     if "challenge" in arms:
         # Challenge arm: fixed config in quick AND full runs (like
-        # serve) — sized past the VMEM budget so the tiled fused route
-        # is what gets measured.
+        # serve).
         challenge = challenge_arm(
             neurons=16384,
             layers=6,
@@ -1612,11 +1444,11 @@ def run(quick: bool = False, arms=None):
             f"{challenge['edge_inputs_per_sec']:.3g} edge-inputs/s",
             flush=True,
         )
-        # challenge arm: the over-budget stack MUST take the tiled
-        # fused route end to end, and the engine's answer set must
-        # reproduce the numpy ground truth bit-for-bit
-        assert challenge["routes"] == ["fused-tiled"], challenge
-        assert challenge["levels"] == ["resident"], challenge
+        # challenge arm: the stack takes the layered route end to end,
+        # and the engine's answer set must reproduce the numpy ground
+        # truth bit-for-bit
+        assert challenge["routes"] == ["layered"], challenge
+        assert challenge["levels"] == ["layered"], challenge
         assert challenge["reference_match"], challenge
         assert 0 < challenge["n_categories"] < challenge["n_inputs"]
         assert challenge["served"] == challenge["n_inputs"]
@@ -1678,46 +1510,24 @@ def run(quick: bool = False, arms=None):
             block=16,
             width=64,
             reps=3,
-            neurons=8192,
-            layers=2,
-            radix_width=32,
-            density=0.3,
-            seed=2,
         )
-        sk, rad = tune["skewed"], tune["radix"]
+        sk = tune["skewed"]
         print(
             f"tune: skewed winner {sk['winner']}  steps "
             f"{sk['grid_steps_default']}→{sk['grid_steps_tuned']}  "
             f"wall {sk['wall_s_default']*1e3:.2f}ms"
-            f"→{sk['wall_s_tuned']*1e3:.2f}ms  |  "
-            f"radix {rad['neurons']}x{rad['layers']} "
-            f"{rad['winner']}: route {rad['route_default']}"
-            f"→{rad['route_tuned']} (panel "
-            f"{rad['vmem_bytes_f32']>>20}MiB→{rad['vmem_bytes_bf16']>>20}MiB"
-            f" vs {rad['vmem_soft_limit']>>20}MiB budget)  "
-            f"bf16 err {rad['bf16_max_abs_err']:.4f}",
+            f"→{sk['wall_s_tuned']*1e3:.2f}ms",
             flush=True,
         )
         # tune arm headline: the sweep's deterministic cost-model
         # scoring finds a config that STRICTLY beats the default — on
         # the skewed stack a forced block-CSR layout drops the exact
-        # grid-step bill, and on the over-budget RadiX-net stack bf16
-        # activation panels halve the resident footprint and move the
-        # route from fused-tiled back to fused, with numerics inside
-        # the gate on challenge-shaped inputs.
+        # grid-step bill.
         assert sk["winner"] == "layout=bcsr", sk
         assert sk["grid_steps_tuned"] < sk["grid_steps_default"], sk
         assert sk["block_work_tuned"] < sk["block_work_default"], sk
         assert sk["accuracy_ok"], sk
-        assert rad["route_default"] == "fused-tiled", rad
-        assert rad["route_tuned"] == "fused", rad
-        assert (
-            rad["vmem_bytes_bf16"]
-            <= rad["vmem_soft_limit"]
-            < rad["vmem_bytes_f32"]
-        ), rad
-        assert rad["bf16_max_abs_err"] <= 0.05, rad
-        assert tune["table_entries"] == 2, tune
+        assert tune["table_entries"] == 1, tune
         payload["tune"] = tune
         print(f"wrote {TUNING_TABLE_PATH}")
 

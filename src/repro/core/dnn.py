@@ -12,11 +12,6 @@ Execution modes:
 * ``fused=True`` — beyond-paper: one fused sparse-matmul + bias + max
   epilogue per layer (single activation stream; see DESIGN.md §2).
 
-* ``dnn_forward_resident`` — beyond-paper, deepest fusion: ONE Pallas
-  call for the whole homogeneous square stack, activations resident in
-  VMEM across layers (``repro.kernels.fused_mlp``); falls back to the
-  layered path when ineligible.
-
 Weight layouts: dense arrays, ELL-padded :class:`BlockSparseMatrix`
 (regular topologies) or occupancy-exact :class:`BlockCSRMatrix`
 (skewed/pruned topologies — kernel grid ∝ true nnz blocks).
@@ -36,8 +31,7 @@ forward — every sparse layer goes through the custom-VJP Pallas kernel
 wrappers (``repro.kernels.ops``), so the backward pass computes
 dX = Wᵀ·dY and sparse-preserving weight cotangents with no densify
 (``repro.kernels.autodiff``). ``dnn_value_and_grad`` packages the usual
-loss → (loss, (dweights, dbiases)) step; the resident fused path is
-forward-only and refuses differentiation.
+loss → (loss, (dweights, dbiases)) step.
 """
 
 from __future__ import annotations
@@ -111,20 +105,6 @@ def dnn_grid_steps(
     return _plan_cost.stack_grid_steps(weights, n, block_n=block_n)
 
 
-def _sharded_plan_forward(
-    weights: Sequence[Weight], biases: Sequence[Array], y0: Array, mesh
-) -> Array:
-    """The one mesh dispatch both forward wrappers share: fetch the
-    mesh-sharded plan for this panel width from the shared default
-    cache and run its shard_map executable."""
-    from repro.plan import default_cache
-
-    plan = default_cache().get(
-        weights, biases, max(y0.shape[1], 1), mesh=mesh
-    )
-    return plan.forward(y0)
-
-
 def dnn_layer(w: Weight, y: Array, b: Array, *, fused: bool = True) -> Array:
     """One forward layer: max(W·Y + b⊗1ᵀ, 0).  y: (m, n); b: (m,)."""
     if fused:
@@ -160,7 +140,12 @@ def dnn_forward(
     unchanged when ``mesh`` is None (the default).
     """
     if mesh is not None:
-        return _sharded_plan_forward(weights, biases, y0, mesh)
+        from repro.plan import default_cache
+
+        plan = default_cache().get(
+            weights, biases, max(y0.shape[1], 1), mesh=mesh
+        )
+        return plan.forward(y0)
     y = y0
     for w, b in zip(weights, biases):
         y = dnn_layer(w, y, b, fused=fused)
@@ -179,106 +164,6 @@ def dnn_forward_all(
     for w, b in zip(weights, biases):
         ys.append(dnn_layer(w, ys[-1], b, fused=fused))
     return ys
-
-
-def resident_eligible(
-    weights: Sequence[Weight], *, block_n: int = DEFAULT_BLOCK_N
-) -> bool:
-    """Can this stack run through the single-call VMEM-resident kernel?
-    (Alias of :func:`repro.plan.resident_eligible` — the route decision
-    tree lives in ``repro.plan.routes``.)"""
-    from repro.plan import routes as _plan_routes
-
-    return _plan_routes.resident_eligible(weights, block_n=block_n)
-
-
-def _has_tracers(*trees) -> bool:
-    return any(
-        isinstance(leaf, jax.core.Tracer)
-        for tree in trees
-        for leaf in jax.tree.leaves(tree)
-    )
-
-
-def dnn_forward_resident(
-    weights: Sequence[Weight],
-    biases: Sequence[Array],
-    y0: Array,
-    *,
-    block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool | None = None,
-    panel_dtype=None,
-    tuned=None,
-    mesh=None,
-) -> Array:
-    """L-layer forward with the activation panel resident in VMEM.
-
-    One ``pallas_call`` total (vs L for the layered path): eliminates
-    L−1 HBM activation round-trips. Stacks whose panel exceeds the VMEM
-    budget take the multi-panel tiled variant of the same single-call
-    kernel (HBM ping-pong panel, m tiled over the row-block grid); falls
-    back to ``dnn_forward(..., fused=True)`` when the stack is ineligible
-    for both (heterogeneous, dense, CSR-layout, or non-square).
-
-    A plan-backed wrapper: with default knobs the stack's route, layout
-    choices, and executable come from the shared
-    :class:`repro.plan.PlanCache` — repeated calls on the same topology
-    and panel width reuse one compiled plan. A ``tuned`` config
-    (``repro.tune.TunedConfig``) rides into the cache key, so tuned and
-    untuned calls on the same topology each keep their own compiled
-    plan. Explicit ``block_n``/``interpret``/``panel_dtype`` overrides
-    take the direct path, as does any call under trace (a traced
-    topology cannot be fingerprinted host-side, and a traced ``y0``
-    means someone is differentiating or vmapping through this
-    forward-only wrapper — the inline fallback keeps the legacy
-    XLA-differentiable behaviour for ineligible stacks).
-
-    ``mesh`` overrides residency entirely: the VMEM-resident kernel is
-    single-device, so a mesh routes through the sharded layered plan
-    (``repro.plan.ShardedStackPlan``) exactly like ``dnn_forward``.
-    """
-    if mesh is not None:
-        return _sharded_plan_forward(weights, biases, y0, mesh)
-    if (
-        block_n == DEFAULT_BLOCK_N
-        and interpret is None
-        and panel_dtype is None
-        and not _has_tracers(list(weights), list(biases), y0)
-    ):
-        from repro.plan import default_cache
-
-        plan = default_cache().get(
-            weights, biases, max(y0.shape[1], 1), tuned=tuned
-        )
-        return plan.forward(y0)
-    from repro.plan import routes as _plan_routes
-
-    route = _plan_routes.fused_route(
-        weights, block_n=block_n, panel_dtype=panel_dtype
-    )
-    if route is None:
-        return dnn_forward(weights, biases, y0, fused=True)
-    from repro.kernels import ops as kernel_ops
-
-    stacked_w = stack_bsr(list(weights))
-    stacked_b = jnp.stack(list(biases))
-    if route == _plan_routes.ROUTE_FUSED_TILED:
-        return kernel_ops.fused_mlp_tiled_forward(
-            stacked_w,
-            stacked_b,
-            y0,
-            block_n=block_n,
-            interpret=interpret,
-            panel_dtype=panel_dtype,
-        )
-    return kernel_ops.fused_mlp_forward(
-        stacked_w,
-        stacked_b,
-        y0,
-        block_n=block_n,
-        interpret=interpret,
-        panel_dtype=panel_dtype,
-    )
 
 
 def dnn_layer_trainable(
@@ -340,8 +225,7 @@ def dnn_forward_trainable(
     kernels (custom VJPs: sparse-preserving dW, occupancy-exact dX);
     ``use_kernel=False`` uses the jnp oracle paths (same math, XLA
     autodiff — the pragmatic choice on CPU where kernels interpret).
-    Both are ``jax.value_and_grad``-compatible; the resident fused
-    forward is NOT (see ``dnn_forward_resident``).
+    Both are ``jax.value_and_grad``-compatible.
 
     ``plan``: a differentiable :class:`repro.plan.StackPlan` built for
     this topology. Its cached block-CSR transposes make the backward
@@ -417,22 +301,3 @@ def dnn_forward_scan(
     y, _ = jax.lax.scan(body, y0, (stacked_weights, stacked_biases))
     return y
 
-
-def stack_bsr(mats: Sequence[BlockSparseMatrix]) -> BlockSparseMatrix:
-    """Stack same-topology-shape BSR matrices along a new leading axis so
-    they can be scanned over (weights of a deep sparse DNN)."""
-    first = mats[0]
-    for m in mats[1:]:
-        if (
-            m.shape != first.shape
-            or m.block_shape != first.block_shape
-            or m.max_blocks_per_row != first.max_blocks_per_row
-        ):
-            raise ValueError("stack_bsr requires homogeneous BSR structure")
-    return BlockSparseMatrix(
-        jnp.stack([m.blocks for m in mats]),
-        jnp.stack([m.col_idx for m in mats]),
-        jnp.stack([m.block_mask for m in mats]),
-        first.shape,
-        first.block_shape,
-    )

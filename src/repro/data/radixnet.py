@@ -240,8 +240,7 @@ def conn_to_bsr(
     ``block_size²`` tiles holding 1/16 at the exact (row, col) positions
     of ``conn`` and 0 elsewhere. ``pad_blocks_per_row`` right-pads the
     ELL slot axis with masked-off blocks so layers of different phases
-    can stack homogeneously (``stack_bsr`` and the fused kernels require
-    one ``max_blocks_per_row`` across the stack).
+    share one ``max_blocks_per_row``.
     """
     import jax.numpy as jnp
 
@@ -303,8 +302,7 @@ def radixnet_weights(
     All layers share one ``max_blocks_per_row`` (the max over the spec's
     phases — butterfly phases past stride ``block_size`` store 32
     diagonal blocks, the stride-1 phase stores ``32/block_size`` dense
-    ones), so the stack is eligible for the fused single-``pallas_call``
-    routes.
+    ones), so every layer has the same ELL shape.
     """
     import jax.numpy as jnp
 

@@ -1,6 +1,6 @@
 """Pallas TPU kernels + jit'd wrappers (``ops``) and jnp oracles (``ref``).
 
-Kernel menu and when dispatch picks which (see ``repro.core.dnn``):
+Kernel menu and when dispatch picks which (see ``repro.plan.routes``):
 
   semiring_matmul — dense ⊕.⊗ with fused bias/ReLU epilogue; the BLAS
       arm and the fallback for weights with no sparse structure.
@@ -9,10 +9,8 @@ Kernel menu and when dispatch picks which (see ``repro.core.dnn``):
       block-row stores ≈ the same number of blocks.
   bcsr_spmm       — occupancy-exact block-CSR × dense. Grid ``(n_tiles,
       total_nnz_blocks)``: compute and DMA scale with true nnz, the
-      right arm for skewed or magnitude-pruned topologies.
-  fused_mlp       — VMEM-resident multi-layer forward for square
-      ``stack_bsr`` stacks: one ``pallas_call`` for all L layers, no
-      inter-layer HBM activation traffic. Forward-only (no VJP).
+      right arm for skewed or magnitude-pruned topologies, and the one
+      every RadiX-net layer runs (``repro.plan.components``).
 
 ``autodiff`` holds the ``jax.custom_vjp`` rules that make the two SpMM
 wrappers trainable (sparse-preserving weight cotangents, kernel-

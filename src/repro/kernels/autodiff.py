@@ -31,10 +31,6 @@ Backward-pass routing:
     a traced weight cannot provide, so dB uses the occupancy-exact
     scatter-⊕ (``sparse.ops.bsr_transpose_matmul``) and dA the sampled
     product; both scale with stored blocks, neither densifies.
-  * ``fused_mlp`` — the VMEM-resident multi-layer kernel has NO VJP (its
-    per-layer activations never exist outside VMEM, so nothing can be
-    checkpointed); its rule raises with a pointer to the layered path.
-    ``serve.SparseDNNEngine(differentiable=True)`` routes around it.
 
 Only the arithmetic (``plus_times``) semiring is differentiable — ReLU
 is the fused max-plus step and its subgradient is handled here; the
@@ -61,7 +57,6 @@ from jax import dtypes as jax_dtypes
 from repro.kernels import DEFAULT_BLOCK_N
 from repro.kernels import bcsr_spmm as _bcsr
 from repro.kernels import bsr_spmm as _bsr
-from repro.kernels import fused_mlp as _fmlp
 from repro.sparse import ops as sparse_ops
 from repro.sparse.bcsr import BlockCSRMatrix
 from repro.sparse.bsr import BlockSparseMatrix
@@ -235,84 +230,3 @@ def _bcsr_bwd(cfg, res, g):
 
 bcsr_spmm_diff.defvjp(_bcsr_fwd, _bcsr_bwd)
 
-
-# --------------------------------------------------------------------------
-# VMEM-resident fused multi-layer forward: explicitly NOT differentiable
-# --------------------------------------------------------------------------
-
-
-class FusedMlpConfig(NamedTuple):
-    block_n: int = DEFAULT_BLOCK_N
-    interpret: bool = False
-    # activation-panel dtype name ("bfloat16" halves the resident VMEM
-    # footprint; accumulation stays f32) — None keeps float32 panels
-    panel_dtype: str | None = None
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def fused_mlp_forward_nondiff(
-    cfg: FusedMlpConfig, stacked_w: BlockSparseMatrix, stacked_b: Array, y0: Array
-):
-    """The fused kernel with a VJP rule that fails loudly (instead of the
-    opaque pallas_call transpose error) and says what to use instead."""
-    return _fmlp.fused_mlp_forward(
-        stacked_w,
-        stacked_b,
-        y0,
-        block_n=cfg.block_n,
-        interpret=cfg.interpret,
-        panel_dtype=cfg.panel_dtype,
-    )
-
-
-def _fused_fwd(cfg, stacked_w, stacked_b, y0):
-    return fused_mlp_forward_nondiff(cfg, stacked_w, stacked_b, y0), None
-
-
-def _fused_bwd(cfg, res, g):
-    raise NotImplementedError(
-        "fused_mlp_forward has no VJP: the VMEM-resident kernel never "
-        "materializes per-layer activations, so there is nothing to "
-        "checkpoint for the backward pass. Differentiate the layered "
-        "kernel path instead (repro.core.dnn.dnn_forward_trainable, or "
-        "serve.SparseDNNEngine(differentiable=True) which routes around "
-        "the fused path automatically)."
-    )
-
-
-fused_mlp_forward_nondiff.defvjp(_fused_fwd, _fused_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def fused_mlp_tiled_forward_nondiff(
-    cfg: FusedMlpConfig, stacked_w: BlockSparseMatrix, stacked_b: Array, y0: Array
-):
-    """The tiled fused kernel (HBM ping-pong panel) with the same
-    fails-loudly VJP story as the resident kernel: per-layer activations
-    only ever exist in the kernel's scratch buffers."""
-    return _fmlp.fused_mlp_tiled_forward(
-        stacked_w,
-        stacked_b,
-        y0,
-        block_n=cfg.block_n,
-        interpret=cfg.interpret,
-        panel_dtype=cfg.panel_dtype,
-    )
-
-
-def _fused_tiled_fwd(cfg, stacked_w, stacked_b, y0):
-    return fused_mlp_tiled_forward_nondiff(cfg, stacked_w, stacked_b, y0), None
-
-
-def _fused_tiled_bwd(cfg, res, g):
-    raise NotImplementedError(
-        "fused_mlp_tiled_forward has no VJP: per-layer activations only "
-        "exist in the kernel's HBM/VMEM scratch, so there is nothing to "
-        "checkpoint for the backward pass. Differentiate the layered "
-        "kernel path instead (repro.core.dnn.dnn_forward_trainable, or "
-        "serve.SparseDNNEngine(differentiable=True) which routes around "
-        "the fused paths automatically)."
-    )
-
-
-fused_mlp_tiled_forward_nondiff.defvjp(_fused_tiled_fwd, _fused_tiled_bwd)

@@ -2,8 +2,7 @@
 
 One wrapper per kernel: ``semiring_matmul`` (dense), ``bsr_spmm``
 (ELL grid), ``bcsr_spmm`` (occupancy-exact CSR grid — also fills the
-empty block-rows the kernel grid never visits), ``fused_mlp_forward``
-(VMEM-resident multi-layer, single pallas_call). See the package
+empty block-rows the kernel grid never visits). See the package
 docstring for when dispatch picks which.
 
 On TPU the kernels run compiled; everywhere else (this container is
@@ -19,8 +18,7 @@ sparse wrappers route through the ``jax.custom_vjp`` rules of
 ``repro.kernels.autodiff`` — ``jax.grad`` through ``bsr_spmm`` /
 ``bcsr_spmm`` yields sparse-preserving weight cotangents (same layout as
 the primal, no densify) and ``Aᵀ·dY`` operand gradients. Other semirings
-keep the primal-only kernel path. ``fused_mlp_forward`` is NOT
-differentiable and says so if asked.
+keep the primal-only kernel path.
 """
 
 from __future__ import annotations
@@ -29,7 +27,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import DEFAULT_BLOCK_N
 from repro.kernels import autodiff as _ad
@@ -135,11 +132,6 @@ def effective_block_n(n: int, block_n: int = DEFAULT_BLOCK_N) -> int:
     return min(block_n, _ceil_mult(n))
 
 
-def _panel_dtype_name(panel_dtype) -> str | None:
-    """Canonical (hashable) panel-dtype name for the static jit config."""
-    return None if panel_dtype is None else str(np.dtype(panel_dtype))
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("semiring_name", "fuse_bias_relu", "block_n", "interpret"),
@@ -241,65 +233,3 @@ def bcsr_spmm(
     )
     return jnp.where(row_empty[:, None], fill[:, None], out)
 
-
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret", "panel_dtype")
-)
-def fused_mlp_forward(
-    stacked_w: BlockSparseMatrix,
-    stacked_b: Array,
-    y0: Array,
-    *,
-    block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool | None = None,
-    panel_dtype=None,
-) -> Array:
-    """Padded, jit'd VMEM-resident L-layer forward — ONE pallas_call.
-
-    ``stacked_w``: BlockSparseMatrix whose leaves carry a leading L axis
-    (see ``repro.core.dnn.stack_bsr``); square layers only. The
-    activation panel never round-trips to HBM between layers.
-    ``panel_dtype=jnp.bfloat16`` halves the resident panel's VMEM bill
-    (f32 accumulate, result cast back — see ``kernels.fused_mlp``).
-
-    NOT differentiable (per-layer activations never leave VMEM, so there
-    is nothing to checkpoint): ``jax.grad`` through this raises with a
-    pointer to the layered path (``dnn_forward_trainable``).
-    """
-    interpret = auto_interpret() if interpret is None else interpret
-    n = y0.shape[1]
-    block_n = effective_block_n(n, block_n)
-    yp = _pad_to(y0, 1, block_n)
-    cfg = _ad.FusedMlpConfig(block_n, interpret, _panel_dtype_name(panel_dtype))
-    out = _ad.fused_mlp_forward_nondiff(cfg, stacked_w, stacked_b, yp)
-    return out[:, :n]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret", "panel_dtype")
-)
-def fused_mlp_tiled_forward(
-    stacked_w: BlockSparseMatrix,
-    stacked_b: Array,
-    y0: Array,
-    *,
-    block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool | None = None,
-    panel_dtype=None,
-) -> Array:
-    """Padded, jit'd multi-panel tiled L-layer forward — ONE pallas_call.
-
-    The route for homogeneous square stacks whose activation panel
-    exceeds ``VMEM_SOFT_LIMIT_BYTES``: the ping-pong panel lives in HBM
-    (a second kernel output) and the m dimension is tiled over the row-block grid
-    (``repro.kernels.fused_mlp.fused_mlp_tiled_forward``). Same
-    forward-only contract as ``fused_mlp_forward``, including bf16
-    activation panels via ``panel_dtype``.
-    """
-    interpret = auto_interpret() if interpret is None else interpret
-    n = y0.shape[1]
-    block_n = effective_block_n(n, block_n)
-    yp = _pad_to(y0, 1, block_n)
-    cfg = _ad.FusedMlpConfig(block_n, interpret, _panel_dtype_name(panel_dtype))
-    out = _ad.fused_mlp_tiled_forward_nondiff(cfg, stacked_w, stacked_b, yp)
-    return out[:, :n].astype(jnp.result_type(stacked_w.dtype, y0.dtype))

@@ -59,24 +59,3 @@ def bcsr_spmm_ref(
         out = jnp.maximum(out + bias.astype(jnp.float32)[:, None], 0.0)
     return out
 
-
-def fused_mlp_forward_ref(
-    stacked_w: BlockSparseMatrix,
-    stacked_b: Array,
-    y0: Array,
-) -> Array:
-    """Layer-by-layer reference for the VMEM-resident fused forward."""
-    n_layers = stacked_b.shape[0]
-    y = y0.astype(jnp.float32)
-    for l in range(n_layers):
-        w_l = BlockSparseMatrix(
-            stacked_w.blocks[l].astype(jnp.float32),
-            stacked_w.col_idx[l],
-            stacked_w.block_mask[l],
-            stacked_w.shape,
-            stacked_w.block_shape,
-        )
-        y = sparse_ops.bsr_matmul_fused_relu(
-            w_l, y, stacked_b[l].astype(jnp.float32)
-        )
-    return y

@@ -2,8 +2,8 @@
 
 The paper's economics — inference cost ∝ stored nonzeros — assume the
 per-topology analysis is free. It is, but only if it happens once: this
-package compiles a sparse stack's layout choices, route (fused /
-layered / XLA), exact grid-step bill, cached block-CSR backward
+package compiles a sparse stack's layout choices, route (layered /
+XLA / sharded), exact grid-step bill, cached block-CSR backward
 transpose, and a per-width-class jitted executable into a
 :class:`StackPlan`, cached in a :class:`PlanCache` keyed by
 ``(topology fingerprint, width class, differentiable?)``. Every
@@ -25,7 +25,6 @@ from repro.plan.cost import (  # noqa: F401
 )
 from repro.plan.degrade import (  # noqa: F401
     LEVEL_LAYERED,
-    LEVEL_RESIDENT,
     LEVEL_SHARDED,
     DegradationLadder,
     DegradeEvent,
@@ -43,15 +42,10 @@ from repro.plan.mxm import (  # noqa: F401
     reset_mxm_cache,
 )
 from repro.plan.routes import (  # noqa: F401
-    ROUTE_FUSED,
-    ROUTE_FUSED_TILED,
     ROUTE_LAYERED,
     ROUTE_SHARDED,
     ROUTE_XLA,
-    fused_route,
     layer_path,
-    resident_eligible,
-    takes_component_layout,
 )
 from repro.plan.sharded import (  # noqa: F401
     ShardedLayerPlan,
@@ -72,13 +66,10 @@ from repro.plan.stack_plan import (  # noqa: F401
 __all__ = [
     "ELL_WASTE_THRESHOLD",
     "DEFAULT_WIDTH_CLASSES",
-    "ROUTE_FUSED",
-    "ROUTE_FUSED_TILED",
     "ROUTE_LAYERED",
     "ROUTE_SHARDED",
     "ROUTE_XLA",
     "LEVEL_LAYERED",
-    "LEVEL_RESIDENT",
     "LEVEL_SHARDED",
     "DegradationLadder",
     "DegradeEvent",
@@ -91,7 +82,6 @@ __all__ = [
     "build_plan",
     "build_sharded_plan",
     "default_cache",
-    "fused_route",
     "layer_block_area",
     "layer_grid_steps",
     "layer_layout",
@@ -101,9 +91,7 @@ __all__ = [
     "preferred_layout",
     "quantize_width",
     "reset_default_cache",
-    "resident_eligible",
     "stack_grid_steps",
-    "takes_component_layout",
     "to_preferred_layout",
     "topology_fingerprint",
 ]

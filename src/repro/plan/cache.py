@@ -5,8 +5,8 @@ serving looks a plan up per dispatched panel, and after the first panel
 of each width class every lookup is a hit — zero layout decisions, zero
 grid-step sums, zero topology sorts, zero recompiles on the hot path.
 
-Keying: ``(topology fingerprint, width class, differentiable?,
-requested residency)`` — see :class:`repro.plan.PlanKey`. Because plans
+Keying: ``(topology fingerprint, width class, differentiable?, mesh,
+tuning)`` — see :class:`repro.plan.PlanKey`. Because plans
 bind weight/bias VALUES (serving weights are frozen), a hit additionally
 requires the cached plan's bound arrays to be the same objects the
 caller passed; a same-topology stack with different value arrays
@@ -72,7 +72,6 @@ class PlanCache:
         width: int,
         *,
         differentiable: bool = False,
-        use_resident: bool | None = None,
         relayout: bool | None = None,
         fingerprint: str | None = None,
         mesh=None,
@@ -104,8 +103,7 @@ class PlanCache:
         mesh_fp = None if mesh is None else _sharded.mesh_fingerprint(mesh)
         tuned_token = None if tuned is None else tuned.token()
         key = PlanKey(
-            fingerprint, width, differentiable, use_resident, mesh_fp,
-            tuned=tuned_token,
+            fingerprint, width, differentiable, mesh=mesh_fp, tuned=tuned_token
         )
         self.lookups += 1
         plan = self._entries.get(key)
@@ -119,17 +117,16 @@ class PlanCache:
             self._entries.move_to_end(key)
             return plan
         self.misses += 1
-        # A resident plan for the same stack at ANOTHER width class can
-        # donate its width-independent artifacts (relayouted weights,
-        # cached transposes, fused stack; for sharded plans: partition
-        # layouts and per-shard transposes) — only the executable and
-        # the grid-step bill are per-width.
+        # A cached plan for the same stack at ANOTHER width class
+        # donates its width-independent artifacts (relayouted weights
+        # and biases, row gathers, cached transposes; for sharded plans:
+        # partition layouts and per-shard transposes) — only the
+        # executable and the grid-step bills are per-width.
         donor = None
         for cand in reversed(self._entries.values()):
             if (
                 cand.key.fingerprint == fingerprint
                 and cand.differentiable == differentiable
-                and cand.key.resident == use_resident
                 and cand.key.mesh == mesh_fp
                 and cand.key.tuned == tuned_token
                 and len(cand.source_weights) == len(weights)
@@ -148,7 +145,6 @@ class PlanCache:
                     width,
                     mesh,
                     differentiable=differentiable,
-                    use_resident=use_resident,
                     fingerprint=fingerprint,
                     donor=donor,
                 )
@@ -158,7 +154,6 @@ class PlanCache:
                     biases,
                     width,
                     differentiable=differentiable,
-                    use_resident=use_resident,
                     relayout=relayout,
                     fingerprint=fingerprint,
                     donor=donor,
@@ -188,7 +183,7 @@ class PlanCache:
 
 
 # Shared cache behind the module-level convenience wrappers
-# (repro.core.dnn.dnn_forward_resident and friends). Engines own their
+# (repro.core.dnn.dnn_forward with a mesh). Engines own their
 # own caches; this one serves ad-hoc functional callers. Plans hold
 # strong references to the weight stacks they bind, so this cache is
 # kept SMALL — loops over many transient models retain at most

@@ -33,9 +33,9 @@ rounding error; see PERF.md.)
 The plan (``repro.plan.stack_plan``) runs the re-laid block-CSR weight
 through the same ``bcsr_spmm`` kernel, holds each layer's activations in
 that layer's row order, and gathers them into the next component
-layer's column order in between. Host-side, once per distinct weight
-object: the route choice (``repro.plan.routes.takes_component_layout``)
-and the plan build read the same result.
+layer's column order in between. Detection runs host-side and is
+memoised per distinct weight object, so every plan built over it reads
+the same result.
 """
 
 from __future__ import annotations

@@ -67,13 +67,8 @@ def mxv_grid_steps(w: Weight, *, block_n: int = DEFAULT_BLOCK_N) -> int:
 def stack_grid_steps(
     weights: Sequence[Weight], n: int, *, block_n: int = DEFAULT_BLOCK_N
 ) -> int:
-    """Total forward grid steps of the L-layer stack on an (m, n) panel.
-
-    The VMEM-resident fused kernel's grid is ``(n_tiles, L, nrb, mbpr)``
-    — exactly the Σ of its layers' ELL grids — so this sum is the step
-    count for BOTH the layered and the resident dispatch; residency
-    changes pallas_call count and HBM traffic, not grid steps.
-    """
+    """Total forward grid steps of the L-layer stack on an (m, n) panel:
+    the Σ of its layers' grids, one kernel launch each."""
     return sum(layer_grid_steps(w, n, block_n=block_n) for w in weights)
 
 

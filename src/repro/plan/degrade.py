@@ -1,4 +1,4 @@
-"""Graceful plan degradation: sharded → single-device → layered.
+"""Graceful plan degradation: sharded → single-device layered.
 
 A :class:`repro.plan.PlanCache` answers "the plan for this (stack,
 width)"; this module answers "the plan for this (stack, width) *given
@@ -7,15 +7,13 @@ serving engine can run a fingerprinted stack at:
 
 1. ``sharded``  — mesh-sharded :class:`~repro.plan.ShardedStackPlan`
    (only when the engine was built with a mesh and it is healthy);
-2. ``resident`` — single-device VMEM-resident fused plan (only when the
-   engine resolved residency and no compile failure demoted it);
-3. ``layered``  — single-device per-layer kernel plan, the floor: it
+2. ``layered``  — single-device per-layer kernel plan, the floor: it
    needs nothing but one device and always exists.
 
 ``get_plan`` walks the ladder top-down and returns the first level that
-produces a plan. A level that fails to build — a plan-compile failure,
-a VMEM-guard rejection, an injected fault — is marked unhealthy and the
-walk continues downward, so **in-flight requests are never dropped**: a
+produces a plan. A level that fails to build — a plan-compile failure
+or an injected fault — is marked unhealthy and the walk continues
+downward, so **in-flight requests are never dropped**: a
 shard failure mid-stream re-plans the same fingerprint on a single
 device and the panel that triggered the fallback is still served (the
 plan cache already holds or builds the lower-level plan for the same
@@ -35,7 +33,6 @@ import dataclasses
 from typing import Callable
 
 LEVEL_SHARDED = "sharded"
-LEVEL_RESIDENT = "resident"
 LEVEL_LAYERED = "layered"
 
 
@@ -52,40 +49,31 @@ class DegradeEvent:
 class DegradationLadder:
     """Health-aware plan lookup over a :class:`~repro.plan.PlanCache`.
 
-    ``mesh``/``use_resident`` describe the engine's *preferred* level;
-    the ladder serves the highest healthy level at or below it. The
+    ``mesh`` decides the engine's *preferred* level; the ladder serves
+    the highest healthy level at or below it. The
     floor level (``layered``) cannot be demoted — a failure there
     propagates, because there is nothing left to degrade to.
     """
 
-    def __init__(
-        self, cache, *, mesh=None, use_resident: bool = False, tuned=None
-    ):
+    def __init__(self, cache, *, mesh=None, tuned=None):
         self.cache = cache
         self.mesh = mesh
-        self.use_resident = bool(use_resident)
         # Tuned kernel config (repro.tune.TunedConfig) applied at the
         # single-device levels; the sharded builder takes no tuning
         # knobs, so a sharded lookup always passes tuned=None.
         self.tuned = tuned
-        self._healthy = {LEVEL_SHARDED: True, LEVEL_RESIDENT: True}
+        self._healthy = {LEVEL_SHARDED: True}
         self.events: list[DegradeEvent] = []
 
     @property
     def preferred_level(self) -> str:
-        if self.mesh is not None:
-            return LEVEL_SHARDED
-        if self.use_resident:
-            return LEVEL_RESIDENT
-        return LEVEL_LAYERED
+        return LEVEL_SHARDED if self.mesh is not None else LEVEL_LAYERED
 
     def levels(self) -> list[str]:
         """Currently serviceable levels, most preferred first."""
         out = []
         if self.mesh is not None and self._healthy[LEVEL_SHARDED]:
             out.append(LEVEL_SHARDED)
-        if self.use_resident and self._healthy[LEVEL_RESIDENT]:
-            out.append(LEVEL_RESIDENT)
         out.append(LEVEL_LAYERED)
         return out
 
@@ -128,7 +116,7 @@ class DegradationLadder:
         """(plan, level, cache_hit) at the best healthy level.
 
         ``compile_hook(level)`` runs before each level's cache lookup;
-        raising from it (fault injection, VMEM guards) demotes that
+        raising from it (fault injection) demotes that
         level and falls through. Only the floor's failure propagates.
         """
         last_err: Exception | None = None
@@ -142,7 +130,6 @@ class DegradationLadder:
                     biases,
                     width,
                     differentiable=differentiable,
-                    use_resident=level == LEVEL_RESIDENT,
                     fingerprint=fingerprint,
                     mesh=self.mesh if level == LEVEL_SHARDED else None,
                     tuned=self.tuned if level != LEVEL_SHARDED else None,

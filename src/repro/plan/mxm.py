@@ -123,7 +123,6 @@ def mxm_plan(
         fingerprint=topology_fingerprint([a]),
         width=n,
         differentiable=False,
-        resident=False,
         semiring=semiring_name,
     )
     _stats["lookups"] += 1

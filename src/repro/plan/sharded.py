@@ -368,7 +368,6 @@ def build_sharded_plan(
     mesh: Mesh,
     *,
     differentiable: bool = False,
-    use_resident: bool | None = None,
     fingerprint: str | None = None,
     donor: "ShardedStackPlan | None" = None,
 ) -> ShardedStackPlan:
@@ -380,8 +379,7 @@ def build_sharded_plan(
     re-laid to block-CSR at build time for inference plans (the segment
     layout is what partitions) and **rejected** for differentiable plans
     (cotangents must mirror the caller's layout — convert the stack to
-    block-CSR first); dense layers run replicated. ``use_resident=True``
-    is refused — the VMEM-resident fused kernel is single-device.
+    block-CSR first); dense layers run replicated.
 
     ``donor``: an existing sharded plan for the same (stack, mesh,
     differentiability) at another width class; partition artifacts and
@@ -401,18 +399,12 @@ def build_sharded_plan(
         raise ValueError("weights/biases length mismatch")
     if width < 1:
         raise ValueError(f"width must be >= 1, got {width}")
-    if use_resident:
-        raise ValueError(
-            "use_resident=True is incompatible with mesh sharding: the "
-            "VMEM-resident fused kernel runs a single device's VMEM; "
-            "sharded plans always take the layered per-shard route"
-        )
     if fingerprint is None:
         fingerprint = topology_fingerprint(weights)
     axes = row_block_axes(mesh)
     n_shards = mesh_shard_count(mesh)
     mesh_fp = mesh_fingerprint(mesh)
-    key = PlanKey(fingerprint, width, differentiable, use_resident, mesh_fp)
+    key = PlanKey(fingerprint, width, differentiable, mesh=mesh_fp)
 
     if donor is not None and (
         donor.key.fingerprint != fingerprint

@@ -1,7 +1,7 @@
 """Compile-once execution plans for sparse DNN stacks.
 
 Every entry point used to re-derive *how* to run a stack on every call:
-layout choice, fused-residency eligibility, grid-step billing, and —
+layout choice, grid-step billing, and —
 worst — the block-CSR backward re-sorted the frozen topology every
 single backward pass. A :class:`StackPlan` does all of that analysis
 ONCE per ``(topology-fingerprint, panel-width class, differentiable?)``
@@ -12,7 +12,7 @@ the per-topology analysis should be too) and carries:
   ``repro.plan.layout``, applied at build time instead of per call, or
   the component layout of ``repro.plan.components`` with the row
   gathers that carry activations between its row and column orders);
-* the route — fused / layered / XLA fallback (``repro.plan.routes``);
+* the route — layered, or the XLA fallback (``repro.plan.routes``);
 * the exact grid-step bill for the plan's panel width
   (``repro.plan.cost``);
 * the **cached block-CSR transpose** (sorted layout + permutation,
@@ -94,8 +94,8 @@ def topology_fingerprint(weights: Sequence[Weight]) -> str:
 
 class PlanKey(NamedTuple):
     """What a compiled plan is keyed on. Same topology + same width
-    class + same differentiability (+ same residency request, + same
-    mesh) → the same plan, hence a cache hit and zero recompiles.
+    class + same differentiability (+ same mesh) → the same plan, hence
+    a cache hit and zero recompiles.
 
     ``mesh`` is the mesh/shard fingerprint
     (:func:`repro.plan.sharded.mesh_fingerprint`) for sharded plans and
@@ -116,7 +116,6 @@ class PlanKey(NamedTuple):
     fingerprint: str
     width: int
     differentiable: bool
-    resident: bool | None  # the use_resident tri-state the caller asked
     mesh: str | None = None  # mesh/shard fingerprint, None = unsharded
     tuned: str | None = None  # TunedConfig token, None = default constants
     semiring: str = "plus_times"  # the plan's ⊕.⊗ algebra
@@ -129,7 +128,7 @@ class LayerPlan:
     index: int
     source_layout: str  # layout of the caller's weight ("dense"/"ell"/"bcsr")
     layout: str  # execution layout: "dense"/"ell"/"bcsr"/"component"
-    path: str  # routes.layer_path value, or "fused"/"fused-tiled"
+    path: str  # routes.layer_path value
     grid_steps: int  # exact bill at the plan's width
     transpose_plan: BcsrTransposePlan | None  # cached backward transpose
     # Row gather into this layer's column order, applied to the held
@@ -150,7 +149,7 @@ class StackPlan:
     """
 
     key: PlanKey
-    route: str  # routes.ROUTE_FUSED / ROUTE_FUSED_TILED / ROUTE_LAYERED / ROUTE_XLA
+    route: str  # routes.ROUTE_LAYERED / ROUTE_XLA
     layers: tuple[LayerPlan, ...]
     width: int
     differentiable: bool
@@ -163,7 +162,6 @@ class StackPlan:
     # Row gather back to natural order after the last layer (None: the
     # last layer's rows are in natural order).
     out_gather: Array | None = None
-    _stacked: tuple | None = None  # (stacked_w, stacked_b) for fused
     # The layered executable's (weights, biases, gathers): each object once
     _args: tuple | None = None
     _fn: Callable | None = None
@@ -174,18 +172,8 @@ class StackPlan:
         return len(self.layers)
 
     @property
-    def is_fused_route(self) -> bool:
-        """Single-``pallas_call`` whole-stack route (resident or tiled)."""
-        return self.route in (
-            _routes.ROUTE_FUSED,
-            _routes.ROUTE_FUSED_TILED,
-        )
-
-    @property
     def pallas_calls(self) -> int:
         """Kernel launches one forward of this plan performs."""
-        if self.is_fused_route:
-            return 1
         return sum(1 for lp in self.layers if lp.path != "xla-dense")
 
     @property
@@ -208,9 +196,9 @@ class StackPlan:
 
     @property
     def weight_args(self) -> int:
-        """Weight arrays the executable takes: one stacked weight on a
-        fused route, else each distinct layer weight once."""
-        return 1 if self.is_fused_route else len(self._args[0])
+        """Weight arrays the executable takes: each distinct layer weight
+        once."""
+        return len(self._args[0])
 
     @property
     def gathers(self) -> tuple[Array | None, ...]:
@@ -252,20 +240,14 @@ class StackPlan:
             )
         if k < self.width:
             y0 = jnp.pad(y0, ((0, 0), (0, self.width - k)))
-        return self._fn(*self._bound(), y0)[:, :k]
-
-    def _bound(self) -> tuple:
-        """The executable's arguments besides the panel."""
-        if self.is_fused_route:
-            return self._stacked
-        return self._args
+        return self._fn(*self._args, y0)[:, :k]
 
     def lower(self, dtype=jnp.float32):
         """The executable lowered for one panel of this width class, ahead
         of time: ``.compile().as_text()`` shows what the device runs
         (``tpu_custom_call`` for each Pallas kernel)."""
         y = jax.ShapeDtypeStruct((self.weights[0].shape[1], self.width), dtype)
-        return self._fn.lower(*self._bound(), y)
+        return self._fn.lower(*self._args, y)
 
     def forward_trainable(
         self,
@@ -310,27 +292,6 @@ def _make_executable(plan: StackPlan) -> Callable:
     # Tuned plans thread their overrides into every kernel call; untuned
     # plans pass nothing so the wrappers run on the hand-picked defaults.
     block_n = _tuned_attr(plan.tuned, "block_n") or DEFAULT_BLOCK_N
-    panel_dtype = _tuned_attr(plan.tuned, "panel_dtype")
-    fused_kw = {"block_n": block_n, "panel_dtype": panel_dtype}
-
-    if plan.route == _routes.ROUTE_FUSED:
-
-        def run_fused(stacked_w, stacked_b, y):
-            plan._compiles += 1
-            return kernel_ops.fused_mlp_forward(stacked_w, stacked_b, y, **fused_kw)
-
-        return jax.jit(run_fused)
-
-    if plan.route == _routes.ROUTE_FUSED_TILED:
-
-        def run_fused_tiled(stacked_w, stacked_b, y):
-            plan._compiles += 1
-            return kernel_ops.fused_mlp_tiled_forward(
-                stacked_w, stacked_b, y, **fused_kw
-            )
-
-        return jax.jit(run_fused_tiled)
-
     paths = tuple(lp.path for lp in plan.layers)
     tps = plan.transpose_plans
     # A stack that repeats a few phase weights through its layers passes
@@ -446,7 +407,6 @@ def build_plan(
     width: int,
     *,
     differentiable: bool = False,
-    use_resident: bool | None = None,
     relayout: bool | None = None,
     fingerprint: str | None = None,
     donor: "StackPlan | None" = None,
@@ -454,33 +414,28 @@ def build_plan(
 ) -> StackPlan:
     """Compile one :class:`StackPlan` (all the per-topology analysis).
 
-    ``use_resident``: None auto-detects — a fused route where one fits,
-    unless every distinct weight has a component layout
-    (``repro.plan.routes.takes_component_layout``) — True demands the
-    fused route (ValueError when ineligible), False forces the layered
-    route: the ``SparseDNNEngine`` tri-state, verbatim. ``relayout`` applies
-    the component layout (``repro.plan.components``) or else the
-    ELL→CSR waste heuristic to the bound execution weights; default on
-    for inference plans, always off for differentiable plans (their
-    cotangents must mirror the caller's layout).
+    ``relayout`` applies the component layout
+    (``repro.plan.components``) to each weight that has one, and the
+    ELL→CSR waste heuristic to the rest, in the bound execution
+    weights; default on for inference plans, always off for
+    differentiable plans (their cotangents must mirror the caller's
+    layout).
 
     ``donor``: an existing plan for the SAME stack (same fingerprint,
-    differentiability, and residency request) at a different width
-    class. Only the width-dependent pieces (grid-step bill, executable)
-    are rebuilt; the width-independent topology artifacts — relayouted
-    execution weights and biases, row gathers, cached transposes (so
-    the topology is still sorted exactly once no matter how many width
-    classes serve it), and the fused weight stack — are shared by
-    reference.
+    differentiability and tuning) at a different width class. Only the
+    width-dependent pieces (grid-step bills, executable) are rebuilt;
+    the width-independent topology artifacts — relayouted execution
+    weights and biases, row gathers and cached transposes (so the
+    topology is still sorted exactly once no matter how many width
+    classes serve it) — are shared by reference.
     ``PlanCache.get`` supplies this automatically.
 
     ``tuned``: a :class:`repro.tune.TunedConfig` (duck-typed — the plan
     layer only reads its fields) consulted BEFORE the hand-picked
     defaults: ``block_n`` feeds every kernel call and the grid bill,
-    ``panel_dtype``/``vmem_limit_bytes`` move the resident↔tiled
-    boundary, ``layout`` overrides the ELL-waste heuristic, and
-    ``block_size`` re-blocks layered execution weights; either of the
-    last two keeps the component layout off. The config's
+    ``layout`` overrides the ELL-waste heuristic, and ``block_size``
+    re-blocks layered execution weights; either of the last two keeps
+    the component layout off. The config's
     token lands in :attr:`PlanKey.tuned` so tuned and untuned plans
     never collide in a :class:`~repro.plan.PlanCache`.
     """
@@ -495,34 +450,9 @@ def build_plan(
 
     tuned_token = None if tuned is None else tuned.token()
     t_block_n = _tuned_attr(tuned, "block_n") or DEFAULT_BLOCK_N
-    t_panel = _tuned_attr(tuned, "panel_dtype")
-    t_vmem = _tuned_attr(tuned, "vmem_limit_bytes")
     t_layout = _tuned_attr(tuned, "layout")
     t_block_size = _tuned_attr(tuned, "block_size")
 
-    # fused_ok: which single-pallas_call route structurally fits —
-    # ROUTE_FUSED (panel resident in VMEM), ROUTE_FUSED_TILED (panel
-    # past the VMEM budget, ping-ponged through HBM), or None.
-    fused_ok = (
-        None
-        if differentiable
-        else _routes.fused_route(
-            weights,
-            block_n=t_block_n,
-            panel_dtype=t_panel,
-            vmem_limit=t_vmem,
-        )
-    )
-    if use_resident and fused_ok is None:
-        raise ValueError(
-            "use_resident=True but the stack is not eligible for the "
-            "fused whole-stack kernels"
-            + (
-                " (differentiable plans route around their missing VJP)"
-                if differentiable
-                else " (needs a homogeneous square BSR stack)"
-            )
-        )
     if relayout is None:
         relayout = not differentiable
     if differentiable and relayout:
@@ -531,27 +461,16 @@ def build_plan(
             "must keep the caller's layouts so cotangents line up"
         )
 
-    if (use_resident is not None and not use_resident) or (
-        use_resident is None
-        and fused_ok is not None
-        and _routes.takes_component_layout(weights, relayout=relayout, tuned=tuned)
-    ):
-        # forced, or the component layout runs the fewer grid steps
-        route = _routes.ROUTE_LAYERED
-    else:
-        route = fused_ok or _routes.ROUTE_LAYERED
-
     if donor is not None:
         if (
             donor.key.fingerprint != fingerprint
             or donor.differentiable != differentiable
-            or donor.key.resident != use_resident
             or donor.key.tuned != tuned_token
             or donor.n_layers != len(weights)
         ):
             raise ValueError(
                 "donor plan does not match this stack's plan key "
-                "(fingerprint / differentiable / residency / tuned / layers)"
+                "(fingerprint / differentiable / tuned / layers)"
             )
         route = donor.route
         exec_weights = list(donor.weights)
@@ -567,10 +486,6 @@ def build_plan(
             for lp, ew in zip(donor.layers, exec_weights)
         ]
     else:
-        fused_family = route in (
-            _routes.ROUTE_FUSED,
-            _routes.ROUTE_FUSED_TILED,
-        )
         exec_weights = []
         exec_biases = []
         comps = []  # each layer's ComponentLayout, or None
@@ -583,7 +498,7 @@ def build_plan(
         for i, (w, b) in enumerate(zip(weights, biases)):
             src_layout = _layout.layer_layout(w)
             ew, comp = w, None
-            if not fused_family and relayout and id(w) not in relaid:
+            if relayout and id(w) not in relaid:
                 if t_layout is None and t_block_size is None:
                     # A tuned layout or block size wins over it.
                     comp = _components.component_layout(w)
@@ -598,7 +513,7 @@ def build_plan(
                     if bs != t_block_size:
                         ew = _reblock(ew, t_block_size)
                 relaid[id(w)] = (ew, comp)
-            if not fused_family and relayout:
+            if relayout:
                 ew, comp = relaid[id(w)]
             if comp is not None:
                 # The bias follows the layer's rows: b[R].
@@ -610,11 +525,7 @@ def build_plan(
             exec_layout = (
                 "component" if comp is not None else _layout.layer_layout(ew)
             )
-            path = (
-                route
-                if fused_family
-                else _routes.layer_path(ew, differentiable=differentiable)
-            )
+            path = _routes.layer_path(ew, differentiable=differentiable)
             tp = None
             if differentiable and isinstance(ew, BlockCSRMatrix):
                 # The one and only topology sort for this layer: every
@@ -641,15 +552,14 @@ def build_plan(
             dataclasses.replace(lp, gather=g)
             for lp, g in zip(layer_plans, layer_gathers)
         ]
-        if route == _routes.ROUTE_LAYERED and all(
-            lp.path == "xla-dense" for lp in layer_plans
-        ):
-            route = _routes.ROUTE_XLA
+        route = (
+            _routes.ROUTE_XLA
+            if all(lp.path == "xla-dense" for lp in layer_plans)
+            else _routes.ROUTE_LAYERED
+        )
 
     plan = StackPlan(
-        key=PlanKey(
-            fingerprint, width, differentiable, use_resident, tuned=tuned_token
-        ),
+        key=PlanKey(fingerprint, width, differentiable, tuned=tuned_token),
         route=route,
         layers=tuple(layer_plans),
         width=width,
@@ -662,15 +572,5 @@ def build_plan(
         tuned=tuned,
         out_gather=out_gather,
     )
-    if plan.is_fused_route:
-        if donor is not None:
-            plan._stacked = donor._stacked  # one device copy per topology
-        else:
-            from repro.core import dnn as _dnn
-
-            plan._stacked = (
-                _dnn.stack_bsr(list(exec_weights)),
-                jnp.stack(list(biases)),
-            )
     plan._fn = _make_executable(plan)
     return plan

@@ -10,8 +10,8 @@ inputs as columns, and the official rate metric
 
 reported per run. Every panel goes through the engine's normal
 submit/step path, so runs exercise exactly what production serving
-exercises: plan-cache width classes, the degradation ladder, fused /
-fused-tiled / layered / sharded routing — a mesh makes this the
+exercises: plan-cache width classes, the degradation ladder, layered /
+sharded routing — a mesh makes this the
 "sharded engine" leg of the conformance suite.
 
 The driver never materialises the full output set: each step's panel is
@@ -75,25 +75,23 @@ def run_challenge(
     density: float = 0.3,
     seed: int = 0,
     mesh: Any = None,
-    use_resident: bool | None = None,
     engine: SparseDNNEngine | None = None,
     warmup: bool = True,
     block_size: int = 16,
     tuning_table: Any = None,
-    panel_dtype: Any = None,
 ) -> ChallengeResult:
     """Stream ``n_inputs`` seeded inputs through the engine, panelwise.
 
     ``engine``: pass a prebuilt engine (e.g. with a fault injector or a
     shared plan cache) — it must serve the spec's topology; by default
     the driver builds one from :func:`repro.data.radixnet
-    .radixnet_weights` with the given ``mesh``/``use_resident``.
+    .radixnet_weights` with the given ``mesh``.
     ``warmup`` runs one untimed panel of the same width class first so
     the metric bills steady-state serving, not plan compilation; its
     wall time (plan build, compile and one panel) is ``warmup_seconds``.
-    ``tuning_table``/``panel_dtype`` thread straight into the default
-    engine (``repro.tune``): a table hit on this spec's fingerprint —
-    or an explicit bf16-panel override — retunes every panel's plan.
+    ``tuning_table`` threads straight into the default engine
+    (``repro.tune``): a table hit on this spec's fingerprint retunes
+    every panel's plan.
     """
     if engine is None:
         weights, biases = rx.radixnet_weights(spec, block_size=block_size)
@@ -102,9 +100,7 @@ def run_challenge(
             biases,
             batch_align=batch_align,
             mesh=mesh,
-            use_resident=use_resident,
             tuning_table=tuning_table,
-            panel_dtype=panel_dtype,
         )
     panel = jnp.asarray(
         rx.radixnet_input_panel(

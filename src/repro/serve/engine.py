@@ -11,11 +11,10 @@ dry-run cells ``decode_32k``/``long_500k`` lower exactly the
 ``SparseDNNEngine`` — the paper's workload as a service: batched forward
 passes through a deep sparse ReLU MLP (GraphChallenge-style inference).
 Requests are feature columns; the engine right-pads each batch to the
-kernel tile, dispatches the VMEM-resident single-``pallas_call`` forward
-when the stack qualifies (square, homogeneous, panel fits VMEM, not
-a union of complete components) and the layered fused path otherwise,
-and reports per-batch kernel-step
-accounting so operators can see the nnz-proportional scaling live.
+kernel tile, dispatches the plan's layered forward (one fused
+SpMM + bias + ReLU kernel launch per layer), and reports per-batch
+kernel-step accounting so operators can see the nnz-proportional
+scaling live.
 
 Two call conventions on ``SparseDNNEngine``:
 
@@ -129,8 +128,8 @@ class SparseDNNEngine:
     accepts (m, batch) activation panels of any batch size; batches are
     padded to ``batch_align`` so the jit cache stays warm across request
     sizes. ``differentiable=True`` guarantees the served forward is
-    ``jax.grad``-compatible (layered custom-VJP kernels only; the
-    VJP-less fused resident path is rejected/bypassed). ``mesh=``
+    ``jax.grad``-compatible (custom-VJP kernels on the caller's
+    layouts; dense layers in the XLA form). ``mesh=``
     serves the stack mesh-sharded (``repro.plan.ShardedStackPlan``):
     same outputs, per-shard grid-step accounting in the step stats.
     """
@@ -138,12 +137,9 @@ class SparseDNNEngine:
     weights: Sequence[dnn.Weight]
     biases: Sequence[Array]
     batch_align: int = 64
-    use_resident: bool | None = None  # None = auto-detect eligibility
     # Differentiable serving (gradient-based attribution, fine-tuning
-    # against served traffic): the VMEM-resident fused kernel has NO VJP
-    # (activations never leave VMEM — nothing to checkpoint), so this
-    # flag forces the layered custom-VJP kernel path and REJECTS an
-    # explicit use_resident=True.
+    # against served traffic): plans keep the caller's layouts so the
+    # custom-VJP kernels' cotangents line up.
     differentiable: bool = False
     # Compiled-plan cache (one per engine unless shared explicitly):
     # holds one StackPlan per padded panel width seen; size it to the
@@ -153,8 +149,7 @@ class SparseDNNEngine:
     # segment across the mesh's row_blocks axes and serve through
     # repro.plan.ShardedStackPlan (shard-local kernels + psum between
     # layers). Outputs match the single-device engine; step stats grow
-    # per-shard grid-step accounting. Incompatible with
-    # use_resident=True (the fused kernel is single-device VMEM).
+    # per-shard grid-step accounting.
     mesh: Any = None
     # Fault handling (docs/robustness.md). ``fault_injector``: a
     # repro.testing.faults.FaultInjector polled at this engine's named
@@ -182,38 +177,19 @@ class SparseDNNEngine:
     # Kernel autotuning (docs/tuning.md). ``tuning_table``: a
     # repro.tune.TuningTable consulted ONCE at construction by this
     # stack's topology fingerprint — a hit threads the tuned config
-    # (block_n, forced layout, bf16 panels, VMEM budget) through every
-    # plan this engine builds; a miss serves defaults, silently.
-    # ``panel_dtype``: explicit bf16-panel override (e.g. "bfloat16"),
-    # applied on top of any table hit. The sharded level always serves
-    # untuned (the sharded builder takes no tuning knobs).
+    # (block size, block_n, forced layout) through every plan this
+    # engine builds; a miss serves defaults, silently. The sharded
+    # level always serves untuned (the sharded builder takes no tuning
+    # knobs).
     tuning_table: Any = None
-    panel_dtype: Any = None
 
     def __post_init__(self):
         self.n_layers = len(self.weights)
         if len(self.biases) != self.n_layers:
             raise ValueError("weights/biases length mismatch")
-        if self.differentiable and self.use_resident:
-            raise ValueError(
-                "use_resident=True is incompatible with differentiable="
-                "True: the fused VMEM-resident kernel has no VJP. Use "
-                "use_resident=None/False to route through the layered "
-                "kernel path, whose custom VJPs support jax.grad."
-            )
-        if self.mesh is not None and self.use_resident:
-            raise ValueError(
-                "use_resident=True is incompatible with mesh=: the "
-                "VMEM-resident fused kernel runs a single device's "
-                "VMEM; sharded serving always takes the per-shard "
-                "layered route. Pass use_resident=None/False."
-            )
-        from repro.plan import routes as _routes
-
         # Fingerprint once — weights are immutable across requests; the
-        # hot path must not re-hash the topology per step. Computed
-        # before residency so the tuning-table lookup (keyed by this
-        # fingerprint) can shift the resident boundary below.
+        # hot path must not re-hash the topology per step. The
+        # tuning-table lookup is keyed by it too.
         self._fingerprint = topology_fingerprint(tuple(self.weights))
         self._tuned = None
         if self.tuning_table is not None:
@@ -223,48 +199,6 @@ class SparseDNNEngine:
             self._tuned = self.tuning_table.lookup(
                 self._fingerprint, dtype=dtype
             )
-        if self.panel_dtype is not None:
-            from repro.tune.table import TunedConfig
-
-            pdt = str(np.dtype(self.panel_dtype))
-            if self._tuned is None:
-                self._tuned = TunedConfig(panel_dtype=pdt)
-            else:
-                self._tuned = dataclasses.replace(
-                    self._tuned, panel_dtype=pdt
-                )
-        # Fused-family eligibility covers both the VMEM-resident kernel
-        # and the multi-panel tiled variant (panel past the VMEM budget)
-        # — either way the plan layer serves ONE pallas_call per step.
-        # Tuned knobs move the boundary: bf16 panels halve the VMEM
-        # bill, so a stack that tiles under f32 can serve resident.
-        fused_kw: dict = {}
-        if self._tuned is not None:
-            if self._tuned.block_n is not None:
-                fused_kw["block_n"] = self._tuned.block_n
-            fused_kw["panel_dtype"] = self._tuned.panel_dtype
-            fused_kw["vmem_limit"] = self._tuned.vmem_limit_bytes
-        resident_ok = (
-            not self.differentiable
-            and self.mesh is None
-            and _routes.fused_route(self.weights, **fused_kw) is not None
-        )
-        if self.use_resident and not resident_ok:
-            raise ValueError(
-                "use_resident=True but the stack is not eligible for the "
-                "fused whole-stack kernels (needs a homogeneous square "
-                "BSR stack); pass use_resident=None to auto-detect"
-            )
-        # Auto-detection reads the plan layer's own rule, so the ladder's
-        # level and ``build_plan(use_resident=None)`` agree: a stack
-        # whose every distinct weight has a component layout leaves the
-        # fused kernels for the layered route.
-        self._resident = (
-            resident_ok
-            and not _routes.takes_component_layout(self.weights, tuned=self._tuned)
-            if self.use_resident is None
-            else self.use_resident
-        )
         if self.validate:
             checked: set[int] = set()  # layers may repeat one object
             for i, w in enumerate(self.weights):
@@ -274,12 +208,9 @@ class SparseDNNEngine:
         if self.plan_cache is None:
             self.plan_cache = PlanCache(max_size=16)
         # The degradation ladder owns execution-level health: sharded →
-        # single-device → layered fallback for the same fingerprint.
+        # single-device layered fallback for the same fingerprint.
         self._ladder = DegradationLadder(
-            self.plan_cache,
-            mesh=self.mesh,
-            use_resident=self._resident,
-            tuned=self._tuned,
+            self.plan_cache, mesh=self.mesh, tuned=self._tuned
         )
         self._served = 0
         self._steps = 0
@@ -306,11 +237,10 @@ class SparseDNNEngine:
     def _plan_for_width(self, width: int, *, step: int = -1, compile_hook=None):
         """(plan, level, cache_hit) serving a ``width``-wide panel at
         the best healthy degradation level. Route rules are the plan
-        layer's (fused when eligible and not differentiable; layered
-        per-layout kernels otherwise; dense layers keep jax.grad
+        layer's (layered per-layout kernels; dense layers keep jax.grad
         compatibility under ``differentiable=True`` via the XLA form);
-        the ladder only decides WHICH level of them to serve at when the
-        mesh or the resident path is marked unhealthy."""
+        the ladder only decides WHICH level to serve at when the mesh is
+        marked unhealthy."""
         with spans.span("engine.plan", width=width) as s:
             plan, level, hit = self._ladder.get_plan(
                 tuple(self.weights),
@@ -366,7 +296,6 @@ class SparseDNNEngine:
             "pad_slots": 0,
             "grid_steps": 0,
             "request_ids": [],
-            "resident": self._resident,
             "differentiable": self.differentiable,
             "pallas_calls": 0,
             "served_total": self._served,
@@ -554,7 +483,6 @@ class SparseDNNEngine:
             "pad_slots": width - batch,
             "grid_steps": plan.grid_steps,
             "request_ids": ids,
-            "resident": self._resident,
             "differentiable": self.differentiable,
             "pallas_calls": plan.pallas_calls,
             "served_total": self._served,

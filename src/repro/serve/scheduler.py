@@ -24,9 +24,8 @@ rate over request *streams*, which is what this module serves:
   baseline the benchmark's ``serve`` arm compares against.
 
 Scheduling model: discrete ticks. Every engine step serves a full
-L-layer forward for its panel (the resident path does the whole stack in
-one ``pallas_call``; splitting a request across ticks would re-stream
-its activations through HBM for no kernel saving — see
+L-layer forward for its panel (splitting a request across ticks would
+re-stream its activations through HBM for no kernel saving — see
 ``docs/serving.md``). "Continuous" therefore means continuous over the
 *stream*: slots turn over every step, a request arriving while a panel
 is in flight is packed into the very next panel instead of waiting for a
@@ -220,7 +219,6 @@ class StepRecord:
     padded_width: int  # panel width after tile alignment
     grid_steps: int  # exact kernel grid steps billed for the panel
     pallas_calls: int
-    resident: bool
     width_class: int | None = None  # plan width the panel compiled at
     plan_cache_hit: bool | None = None  # compiled-plan reuse vs build
     retries: int = 0  # transient-failure retries before success
@@ -621,7 +619,6 @@ class ContinuousBatcher:
                 padded_width=estats["padded_batch"],
                 grid_steps=estats["grid_steps"],
                 pallas_calls=estats["pallas_calls"],
-                resident=estats["resident"],
                 width_class=plan_stats.get("width_class"),
                 plan_cache_hit=plan_stats.get("cache_hit"),
                 retries=int(estats.get("retries", 0)),
@@ -738,7 +735,6 @@ def serve_trace_static(
                 padded_width=estats["padded_batch"],
                 grid_steps=estats["grid_steps"],
                 pallas_calls=estats["pallas_calls"],
-                resident=estats["resident"],
                 width_class=plan_stats.get("width_class"),
                 plan_cache_hit=plan_stats.get("cache_hit"),
             )

@@ -3,7 +3,7 @@
 Production-scale sparse-DNN serving (the ROADMAP's north star) fails in
 a handful of recurring ways: a request column goes non-finite and
 poisons its packed panel, a mesh shard dies mid-stream, a plan compile
-blows the VMEM guard, a cache eviction storm forces recompiles, a node
+fails, a cache eviction storm forces recompiles, a node
 straggles. This module makes every one of those *scriptable*: faults
 are **scheduled**, never sampled — a :class:`FaultInjector` holds a map
 ``(site, when) → payload`` armed by tests/benchmarks, and each

@@ -2,10 +2,9 @@
 
 Per ``(topology fingerprint, backend, dtype)`` this package sweeps the
 kernel knobs the plan layer exposes — column-tile width ``block_n``,
-weight block size, forced layout (ELL vs block-CSR), bf16 activation
-panels, and the resident↔tiled VMEM budget — scores candidates with the
-exact grid-step cost model (``repro.plan.cost``), and persists the
-winner in a versioned on-disk :class:`TuningTable` that
+weight block size and forced layout (ELL vs block-CSR) — scores
+candidates with the exact grid-step cost model (``repro.plan.cost``),
+and persists the winner in a versioned on-disk :class:`TuningTable` that
 ``repro.plan.PlanCache`` / ``build_plan`` consult before falling back
 to defaults. Selection is cost-model-deterministic; wall-clock is
 recorded as evidence, never used to pick (CI machines jitter, cost

@@ -9,14 +9,8 @@ output, and picks a winner **deterministically**:
    the plan's executed weights. Block-size-invariant (a re-blocked
    candidate cannot win by coarsening the grid) and layout-sensitive
    (forcing block-CSR on a skewed stack genuinely drops the bill).
-2. route rank — ``fused`` < ``fused-tiled`` < ``layered`` < ``xla``:
-   at equal ⊗-work, fewer pallas_calls and less HBM panel traffic win.
-   This is where bf16 panels earn their keep: halving the panel bill
-   moves a stack across the resident boundary without touching work.
-3. fused-panel VMEM bytes — at equal work and route, the smaller
-   resident footprint wins (bf16 beats f32 for resident stacks).
-4. enumeration order — the default config is enumerated first, so a
-   candidate must *strictly* improve something to displace it.
+2. enumeration order — the default config is enumerated first, so a
+   candidate must *strictly* improve the bill to displace it.
 
 Wall-clock is measured (min over ``reps`` timed forwards, recorded in
 the sweep evidence and the table entry) but **never used for
@@ -25,8 +19,8 @@ table that flips winners run-to-run is worse than no table.
 
 Accuracy is a hard gate, not a score: every candidate's probe output
 must stay within ``accuracy_rtol × max|default output|`` of the default
-plan's output, so a bf16 (or re-blocked) config can only be selected if
-its numerics hold on this topology.
+plan's output, so a re-blocked or re-laid config can only be selected
+if its numerics hold on this topology.
 """
 
 from __future__ import annotations
@@ -41,37 +35,24 @@ import numpy as np
 from repro.kernels import DEFAULT_BLOCK_N
 from repro.tune.table import TunedConfig, TuningTable
 
-_ROUTE_RANK = {"fused": 0, "fused-tiled": 1, "layered": 2, "xla": 3}
-
-
 def default_candidates(
     *,
     layouts: Sequence[str | None] = (None, "ell", "bcsr"),
-    panel_dtypes: Sequence[str | None] = (None, "bfloat16"),
     block_ns: Sequence[int | None] = (None,),
     block_sizes: Sequence[int | None] = (None,),
-    vmem_limits: Sequence[int | None] = (None,),
 ) -> list[TunedConfig]:
     """The sweep's candidate grid — the all-``None`` default config is
     always enumerated first (ties go to it)."""
     out: list[TunedConfig] = []
     seen: set[str] = set()
     for bn in block_ns:
-        for pdt in panel_dtypes:
-            for lay in layouts:
-                for bs in block_sizes:
-                    for vl in vmem_limits:
-                        cfg = TunedConfig(
-                            block_size=bs,
-                            block_n=bn,
-                            layout=lay,
-                            panel_dtype=pdt,
-                            vmem_limit_bytes=vl,
-                        )
-                        if cfg.token() in seen:
-                            continue
-                        seen.add(cfg.token())
-                        out.append(cfg)
+        for lay in layouts:
+            for bs in block_sizes:
+                cfg = TunedConfig(block_size=bs, block_n=bn, layout=lay)
+                if cfg.token() in seen:
+                    continue
+                seen.add(cfg.token())
+                out.append(cfg)
     out.sort(key=lambda c: not c.is_default)  # stable: default first
     return out
 
@@ -106,7 +87,7 @@ def sweep_stack(
     """Sweep candidate configs over one stack; return (winner, records).
 
     One record per candidate: ``token``, ``config``, ``route``,
-    ``grid_steps``, ``block_work``, ``vmem_bytes``, ``wall_s``,
+    ``grid_steps``, ``block_work``, ``wall_s``,
     ``max_abs_err``, ``ok`` (accuracy gate), ``selected``. Candidates
     whose plan fails to build are recorded with ``error`` and skipped.
     ``time_forwards=False`` skips the timed reps (pure cost-model sweep
@@ -116,7 +97,6 @@ def sweep_stack(
     judges the numerics that will actually be served.
     """
     from repro import plan as _plan
-    from repro.kernels import fused_mlp as _fmlp
 
     if candidates is None:
         candidates = default_candidates()
@@ -151,13 +131,6 @@ def sweep_stack(
             continue
         bn = cand.block_n or DEFAULT_BLOCK_N
         block_work = _plan.stack_block_work(plan.weights, width, block_n=bn)
-        route_rank = _ROUTE_RANK.get(plan.route, len(_ROUTE_RANK))
-        if plan.route in ("fused", "fused-tiled"):
-            vmem = _fmlp.fused_mlp_vmem_bytes(
-                plan.weights[0].shape[0], bn, cand.panel_dtype
-            )
-        else:
-            vmem = 0
         out = np.asarray(plan.forward(probe), np.float32)
         err = float(np.max(np.abs(out - ref)))
         ok = err <= err_bound
@@ -165,7 +138,6 @@ def sweep_stack(
             route=plan.route,
             grid_steps=int(plan.grid_steps),
             block_work=int(block_work),
-            vmem_bytes=int(vmem),
             max_abs_err=err,
             ok=ok,
         )
@@ -174,7 +146,7 @@ def sweep_stack(
         records.append(rec)
         if not ok:
             continue
-        score = (block_work, route_rank, vmem, idx)
+        score = (block_work, idx)
         if best_score is None or score < best_score:
             best_score = score
             best_idx = idx

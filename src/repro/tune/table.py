@@ -18,16 +18,17 @@ File format (JSON, human-diffable, committed next to benchmarks)::
       "schema_version": 1,
       "entries": {
         "<fingerprint>:<backend>:<dtype>": {
-          "config": {"block_n": 128, "panel_dtype": "bfloat16", ...},
+          "config": {"block_n": 128, "layout": "bcsr", ...},
           "grid_steps": 1234, "block_work": 315904, ...
         }
       }
     }
 
 ``load`` refuses anything it cannot trust: wrong/missing
-``schema_version``, non-object entries, unknown config knobs — all
-raise :class:`TuningTableError` rather than silently steering kernels
-with garbage.
+``schema_version``, non-object entries, unknown config knobs (including
+knobs an older tuner wrote that the plan no longer reads) — all raise
+:class:`TuningTableError` rather than silently steering kernels with
+garbage.
 """
 
 from __future__ import annotations
@@ -37,13 +38,11 @@ import json
 import os
 from typing import Any, Mapping
 
-import numpy as np
-
 SCHEMA_VERSION = 1
 
 # The only knobs a table entry may carry — anything else in a loaded
 # config dict is a schema violation, not a forward-compat freebie.
-_KNOBS = ("block_size", "block_n", "layout", "panel_dtype", "vmem_limit_bytes")
+_KNOBS = ("block_size", "block_n", "layout")
 _LAYOUTS = ("ell", "bcsr")
 
 
@@ -66,19 +65,11 @@ class TunedConfig:
     block_size: int | None = None  # re-block sparse weights to (b, b)
     block_n: int | None = None  # column-tile width of the kernel grids
     layout: str | None = None  # force "ell" or "bcsr" (layered route)
-    panel_dtype: str | None = None  # e.g. "bfloat16" activation panels
-    vmem_limit_bytes: int | None = None  # resident↔tiled boundary budget
 
     def __post_init__(self):
         if self.layout is not None and self.layout not in _LAYOUTS:
             raise ValueError(
                 f"layout must be one of {_LAYOUTS}, got {self.layout!r}"
-            )
-        if self.panel_dtype is not None:
-            # Normalize eagerly so token() is canonical ("bfloat16", not
-            # a dtype object repr) and bad names fail at build time.
-            object.__setattr__(
-                self, "panel_dtype", str(np.dtype(self.panel_dtype))
             )
 
     @property
@@ -122,9 +113,9 @@ class TuningTable:
     ``entries`` maps :func:`entry_key` strings to records: each record
     holds the selected ``config`` plus the sweep's evidence (grid-step /
     block-work bills for tuned and default, measured wall-clock, probe
-    width, route, bf16 max-abs error). The evidence rides along so a
-    committed table is auditable — the bench gates re-derive the
-    grid-step claims from it.
+    width, route, max-abs error against the default plan). The evidence
+    rides along so a committed table is auditable — the bench gates
+    re-derive the grid-step claims from it.
     """
 
     def __init__(self, entries: dict[str, dict] | None = None):

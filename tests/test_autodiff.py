@@ -7,8 +7,8 @@ Checks, per layout (ELL-BSR and block-CSR), in interpret mode:
     (padded/invalid slots exactly zero — the no-densify invariant);
   * grad through models.layers.linear matches dense to 1e-4;
   * the sparse train step decreases loss with kernels in the hot path;
-  * the fused resident kernel refuses differentiation and the serve
-    engine routes/rejects accordingly.
+  * a differentiable serve engine keeps the caller's layouts and routes
+    dense layers through the XLA form.
 """
 
 import jax
@@ -294,31 +294,27 @@ def test_dnn_value_and_grad():
     np.testing.assert_allclose(dbs[0], dbs2[0], rtol=1e-4, atol=1e-5)
 
 
-def test_fused_mlp_grad_raises():
-    ws = [_random_bsr(jax.random.PRNGKey(40), (32, 32), (8, 8), 2) for _ in range(2)]
-    stacked = dnn.stack_bsr(ws)
-    sb = jnp.zeros((2, 32))
-    y0 = jax.random.uniform(jax.random.PRNGKey(41), (32, 16))
-    with pytest.raises(NotImplementedError, match="layered"):
-        jax.grad(lambda y: jnp.sum(ops.fused_mlp_forward(stacked, sb, y)))(y0)
-
-
 def test_serve_engine_differentiable_routing():
     from repro.serve.engine import SparseDNNEngine
 
     ws = [_random_bsr(jax.random.PRNGKey(50), (32, 32), (8, 8), 2) for _ in range(2)]
     bs = [jnp.zeros((32,)) for _ in ws]
-    # resident-eligible stack: differentiable engine must bypass the
-    # fused path...
-    assert dnn.resident_eligible(ws)
+    # a homogeneous square stack serves layered, on the caller's layouts
     eng = SparseDNNEngine(ws, bs, batch_align=8, differentiable=True)
-    out, stats = eng.infer(jax.random.uniform(jax.random.PRNGKey(51), (32, 4)))
-    assert stats["resident"] is False
+    y0 = jax.random.uniform(jax.random.PRNGKey(51), (32, 4))
+    out, stats = eng.infer(y0)
+    assert stats["plan"]["route"] == "layered"
     assert stats["differentiable"] is True
+    assert stats["pallas_calls"] == 2
+    (plan,) = eng.plan_cache.plans()
+    assert plan.layouts == ("ell", "ell")
     assert out.shape == (32, 4)
-    # ...and explicit use_resident=True must be rejected.
-    with pytest.raises(ValueError, match="no VJP"):
-        SparseDNNEngine(ws, bs, use_resident=True, differentiable=True)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(dnn.dnn_forward(ws, bs, y0, fused=True)),
+        rtol=1e-5,
+        atol=1e-5,
+    )
 
 
 def test_serve_engine_differentiable_with_dense_layer():

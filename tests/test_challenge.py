@@ -1,13 +1,13 @@
 """GraphChallenge conformance suite (`docs/benchmarks.md`).
 
 Ground truth is the pure-numpy gather reference in
-``repro.data.radixnet``; every engine execution path — layered Pallas
-plan, VMEM-resident fused kernel, multi-panel tiled fused kernel, the
-streaming challenge driver, and the 8-device sharded engine — must
-produce the SAME challenge answer set (bit-level category agreement) on
-fixed-seed inputs. Small configs run in tier-1; the official challenge
-shapes (1024×120, 4096×120) and the 16384-neuron fused-tiled config are
-``slow``-marked.
+``repro.data.radixnet``; every engine execution path — the layered
+Pallas plan in the component layout and in the caller's 16×16 blocks,
+the XLA forward, the streaming challenge driver, and the 8-device
+sharded engine — must produce the SAME challenge answer set (bit-level
+category agreement) on fixed-seed inputs. Small configs run in tier-1;
+the official challenge shapes (1024×120, 4096×120) and the 16384-neuron
+config are ``slow``-marked.
 """
 
 import os
@@ -23,7 +23,6 @@ import pytest
 import repro.plan as P
 from repro.core import dnn
 from repro.data import radixnet as rx
-from repro.kernels import ops as kernel_ops
 from repro.serve import run_challenge
 
 
@@ -92,7 +91,7 @@ def test_conn_to_bsr_is_exact():
 def test_weights_stack_is_homogeneous_and_fused_eligible():
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 5))
     assert len({w.max_blocks_per_row for w in ws}) == 1
-    assert P.fused_route(ws) is not None
+    assert len({(w.shape, w.block_shape) for w in ws}) == 1
     assert len(bs) == 5 and bs[0].shape == (256,)
 
 
@@ -131,16 +130,13 @@ def _legs_small(spec, y0):
     """(name, final activations) for every single-device execution path."""
     ws, bs = rx.radixnet_weights(spec)
     yj = jnp.asarray(y0)
-    sw = dnn.stack_bsr(ws)
-    sb = jnp.stack(bs)
-    layered = P.build_plan(ws, bs, y0.shape[1], use_resident=False)
-    resident = P.build_plan(ws, bs, y0.shape[1], use_resident=True)
-    assert layered.route == P.ROUTE_LAYERED
-    assert resident.route == P.ROUTE_FUSED
+    layered = P.build_plan(ws, bs, y0.shape[1])
+    blocks = P.build_plan(ws, bs, y0.shape[1], relayout=False)
+    assert layered.route == blocks.route == P.ROUTE_LAYERED
+    assert blocks.component_layers == 0
     return [
         ("layered-plan", layered.forward(yj)),
-        ("fused-resident", resident.forward(yj)),
-        ("fused-tiled", kernel_ops.fused_mlp_tiled_forward(sw, sb, yj)),
+        ("layered-16x16", blocks.forward(yj)),
         ("xla", dnn.dnn_forward(ws, bs, yj, fused=True)),
     ]
 
@@ -162,21 +158,17 @@ def test_conformance_small(neurons, layers):
 
 
 @pytest.mark.parametrize(
-    "use_resident,route,level",
-    [(None, "layered", "layered"), (True, "fused", "resident")],
-    ids=["auto-layered", "fused"],
+    "route,level", [("layered", "layered")], ids=["auto-layered"]
 )
-def test_challenge_driver_small(use_resident, route, level):
-    """Auto-detected residency serves the RadiX-net stack on the layered
-    route in the component layout; ``use_resident=True`` still serves
-    it on the fused kernel."""
+def test_challenge_driver_small(route, level):
+    """The driver serves the RadiX-net stack on the layered route in the
+    component layout."""
     spec = rx.RadixNetSpec(256, 6)
     _, ref_cats = rx.radixnet_reference(
         spec, rx.radixnet_input_panel(256, 50, density=0.3, seed=5)
     )
     res = run_challenge(
-        spec, n_inputs=50, panel_width=24, batch_align=8, seed=5,
-        use_resident=use_resident,
+        spec, n_inputs=50, panel_width=24, batch_align=8, seed=5
     )
     assert np.array_equal(res.categories, ref_cats)
     assert res.served == 50
@@ -196,7 +188,7 @@ def test_challenge_driver_small(use_resident, route, level):
     assert not res.engine.ladder.events
     (plan,) = res.engine.plan_cache.plans()
     assert plan.width == 24 and plan.route == route
-    assert plan.component_layers == (6 if route == "layered" else 0)
+    assert plan.component_layers == 6
     assert plan.lower().compile() is not None
 
 
@@ -211,7 +203,6 @@ def test_challenge_driver_layered_and_failure():
         panel_width=16,
         batch_align=8,
         seed=5,
-        use_resident=False,
     )
     assert res.routes == ("layered",)
     assert np.array_equal(res.categories, ref_cats)
@@ -244,13 +235,9 @@ def test_conformance_challenge_config(neurons, layers, density):
 
     ws, bs = rx.radixnet_weights(spec)
     yj = jnp.asarray(y0)
-    tiled = np.asarray(
-        kernel_ops.fused_mlp_tiled_forward(
-            dnn.stack_bsr(ws), jnp.stack(bs), yj
-        )
-    )
+    layered = np.asarray(P.build_plan(ws, bs, yj.shape[1]).forward(yj))
     xla = np.asarray(dnn.dnn_forward(ws, bs, yj, fused=True))
-    assert np.array_equal(rx.reference_categories(tiled), ref_cats)
+    assert np.array_equal(rx.reference_categories(layered), ref_cats)
     assert np.array_equal(rx.reference_categories(xla), ref_cats)
     # layer-1 exactness: {0,1} inputs × the dyadic 1/16 weight make the
     # first layer bit-exact in f32 under ANY summation order
@@ -264,17 +251,11 @@ def test_conformance_challenge_config(neurons, layers, density):
 
 @pytest.mark.slow
 @pytest.mark.parametrize(
-    "use_resident,route,level",
-    [(None, "layered", "layered"), (True, "fused-tiled", "resident")],
-    ids=["auto-layered", "fused-tiled"],
+    "route,level", [("layered", "layered")], ids=["auto-layered"]
 )
-def test_challenge_engine_routes_fused_tiled_past_vmem_budget(
-    use_resident, route, level
-):
-    """A 16384-neuron stack is past ``VMEM_SOFT_LIMIT_BYTES``: demanding
-    residency serves it on the multi-panel tiled kernel, and
-    auto-detection on the layered route in the component layout; both
-    reproduce the ground-truth categories."""
+def test_challenge_engine_routes_fused_tiled_past_vmem_budget(route, level):
+    """A 16384-neuron stack serves on the layered route in the component
+    layout and reproduces the ground-truth categories."""
     spec = rx.RadixNetSpec(16384, 6)
     assert spec.bias == -0.4
     y0 = rx.radixnet_input_panel(16384, 48, density=0.4, seed=2)
@@ -282,7 +263,7 @@ def test_challenge_engine_routes_fused_tiled_past_vmem_budget(
     assert 0 < len(ref_cats) < 48
     res = run_challenge(
         spec, n_inputs=48, panel_width=24, batch_align=8,
-        density=0.4, seed=2, use_resident=use_resident,
+        density=0.4, seed=2,
     )
     assert res.routes == (route,)
     assert res.levels == (level,)

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro import plan as P
+from repro.core import dnn
 from repro.data import radixnet as rx
 from repro.plan.components import component_layout
 from repro.sparse import BlockCSRMatrix, BlockSparseMatrix
@@ -122,7 +123,7 @@ def test_no_component_layout_unless_all_three_conditions_hold(make):
     w = make()
     assert component_layout(w) is None
     b = jnp.zeros((w.shape[0],), jnp.float32)
-    plan = P.build_plan([w], [b], 8, use_resident=False)
+    plan = P.build_plan([w], [b], 8)
     assert plan.component_layers == 0
     assert plan.gathers == (None, None)
 
@@ -132,8 +133,8 @@ def test_component_plan_matches_the_reference_and_the_plain_layout():
     ws, bs = _stack(1024, 6)
     y0 = rx.radixnet_input_panel(1024, 40, density=0.3, seed=4)
     ref_y, ref_cats = rx.radixnet_reference(spec, y0)
-    plan = P.build_plan(ws, bs, 40, use_resident=False)
-    plain = P.build_plan(ws, bs, 40, use_resident=False, relayout=False)
+    plan = P.build_plan(ws, bs, 40)
+    plain = P.build_plan(ws, bs, 40, relayout=False)
     assert plan.route == P.ROUTE_LAYERED
     assert plan.component_layers == 6 and plain.component_layers == 0
     assert {lp.path for lp in plan.layers} == {"kernel-bcsr"}
@@ -164,7 +165,7 @@ def test_a_mixed_radix_2_phase_matches_the_reference_bit_for_bit():
     ws, bs = rx.radixnet_weights(spec)
     y0 = rx.radixnet_input_panel(2048, 40, density=0.45, seed=5)
     ref_y, ref_cats = rx.radixnet_reference(spec, y0)
-    plan = P.build_plan(ws, bs, 40, use_resident=False)
+    plan = P.build_plan(ws, bs, 40)
     assert plan.route == P.ROUTE_LAYERED
     assert plan.component_layers == 6
     out = np.asarray(plan.forward(jnp.asarray(y0)))
@@ -177,7 +178,7 @@ def test_an_identity_gather_is_skipped():
     its columns in the order phase 1 leaves its rows, and phase 1 reads
     natural order: a 0, 1, 0 stack gathers before its first layer only."""
     ws, bs = _stack(1024, 3)
-    plan = P.build_plan(ws, bs, 8, use_resident=False)
+    plan = P.build_plan(ws, bs, 8)
     assert [lp.gather is None for lp in plan.layers] == [False, True, True]
     assert plan.out_gather is None
     y0 = rx.radixnet_input_panel(1024, 8, density=0.3, seed=6)
@@ -191,23 +192,21 @@ def test_an_identity_gather_is_skipped():
         {"differentiable": True},
         {"tuned": TunedConfig(layout="bcsr")},
         {"tuned": TunedConfig(block_size=32)},
-        {"use_resident": True},  # the fused route, demanded
-        # auto-detection keeps the fused route where the plan keeps the
-        # caller's layout
-        {"use_resident": None, "tuned": TunedConfig(layout="bcsr")},
-        {"use_resident": None, "tuned": TunedConfig(block_size=32)},
-        {"use_resident": None, "relayout": False},
+        # any tuned layout or block size keeps it off, even the one the
+        # stack already has
+        {"tuned": TunedConfig(layout="ell")},
+        {"tuned": TunedConfig(block_size=16)},
+        {"relayout": False},
     ],
     ids=[
-        "differentiable", "tuned-layout", "tuned-block-size", "fused",
+        "differentiable", "tuned-layout", "tuned-block-size",
         "auto-tuned-layout", "auto-tuned-block-size", "auto-no-relayout",
     ],
 )
 def test_stays_off_where_the_plan_keeps_the_callers_layout(kwargs):
     ws, bs = _stack(1024, 3)
-    kwargs = {"use_resident": False, **kwargs}
     plan = P.build_plan(ws, bs, 8, **kwargs)
-    assert plan.is_fused_route == (kwargs["use_resident"] is not False)
+    assert plan.route == P.ROUTE_LAYERED
     assert plan.component_layers == 0
     assert "component" not in plan.layouts
     assert plan.gathers == (None,) * 4
@@ -215,15 +214,15 @@ def test_stays_off_where_the_plan_keeps_the_callers_layout(kwargs):
 
 def test_tuned_block_n_alone_keeps_the_component_layout():
     ws, bs = _stack(1024, 3)
-    plan = P.build_plan(ws, bs, 8, use_resident=False, tuned=TunedConfig(block_n=256))
+    plan = P.build_plan(ws, bs, 8, tuned=TunedConfig(block_n=256))
     assert plan.component_layers == 3
 
 
 def test_a_donor_plan_shares_the_gathers_weights_and_biases():
     ws, bs = _stack(16384, 6)
     cache = P.PlanCache()
-    narrow = cache.get(ws, bs, 16, use_resident=False)
-    wide = cache.get(ws, bs, 128, use_resident=False)
+    narrow = cache.get(ws, bs, 16)
+    wide = cache.get(ws, bs, 128)
     assert cache.builds == 2
     assert wide.component_layers == narrow.component_layers == 6
     for a, b in zip(wide.gathers, narrow.gathers):
@@ -240,24 +239,22 @@ def test_a_donor_plan_shares_the_gathers_weights_and_biases():
 
 def test_describe_shows_the_component_layout():
     ws, bs = _stack(1024, 2)
-    desc = P.build_plan(ws, bs, 8, use_resident=False).describe()
+    desc = P.build_plan(ws, bs, 8).describe()
     assert desc["layouts"] == ["component", "component"]
     assert desc["component_layers"] == 2
 
 
 # ---------------------------------------------------------------------
-# The route choice: auto-detected residency takes the component layout
+# The route: complete components run layered in the component layout
 # ---------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("neurons,layers", [(1024, 3), (256, 5)], ids=["1024x3", "256x5"])
 def test_auto_residency_routes_complete_components_layered(neurons, layers):
-    """A stack the fused kernels could run, whose every weight is a union
-    of complete components, takes the layered route in the component
-    layout, bit-equal to the numpy reference."""
+    """A stack whose every weight is a union of complete components
+    takes the layered route in the component layout, bit-equal to the
+    numpy reference."""
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, layers))
-    assert P.fused_route(ws) == P.ROUTE_FUSED
-    assert P.takes_component_layout(ws)
     plan = P.build_plan(ws, bs, 16)
     assert plan.route == P.ROUTE_LAYERED
     assert plan.component_layers == layers
@@ -267,34 +264,34 @@ def test_auto_residency_routes_complete_components_layered(neurons, layers):
     np.testing.assert_array_equal(np.asarray(plan.forward(jnp.asarray(y0))), ref_y)
 
 
-@pytest.mark.parametrize("neurons,layers", [(1024, 3), (256, 5)], ids=["1024x3", "256x5"])
-def test_demanded_residency_keeps_the_fused_kernel(neurons, layers):
-    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, layers))
-    plan = P.build_plan(ws, bs, 16, use_resident=True)
-    assert plan.route == P.ROUTE_FUSED
-    assert plan.component_layers == 0
-    assert plan.pallas_calls == 1
-
-
 def test_a_stack_of_incomplete_components_auto_routes_fused():
     """Random 16x16 block placement leaves rows of one column set
     sharing columns with rows of another: no complete components, so a
-    homogeneous square BSR stack keeps the fused route."""
+    homogeneous square BSR stack runs layered in its own 16x16 blocks,
+    and matches the layer-by-layer reference."""
     ws = [
         BlockSparseMatrix.random(jax.random.PRNGKey(i), (256, 256), (16, 16), 3)
         for i in range(3)
     ]
-    bs = [jnp.zeros((256,))] * 3
+    bs = [jnp.full((256,), -0.3)] * 3
     assert all(component_layout(w) is None for w in ws)
-    assert not P.takes_component_layout(ws)
     plan = P.build_plan(ws, bs, 16)
-    assert plan.route == P.ROUTE_FUSED
+    assert plan.route == P.ROUTE_LAYERED
     assert plan.component_layers == 0
+    assert plan.pallas_calls == 3
+    assert {w.block_shape for w in plan.weights} == {(16, 16)}
+    y0 = jnp.asarray(rx.radixnet_input_panel(256, 16, density=0.3, seed=5))
+    np.testing.assert_allclose(
+        np.asarray(plan.forward(y0)),
+        np.asarray(dnn.dnn_forward(ws, bs, y0, fused=True)),
+        rtol=1e-5,
+        atol=1e-5,
+    )
 
 
 def test_detection_reads_each_weight_object_once(monkeypatch):
-    """The route choice and the plan build ask for the same weights'
-    layouts; each distinct object is read once."""
+    """The plan build reads each distinct weight object's layout
+    once, however many layers repeat it."""
     from repro.plan import components
 
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 4))

@@ -4,8 +4,8 @@ grids the Pallas calls actually launch.
 ``layer_grid_steps`` claims to bill EXACTLY the kernel grid — these
 tests intercept ``pl.pallas_call`` to capture every launched grid and
 compare step products, across layouts (ELL / block-CSR / dense), the
-fused whole-stack routes, non-default ``block_n``, tuner-chosen block
-sizes, and the narrow-panel effective-block shrink."""
+layered stack route, non-default ``block_n``, tuner-chosen block sizes,
+and the narrow-panel effective-block shrink."""
 
 import math
 
@@ -109,20 +109,6 @@ class TestLayerGridSteps:
 
 
 class TestStackGridSteps:
-    def test_fused_resident_stack(self, captured_grids):
-        ws = [
-            BlockSparseMatrix.random(
-                jax.random.PRNGKey(i), (64, 64), (16, 16), blocks_per_row=2
-            )
-            for i in range(3)
-        ]
-        bs = [jnp.zeros((64,), jnp.float32)] * 3
-        plan = P.build_plan(ws, bs, 128)
-        assert plan.route == P.ROUTE_FUSED
-        plan.forward(jnp.ones((64, 128), jnp.float32)).block_until_ready()
-        assert _steps(captured_grids) == P.stack_grid_steps(ws, 128)
-        assert plan.grid_steps == P.stack_grid_steps(ws, 128)
-
     def test_layered_stack_sums_layers(self, captured_grids):
         ws = [
             BlockSparseMatrix.random(

@@ -87,10 +87,8 @@ def test_scan_equals_loop(sparse):
     key = jax.random.PRNGKey(8)
     ws, bs = _mk_net(key, L=5, m=32, sparse=sparse)
     y0 = jax.random.uniform(jax.random.PRNGKey(9), (32, 8))
-    if sparse:
-        stacked_w = dnn.stack_bsr(ws)
-    else:
-        stacked_w = jnp.stack(ws)
+    # a BSR stack scans as one pytree whose leaves carry a leading L axis
+    stacked_w = jax.tree.map(lambda *leaves: jnp.stack(leaves), *ws)
     stacked_b = jnp.stack(bs)
     out_scan = dnn.dnn_forward_scan(stacked_w, stacked_b, y0)
     out_loop = dnn.dnn_forward(ws, bs, y0)
@@ -112,14 +110,6 @@ def test_scan_jits_once_for_any_depth():
         ws, bs = _mk_net(key, L=L, m=16)
         fwd(jnp.stack(ws), jnp.stack(bs), y0)
     assert len(traces) == 1
-
-
-def test_stack_bsr_rejects_heterogeneous():
-    key = jax.random.PRNGKey(12)
-    a = BlockSparseMatrix.random(key, (16, 16), (8, 8), blocks_per_row=1)
-    b = BlockSparseMatrix.random(key, (16, 16), (8, 8), blocks_per_row=2)
-    with pytest.raises(ValueError):
-        dnn.stack_bsr([a, b])
 
 
 def test_sparse_dense_agree_on_same_weights():

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.plan import LEVEL_LAYERED, LEVEL_RESIDENT, LEVEL_SHARDED
+from repro.plan import LEVEL_LAYERED, LEVEL_SHARDED
 from repro.serve import ContinuousBatcher, QueueFull, SparseDNNEngine
 from repro.sparse import BlockCSRMatrix, BlockSparseMatrix
 from repro.testing import (
@@ -182,61 +182,37 @@ def test_engine_cache_eviction_storm_recompiles():
 # ---------------------------------------------------------------------
 
 
-def test_compile_failure_demotes_resident_to_layered():
-    m = 32
-    ws, bs = _bsr_stack(4, 2, m)
-    layered = SparseDNNEngine(ws, bs, batch_align=8, use_resident=False)
-    ref, _ = layered.infer(_panel(6, m, 4))
-
-    inj = FaultInjector()
-    inj.schedule(SITE_PLAN_COMPILE, 0)
-    eng = SparseDNNEngine(ws, bs, batch_align=8, fault_injector=inj)
-    assert eng.ladder.preferred_level == LEVEL_RESIDENT
-    out, stats = eng.infer(_panel(6, m, 4))
-    # the panel that hit the compile fault is still served — one level
-    # down — and matches the healthy layered engine bit for bit
-    assert stats["failed"] is False
-    assert stats["plan"]["level"] == LEVEL_LAYERED
-    assert stats["plan"]["degraded"] is True
-    assert np.array_equal(out, ref)
-    # demotion is sticky and logged...
-    assert not eng.ladder.is_healthy(LEVEL_RESIDENT)
-    ev = eng.ladder.events
-    assert len(ev) == 1 and ev[0].healthy is False
-    _, s2 = eng.infer(_panel(7, m, 4))
-    assert s2["plan"]["level"] == LEVEL_LAYERED
-    # ...until an operator restore re-admits the level
-    eng.ladder.restore(LEVEL_RESIDENT)
-    _, s3 = eng.infer(_panel(8, m, 4))
-    assert s3["plan"]["level"] == LEVEL_RESIDENT
-    assert s3["plan"]["degraded"] is False
-
-
 def test_restore_reverses_demotion_and_logs_up_transition():
     """Restore semantics: after ``restore()`` the next panel re-plans at
     the restored level, the event history records the up-transition
     (healthy=True) with the operator's reason/step, and restoring an
     already-healthy level is a silent no-op."""
+    from repro.launch.mesh import make_row_blocks_mesh
+
     m = 32
-    ws, bs = _bsr_stack(9, 2, m)
+    ws, bs = _csr_stack(9, 2, m)
+    mesh = make_row_blocks_mesh(1)
     inj = FaultInjector()
     inj.schedule(SITE_PLAN_COMPILE, 0)
-    eng = SparseDNNEngine(ws, bs, batch_align=8, fault_injector=inj)
+    eng = SparseDNNEngine(ws, bs, batch_align=8, mesh=mesh, fault_injector=inj)
+    assert eng.ladder.preferred_level == LEVEL_SHARDED
     _, s0 = eng.infer(_panel(20, m, 4))
+    assert s0["failed"] is False
     assert s0["plan"]["level"] == LEVEL_LAYERED  # demoted by the fault
+    assert s0["plan"]["degraded"] is True
 
-    eng.ladder.restore(LEVEL_RESIDENT, reason="node re-slotted", step=7)
+    eng.ladder.restore(LEVEL_SHARDED, reason="node re-slotted", step=7)
     # the history records the full round trip: down, then up
     assert [(e.level, e.healthy) for e in eng.ladder.events] == [
-        (LEVEL_RESIDENT, False),
-        (LEVEL_RESIDENT, True),
+        (LEVEL_SHARDED, False),
+        (LEVEL_SHARDED, True),
     ]
     up = eng.ladder.events[-1]
     assert up.reason == "node re-slotted" and up.step == 7
-    assert eng.ladder.is_healthy(LEVEL_RESIDENT)
+    assert eng.ladder.is_healthy(LEVEL_SHARDED)
     assert not eng.ladder.degraded
     # idempotent: restoring a healthy level appends NO duplicate event
-    eng.ladder.restore(LEVEL_RESIDENT)
+    eng.ladder.restore(LEVEL_SHARDED)
     assert len(eng.ladder.events) == 2
     # the floor has no health state to restore
     with pytest.raises(ValueError, match="health"):
@@ -244,16 +220,16 @@ def test_restore_reverses_demotion_and_logs_up_transition():
 
     # the next panel re-plans at the restored level — and still matches
     # a never-degraded engine bit for bit
-    clean = SparseDNNEngine(ws, bs, batch_align=8)
+    clean = SparseDNNEngine(ws, bs, batch_align=8, mesh=mesh)
     p = _panel(21, m, 4)
     out, s1 = eng.infer(p)
     ref, _ = clean.infer(p)
-    assert s1["plan"]["level"] == LEVEL_RESIDENT
+    assert s1["plan"]["level"] == LEVEL_SHARDED
     assert s1["plan"]["degraded"] is False
     assert np.array_equal(out, ref)
     # the serve-stats surface sees the same round trip
     d = eng.ladder.describe()
-    assert d["current"] == d["preferred"] == LEVEL_RESIDENT
+    assert d["current"] == d["preferred"] == LEVEL_SHARDED
     assert [e["healthy"] for e in d["events"]] == [False, True]
 
 

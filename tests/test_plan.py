@@ -139,10 +139,12 @@ def test_cache_rejects_stale_bound_values():
 
 
 def test_route_fused_for_homogeneous_square_stack():
+    """A homogeneous square BSR stack (the stack the retired fused route
+    took) routes layered: one kernel launch a layer."""
     ws, bs = _stack(jax.random.PRNGKey(7), 3, 64)
     plan = P.build_plan(ws, bs, 8)
-    assert plan.route == P.ROUTE_FUSED
-    assert plan.pallas_calls == 1
+    assert plan.route == P.ROUTE_LAYERED
+    assert plan.pallas_calls == 3
     y0 = jax.random.uniform(jax.random.PRNGKey(8), (64, 5))
     np.testing.assert_allclose(
         np.asarray(plan.forward(y0)),
@@ -182,9 +184,7 @@ def test_relayout_applies_waste_heuristic_to_inference_plans():
     w = _skewed_ell(0)
     assert P.preferred_layout(w) == "bcsr"
     bs = [jnp.zeros((64,), jnp.float32)]
-    # the fused route would win on this square stack — force layered to
-    # exercise the per-layer waste heuristic
-    plan = P.build_plan([w], bs, 8, use_resident=False)
+    plan = P.build_plan([w], bs, 8)
     assert plan.layers[0].source_layout == "ell"
     assert plan.layers[0].layout == "bcsr"  # the lifted heuristic fired
     y0 = jax.random.uniform(jax.random.PRNGKey(13), (64, 8))
@@ -223,7 +223,7 @@ def test_repeated_layer_objects_are_relaid_once():
 
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 6))
     assert len({id(w) for w in ws}) == 2
-    plan = P.build_plan(ws, bs, 16, use_resident=False)
+    plan = P.build_plan(ws, bs, 16)
     assert plan.layouts == ("component",) * 6
     assert len({id(w) for w in plan.weights}) == 2
     assert all(plan.weights[i] is plan.weights[i % 2] for i in range(6))
@@ -237,13 +237,13 @@ def test_the_executable_takes_each_distinct_weight_once():
     from repro.data import radixnet as rx
 
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 6))
-    plan = P.build_plan(ws, bs, 16, use_resident=False)
-    assert plan.weight_args == 2
-    weights, biases, gathers = plan._bound()
+    plan = P.build_plan(ws, bs, 16)
+    assert plan.route == P.ROUTE_LAYERED and plan.weight_args == 2
+    weights, biases, gathers = plan._args
     assert [w is plan.weights[i] for i, w in enumerate(weights)] == [True, True]
     assert len(biases) == 2  # the bias in each phase's row order
     copies = [jax.tree.map(jnp.copy, w) for w in ws]  # six distinct objects
-    apart = P.build_plan(copies, bs, 16, use_resident=False)
+    apart = P.build_plan(copies, bs, 16)
     assert apart.weight_args == 6
 
     def params(p):  # the compiled program's parameters
@@ -252,74 +252,12 @@ def test_the_executable_takes_each_distinct_weight_once():
         return head.count("%arg")
 
     per_weight = len(jax.tree.leaves(weights[0]))
-    assert params(plan) == len(jax.tree.leaves(plan._bound())) + 1
+    assert params(plan) == len(jax.tree.leaves(plan._args)) + 1
     assert params(apart) - params(plan) == 4 * per_weight + 4  # weights, biases
     y0 = jnp.asarray(rx.radixnet_input_panel(256, 16, density=0.3, seed=2))
     np.testing.assert_array_equal(
         np.asarray(plan.forward(y0)), np.asarray(apart.forward(y0))
     )
-    fused = P.build_plan(ws, bs, 16, use_resident=True)
-    assert fused.is_fused_route and fused.weight_args == 1
-    auto = P.build_plan(ws, bs, 16)  # complete components: layered
-    assert auto.route == P.ROUTE_LAYERED and auto.weight_args == 2
-
-
-def test_vmem_boundary_tips_fused_into_tiled_exactly():
-    """Regression for the route boundary: the last m whose activation
-    panel exactly fills ``VMEM_SOFT_LIMIT_BYTES`` still takes the
-    resident fused route; ONE block-row more must tip into fused-tiled
-    (never layered). Asserted through the plan layer's decision tree,
-    not the kernel. The panel bill counts the six (m, block_n) stripes
-    the v5e compiler allocates, so the boundary sits at m = 4096 (at
-    6144, where four stripes put it, the compiler refused the resident
-    kernel: 18 MiB scoped VMEM against a 16 MiB limit)."""
-    from repro.kernels.fused_mlp import (
-        VMEM_SOFT_LIMIT_BYTES,
-        fused_mlp_vmem_bytes,
-    )
-
-    block = 16
-    bytes_per_row = fused_mlp_vmem_bytes(1)
-    m_res = VMEM_SOFT_LIMIT_BYTES // bytes_per_row  # last resident m
-    assert fused_mlp_vmem_bytes(m_res) == VMEM_SOFT_LIMIT_BYTES
-    assert m_res % block == 0
-
-    at, bs_at = _stack(jax.random.PRNGKey(40), 2, m_res, block=block)
-    over, bs_over = _stack(
-        jax.random.PRNGKey(41), 2, m_res + block, block=block
-    )
-    # the three-way route call is exact at the boundary
-    assert P.fused_route(at) == P.ROUTE_FUSED
-    assert P.fused_route(over) == P.ROUTE_FUSED_TILED
-    # ...and build_plan agrees: both stay single-pallas_call plans
-    plan_at = P.build_plan(at, bs_at, 8)
-    plan_over = P.build_plan(over, bs_over, 8)
-    assert plan_at.route == P.ROUTE_FUSED
-    assert plan_over.route == P.ROUTE_FUSED_TILED
-    assert plan_over.route != P.ROUTE_LAYERED
-    assert plan_at.pallas_calls == plan_over.pallas_calls == 1
-    # the over-budget stack still honours the engine's resident knob
-    # (fused family), and use_resident=False forces layered as usual
-    assert (
-        P.build_plan(over, bs_over, 8, use_resident=True).route
-        == P.ROUTE_FUSED_TILED
-    )
-    assert (
-        P.build_plan(over, bs_over, 8, use_resident=False).route
-        == P.ROUTE_LAYERED
-    )
-
-
-def test_use_resident_tristate_matches_engine_contract():
-    ws, bs = _stack(jax.random.PRNGKey(17), 2, 64)
-    assert P.build_plan(ws, bs, 8, use_resident=True).route == P.ROUTE_FUSED
-    assert P.build_plan(ws, bs, 8, use_resident=False).route == P.ROUTE_LAYERED
-    with pytest.raises(ValueError, match="not eligible"):
-        P.build_plan(
-            [BlockCSRMatrix.from_bsr(ws[0])], bs[:1], 8, use_resident=True
-        )
-    with pytest.raises(ValueError, match="VJP|eligible"):
-        P.build_plan(ws, bs, 8, differentiable=True, use_resident=True)
 
 
 # ---------------------------------------------------------------------
@@ -366,7 +304,7 @@ def test_train_loop_sorts_topology_exactly_once():
 def test_cache_shares_topology_artifacts_across_width_classes():
     """Plans for new width classes donate from an existing plan: the
     topology is sorted once no matter how many classes serve it, and
-    fused plans share one stacked weight copy."""
+    inference plans share one copy of the relaid weights."""
     ws, bs = _stack(jax.random.PRNGKey(34), 2, 32)
     mixed = [BlockCSRMatrix.from_bsr(ws[0]), ws[1]]
     cache = P.PlanCache(max_size=8)
@@ -378,8 +316,9 @@ def test_cache_shares_topology_artifacts_across_width_classes():
     assert p16.grid_steps == dnn.dnn_grid_steps(mixed, 16)  # width-local
     f8 = cache.get(ws, bs, 8)
     f16 = cache.get(ws, bs, 16)
-    assert f8.route == f16.route == P.ROUTE_FUSED
-    assert f16._stacked is f8._stacked  # one device copy per topology
+    assert f8.route == f16.route == P.ROUTE_LAYERED
+    # one device copy per topology
+    assert all(a is b for a, b in zip(f16.weights, f8.weights))
     y0 = jax.random.uniform(jax.random.PRNGKey(35), (32, 10))
     np.testing.assert_allclose(
         np.asarray(f16.forward(y0)),
@@ -387,23 +326,6 @@ def test_cache_shares_topology_artifacts_across_width_classes():
         rtol=1e-5,
         atol=1e-5,
     )
-
-
-def test_dnn_forward_resident_stays_differentiable_on_fallback():
-    """Regression: grad through dnn_forward_resident on an ineligible
-    stack with a dense layer must keep the legacy XLA-differentiable
-    fallback (the plan path would route the dense layer to the VJP-less
-    Pallas kernel)."""
-    m = 32
-    ws, bs = _stack(jax.random.PRNGKey(36), 1, m)
-    mixed = [ws[0], jax.random.normal(jax.random.PRNGKey(37), (m, m)) * 0.1]
-    bs = bs + [jnp.zeros((m,))]
-    y0 = jax.random.uniform(jax.random.PRNGKey(38), (m, 4))
-    g = jax.grad(
-        lambda y: jnp.sum(dnn.dnn_forward_resident(mixed, bs, y))
-    )(y0)
-    assert g.shape == y0.shape
-    assert float(jnp.abs(g).max()) > 0.0
 
 
 def test_planned_grads_match_legacy():

@@ -310,15 +310,24 @@ def test_continuous_beats_static_on_bursty_trace():
 
 
 def test_batcher_routes_resident_path_when_eligible():
+    """A homogeneous square stack serves layered through the batcher:
+    one kernel call per layer, at the engine's preferred level."""
     m = 64
     ws, bs = _stack(jax.random.PRNGKey(8), 3, m)
-    assert dnn.resident_eligible(ws)
     eng = SparseDNNEngine(ws, bs, batch_align=8)
     b = ContinuousBatcher(eng, batch_size=8)
-    b.submit(_col(0, m))
+    rid = b.submit(_col(0, m))
     rec = b.step(force=True)
-    assert rec.resident is True
-    assert rec.pallas_calls == 1  # the whole stack in one kernel call
+    assert rec.pallas_calls == 3  # one kernel call per layer
+    assert rec.plan_level == "layered" and not rec.degraded
+    np.testing.assert_allclose(
+        np.asarray(b.result(rid)),
+        np.asarray(
+            dnn.dnn_forward(ws, bs, _col(0, m)[:, None], fused=True)[:, 0]
+        ),
+        rtol=1e-5,
+        atol=1e-5,
+    )
 
 
 def test_batcher_layered_path_on_mixed_layout():
@@ -329,20 +338,19 @@ def test_batcher_layered_path_on_mixed_layout():
     b = ContinuousBatcher(eng, batch_size=8)
     b.submit(_col(0, m))
     rec = b.step(force=True)
-    assert rec.resident is False
     assert rec.pallas_calls == 2  # one kernel call per layer
 
 
 def test_batcher_differentiable_engine():
-    """differentiable=True engines route around the VJP-less resident
-    kernel; the batcher serves them unchanged."""
+    """differentiable=True engines keep the caller's layouts; the
+    batcher serves them unchanged."""
     m = 64
     ws, bs = _stack(jax.random.PRNGKey(10), 2, m)
     eng = SparseDNNEngine(ws, bs, batch_align=8, differentiable=True)
     b = ContinuousBatcher(eng, batch_size=8)
     rid = b.submit(_col(3, m))
     rec = b.step(force=True)
-    assert rec.resident is False
+    assert rec.pallas_calls == 2
     np.testing.assert_allclose(
         np.asarray(b.result(rid)),
         np.asarray(

@@ -259,9 +259,7 @@ def test_sharded_plan_forward_matches_reference_one_shard():
 
 def test_sharded_plan_rejects_resident_and_ell_differentiable():
     mesh = make_row_blocks_mesh(1)
-    ws, bs = _csr_stack(10, 1, 64)
-    with pytest.raises(ValueError, match="use_resident"):
-        PL.build_sharded_plan(ws, bs, 8, mesh, use_resident=True)
+    _, bs = _csr_stack(10, 1, 64)
     ell = [BlockSparseMatrix.random(
         jax.random.PRNGKey(0), (64, 64), (16, 16), blocks_per_row=2
     )]
@@ -276,8 +274,6 @@ def test_sharded_plan_rejects_resident_and_ell_differentiable():
 def test_engine_mesh_rejects_resident_and_reports_shards():
     mesh = make_row_blocks_mesh(1)
     ws, bs = _csr_stack(11, 2, 64)
-    with pytest.raises(ValueError, match="mesh"):
-        SparseDNNEngine(ws, bs, use_resident=True, mesh=mesh)
     eng = SparseDNNEngine(ws, bs, batch_align=8, mesh=mesh)
     y0 = jax.random.uniform(jax.random.PRNGKey(12), (64, 5))
     out, stats = eng.infer(y0)

@@ -182,8 +182,8 @@ def test_a_traced_engine_step_records_its_parts(log, tracing):
     assert [(r.parent, r.attrs) for r in builds] == [
         (
             "engine.plan",
-            {"width": 8, "route": stats["plan"]["route"], "component_layers": 0,
-             "weight_args": 1},
+            {"width": 8, "route": "layered", "component_layers": 0,
+             "weight_args": 2},
         )
     ]
     assert steps[0].start_ns <= builds[0].start_ns <= builds[0].end_ns <= steps[0].end_ns
@@ -193,7 +193,7 @@ def test_plan_build_counts_the_component_layers(log, tracing):
     from repro.data import radixnet as rx
 
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(256, 5))
-    eng = SparseDNNEngine(ws, bs, batch_align=8, use_resident=False)
+    eng = SparseDNNEngine(ws, bs, batch_align=8)
     eng.infer(jnp.ones((256, 4)))
     (build,) = [r for r in spans.recorded() if r.name == "plan.build"]
     assert build.attrs == {
@@ -202,15 +202,16 @@ def test_plan_build_counts_the_component_layers(log, tracing):
 
 
 def test_an_auto_resident_engine_over_components_builds_layered(log, tracing):
-    """Auto-detected residency over a 1024 x 3 RadiX-net resolves to the
-    layered level, whose plan runs the component layout."""
+    """An engine over a 1024 x 3 RadiX-net serves at the layered level,
+    whose plan runs the component layout."""
     from repro.data import radixnet as rx
 
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(1024, 3))
     eng = SparseDNNEngine(ws, bs, batch_align=8)
     assert eng.ladder.preferred_level == "layered"
     out, stats = eng.infer(jnp.ones((1024, 4)))
-    assert stats["resident"] is False and stats["plan"]["route"] == "layered"
+    assert stats["plan"]["level"] == "layered"
+    assert stats["plan"]["route"] == "layered"
     (plan,) = eng.plan_cache.plans()
     assert plan.route == "layered" and plan.component_layers == 3
     (build,) = [r for r in spans.recorded() if r.name == "plan.build"]

@@ -18,11 +18,11 @@ from jax.sharding import SingleDeviceSharding
 import repro.plan as P
 from repro.data import radixnet as rx
 from repro.kernels import bsr_spmm as ell_kernel
-from repro.kernels import fused_mlp
 from repro.kernels import ops as kernel_ops
 from repro.plan.layout import preferred_layout
 from repro.sparse.bcsr import BlockCSRMatrix
 from repro.sparse.bsr import BlockSparseMatrix
+from repro.tune import TunedConfig
 
 BS = 16  # the RadiX-net block size
 MBPR = 32  # RadiX-net butterfly phases store 32 blocks per block-row
@@ -55,14 +55,13 @@ def _s(one_chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def _ell(one_chip, neurons, layers=None):
-    """An ELL weight (or an L-stack of them) as shapes on the chip."""
+def _ell(one_chip, neurons):
+    """An ELL weight as shapes on the chip."""
     nrb = neurons // BS
-    lead = () if layers is None else (layers,)
     return BlockSparseMatrix(
-        _s(one_chip, lead + (nrb, MBPR, BS, BS)),
-        _s(one_chip, lead + (nrb, MBPR), jnp.int32),
-        _s(one_chip, lead + (nrb, MBPR), jnp.int32),
+        _s(one_chip, (nrb, MBPR, BS, BS)),
+        _s(one_chip, (nrb, MBPR), jnp.int32),
+        _s(one_chip, (nrb, MBPR), jnp.int32),
         (neurons, neurons),
         (BS, BS),
     )
@@ -84,12 +83,16 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _fused_args(one_chip, neurons, layers):
-    return (
-        _ell(one_chip, neurons, layers),
-        _s(one_chip, (layers, neurons)),
-        _s(one_chip, (neurons, WIDTH)),
-    )
+def _compiled_plan_text(one_chip, monkeypatch, plan) -> str:
+    """The plan's whole executable, row gathers included, compiled for
+    one chip with its own arguments (each distinct weight once)."""
+    # the executable calls the kernels with the backend's own choice
+    monkeypatch.setattr(kernel_ops, "auto_interpret", lambda: False)
+    args = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), plan._args)
+    y = _s(one_chip, (plan.weights[0].shape[1], plan.width))
+    text = plan._fn.lower(*args, y).compile().as_text()
+    jax.clear_caches()  # drop the kernels traced for the chip
+    return text
 
 
 # ---------------------------------------------------------------------
@@ -127,42 +130,37 @@ def test_bsr_spmm_compiles_at_4096(one_chip):
 
 
 @pytest.mark.parametrize(
-    "neurons,panel_dtype",
-    [(4096, None), (8192, "bfloat16")],
-    ids=["4096-f32", "8192-bf16"],
+    "neurons", [4096, 8192], ids=["4096-f32", "8192-bf16"]
 )
 def test_fused_mlp_forward_compiles_at_vmem_budget(
-    one_chip, neurons, panel_dtype
+    one_chip, monkeypatch, neurons
 ):
-    """The largest resident panels: 4096 f32 and 8192 bf16 both fill
-    ``VMEM_SOFT_LIMIT_BYTES`` exactly (six stripes, as allocated)."""
-    assert (
-        fused_mlp.fused_mlp_vmem_bytes(neurons, panel_dtype=panel_dtype)
-        == fused_mlp.VMEM_SOFT_LIMIT_BYTES
-    )
-    layers = [_ell(one_chip, neurons)] * 2
-    assert P.fused_route(layers, panel_dtype=panel_dtype) == P.ROUTE_FUSED
-    text = _compiled_text(
-        lambda w, b, y: kernel_ops.fused_mlp_forward(
-            w, b, y, interpret=False, panel_dtype=panel_dtype
-        ),
-        *_fused_args(one_chip, neurons, 2),
-    )
-    assert "tpu_custom_call" in text
+    """Two-layer RadiX-net stacks in the caller's 16×16 ELL blocks
+    (32 slots a block-row, the widths that once filled the whole-stack
+    kernel's VMEM budget): the layered plan runs one ELL kernel a layer,
+    and its executable compiles for one chip."""
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, 2))
+    plan = P.build_plan(ws, bs, WIDTH, relayout=False)
+    assert plan.route == P.ROUTE_LAYERED
+    assert [lp.path for lp in plan.layers] == ["kernel-ell"] * 2
+    assert {w.max_blocks_per_row for w in plan.weights} == {MBPR}
+    text = _compiled_plan_text(one_chip, monkeypatch, plan)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
-def test_fused_mlp_tiled_forward_compiles_at_16384(one_chip):
-    """The challenge bench cell (16384 × 6): past the VMEM budget, and
-    its slot table fits SMEM."""
-    layers = [_ell(one_chip, 16384)] * 6
-    assert P.fused_route(layers) == P.ROUTE_FUSED_TILED
-    text = _compiled_text(
-        lambda w, b, y: kernel_ops.fused_mlp_tiled_forward(
-            w, b, y, interpret=False
-        ),
-        *_fused_args(one_chip, 16384, 6),
-    )
-    assert "tpu_custom_call" in text
+def test_fused_mlp_tiled_forward_compiles_at_16384(one_chip, monkeypatch):
+    """RadiX-net 16384 × 6 in 16×16 block-CSR (a tuned layout keeps the
+    component layout off; ELL's prefetch tables overflow SMEM at 1024
+    block-rows): the layered plan runs one block-CSR kernel a layer, and
+    its executable compiles for one chip."""
+    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(16384, 6))
+    plan = P.build_plan(ws, bs, WIDTH, tuned=TunedConfig(layout="bcsr"))
+    assert plan.route == P.ROUTE_LAYERED
+    assert [lp.path for lp in plan.layers] == ["kernel-bcsr"] * 6
+    assert plan.component_layers == 0 and plan.weight_args == 3
+    assert {w.block_shape for w in plan.weights} == {(BS, BS)}
+    text = _compiled_plan_text(one_chip, monkeypatch, plan)
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
 
 
 def test_semiring_matmul_compiles(one_chip):
@@ -179,18 +177,14 @@ def test_semiring_matmul_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def _radix_plan_compiles(
-    one_chip, monkeypatch, neurons, blocks, grid_steps, fused=None
-):
+def _radix_plan_compiles(one_chip, monkeypatch, neurons, blocks, grid_steps):
     """The engine's plan of the official ``neurons`` × 120 stack routes it
     layered on block-CSR kernels in the component layout, each distinct
     phase matrix re-laid once as ``blocks`` blocks of 1024×32 (32 whole
     32×32 components a block-row, one entry per row a block); the layer
     kernel at the challenge width and the whole executable, gathers
-    included, compile for one chip. ``fused``: the fused route the stack
-    also has (None where its slot table overflows SMEM)."""
+    included, compile for one chip."""
     ws, bs = rx.radixnet_weights(rx.RadixNetSpec(neurons, 120))
-    assert P.fused_route(ws) == fused
     plan = P.build_plan(ws, bs, WIDTH)
     assert plan.route == P.ROUTE_LAYERED
     assert {lp.path for lp in plan.layers} == {"kernel-bcsr"}
@@ -217,27 +211,21 @@ def _radix_plan_compiles(
         .as_text()
     )
     assert "tpu_custom_call" in text
-    # the executable calls the kernels with the backend's own choice
-    monkeypatch.setattr(kernel_ops, "auto_interpret", lambda: False)
-    bound = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), plan._bound())
-    y = _s(one_chip, (neurons, WIDTH))
-    text = plan._fn.lower(*bound, y).compile().as_text()
-    jax.clear_caches()  # drop the kernels traced for the chip
+    text = _compiled_plan_text(one_chip, monkeypatch, plan)
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 120
     assert "gather" in text
 
 
 def test_radix_1024x120_plan_layer_kernels_compile(one_chip, monkeypatch):
     """1024 × 120: two phases of 32 components, one block-row of 32
-    blocks each. The fused kernel could run it (983,040 grid steps a
-    512-wide panel over its 16×16 slots); the component layout runs
-    15,360."""
-    _radix_plan_compiles(one_chip, monkeypatch, 1024, 32, 15_360, P.ROUTE_FUSED)
+    blocks each: 15,360 grid steps a 512-wide panel (its 16×16 slots
+    would run 983,040)."""
+    _radix_plan_compiles(one_chip, monkeypatch, 1024, 32, 15_360)
 
 
 def test_radix_16384x120_plan_layer_kernels_compile(one_chip, monkeypatch):
-    """16384 × 120: three phases of 512 components (the fused slot table
-    and the ELL prefetch tables overflow SMEM)."""
+    """16384 × 120: three phases of 512 components (the ELL prefetch
+    tables overflow SMEM)."""
     _radix_plan_compiles(one_chip, monkeypatch, 16384, 512, 245_760)
 
 
@@ -255,46 +243,11 @@ def test_radix_65536x120_plan_layer_kernels_compile(one_chip, monkeypatch):
 # ---------------------------------------------------------------------
 
 
-def test_fused_slot_table_guard_4096x120(one_chip):
-    """4096 × 120 fits VMEM, but its fused slot table (3.75 MiB) does not
-    fit the 1 MiB SMEM: the compiler refuses the fused kernel, so the
-    route and the plan go layered."""
-    layers = [_ell(one_chip, 4096)] * 120
-    assert fused_mlp.fused_mlp_eligible(layers[0])  # VMEM alone would admit it
-    assert P.fused_route(layers) is None
-    with pytest.raises(Exception, match="(?i)smem"):
-        _compiled_text(
-            lambda w, b, y: kernel_ops.fused_mlp_forward(
-                w, b, y, interpret=False
-            ),
-            *_fused_args(one_chip, 4096, 120),
-        )
-    ws, bs = rx.radixnet_weights(rx.RadixNetSpec(4096, 120))
-    plan = P.build_plan(ws, bs, WIDTH)
-    assert plan.route == P.ROUTE_LAYERED
-    assert not plan.is_fused_route
-
-
-@pytest.mark.parametrize(
-    "layers,fits", [(7, True), (8, False)], ids=["7-fits", "8-refused"]
-)
-def test_fused_slot_table_guard_is_exact(one_chip, layers, fits):
-    """At 16384 neurons a layer adds 128 KiB of slot table: seven layers
-    fit SMEM and compile on the tiled route; the eighth tips the stack
-    off the fused routes, and the compiler refuses it."""
-    stack = [_ell(one_chip, 16384)] * layers
-    args = _fused_args(one_chip, 16384, layers)
-
-    def tiled(w, b, y):
-        return kernel_ops.fused_mlp_tiled_forward(w, b, y, interpret=False)
-
-    if fits:
-        assert P.fused_route(stack) == P.ROUTE_FUSED_TILED
-        assert "tpu_custom_call" in _compiled_text(tiled, *args)
-    else:
-        assert P.fused_route(stack) is None
-        with pytest.raises(Exception, match="(?i)smem"):
-            _compiled_text(tiled, *args)
+def test_fused_slot_table_guard_4096x120(one_chip, monkeypatch):
+    """4096 × 120 (whose 16×16 slot table, 3.75 MiB, would overflow the
+    1 MiB SMEM as one table) routes layered in the component layout,
+    and its kernels compile."""
+    _radix_plan_compiles(one_chip, monkeypatch, 4096, 128, 61_440)
 
 
 @pytest.mark.parametrize(
@@ -322,9 +275,7 @@ def test_ell_prefetch_guard(one_chip, neurons, fits):
     w16k = ws[1]
     assert bool(w16k.block_mask.all())
     assert preferred_layout(w16k) == "bcsr"
-    plan = P.build_plan(
-        [w16k], [jnp.zeros((16384,))], WIDTH, use_resident=False
-    )
+    plan = P.build_plan([w16k], [jnp.zeros((16384,))], WIDTH)
     assert [lp.path for lp in plan.layers] == ["kernel-bcsr"]
 
 

@@ -46,7 +46,6 @@ DEFAULT_BASELINE = (
 # Per-section generator parameters: a section is only compared when ALL
 # of these match between baseline and fresh artifact.
 PARAMS = {
-    "fused": ("m", "layers", "blocks_per_row", "n"),
     "train": ("m", "layers", "block", "blocks_per_row", "n"),
     "serve": (
         "m",
@@ -118,22 +117,15 @@ PARAMS = {
     ),
 }
 
-EXACT = {
-    "fused": (
-        "pallas_calls_fused",
-        "pallas_calls_layered",
-        "hbm_activation_roundtrips_eliminated",
-    ),
-    "train": (
-        "pallas_calls_per_step",
-        "pallas_calls_forward_only",
-        "grid_steps_forward",
-        "grid_steps_backward_kernel",
-        "layout_per_layer",
-        "weight_cotangent_pattern_preserved",
-        "loss_decreased",
-    ),
-}
+TRAIN_EXACT = (
+    "pallas_calls_per_step",
+    "pallas_calls_forward_only",
+    "grid_steps_forward",
+    "grid_steps_backward_kernel",
+    "layout_per_layer",
+    "weight_cotangent_pattern_preserved",
+    "loss_decreased",
+)
 # Plan arm (compiled execution plans): deterministic accounting checked
 # exactly, wall-clocks tolerantly, and the headline amortization gated.
 PLAN_SERVE_EXACT = (
@@ -260,16 +252,6 @@ TUNE_SKEWED_EXACT = (
     "block_work_tuned",
     "block_work_default",
     "accuracy_ok",
-)
-TUNE_RADIX_EXACT = (
-    "winner",
-    "route_default",
-    "route_tuned",
-    "grid_steps_default",
-    "grid_steps_tuned",
-    "vmem_bytes_f32",
-    "vmem_bytes_bf16",
-    "vmem_soft_limit",
 )
 # Fleet arm (replicated serving on a virtual clock): every curve point
 # is a pure function of the generator config — latencies, miss rates,
@@ -422,26 +404,24 @@ def check(baseline: dict, fresh: dict, tol: float) -> Gate:
                 ft["xla_time_s"][arm],
             )
 
-    # --- fused / train: exact counts when the generator params match ---
-    for section in ("fused", "train"):
-        pair = _section_pair(gate, section, baseline, fresh)
-        if pair is None:
-            continue
+    # --- train: exact counts when the generator params match ----------
+    pair = _section_pair(gate, "train", baseline, fresh)
+    if pair is not None:
         bs, fs = pair
-        for field in EXACT[section]:
+        for field in TRAIN_EXACT:
             if field not in bs:
-                gate.skip(section, f"{field} absent from baseline")
+                gate.skip("train", f"{field} absent from baseline")
                 continue
             if field not in fs:
-                gate.missing(section, field)
+                gate.missing("train", field)
                 continue
-            gate.exact(section, field, bs[field], fs[field])
+            gate.exact("train", field, bs[field], fs[field])
         for field, bt in bs.get("xla_time_s", {}).items():
             ft = fs.get("xla_time_s", {}).get(field)
             if ft is None:
-                gate.missing(section, f"xla_time_s.{field}")
+                gate.missing("train", f"xla_time_s.{field}")
                 continue
-            gate.time(section, f"xla_time_s.{field}", bt, ft)
+            gate.time("train", f"xla_time_s.{field}", bt, ft)
 
     # --- plan: compiled-plan amortization (exact) + wall-clocks -------
     pair = _section_pair(gate, "plan", baseline, fresh)
@@ -641,25 +621,19 @@ def check(baseline: dict, fresh: dict, tol: float) -> Gate:
     pair = _section_pair(gate, "tune", baseline, fresh)
     if pair is not None:
         bs, fs = pair
-        for sub, fields in (
-            ("skewed", TUNE_SKEWED_EXACT),
-            ("radix", TUNE_RADIX_EXACT),
-        ):
-            bsub, fsub = bs.get(sub, {}), fs.get(sub, {})
-            for field in fields:
-                if field not in bsub:
-                    gate.skip("tune", f"{sub}.{field} absent from baseline")
-                    continue
-                if field not in fsub:
-                    gate.missing("tune", f"{sub}.{field}")
-                    continue
-                gate.exact("tune", f"{sub}.{field}", bsub[field], fsub[field])
-        # headline invariants, gated regardless of baseline drift: the
+        bsub, fsub = bs.get("skewed", {}), fs.get("skewed", {})
+        for field in TUNE_SKEWED_EXACT:
+            if field not in bsub:
+                gate.skip("tune", f"skewed.{field} absent from baseline")
+                continue
+            if field not in fsub:
+                gate.missing("tune", f"skewed.{field}")
+                continue
+            gate.exact("tune", f"skewed.{field}", bsub[field], fsub[field])
+        # headline invariant, gated regardless of baseline drift: the
         # tuned config must STRICTLY beat the default's grid-step bill
-        # on the skewed stack, bf16 panels must move the RadiX-net
-        # stack across the resident boundary, and the bf16 numerics
-        # must hold on the challenge-shaped probe.
-        sk, rad = fs.get("skewed", {}), fs.get("radix", {})
+        # on the skewed stack.
+        sk = fsub
         won = (
             sk.get("grid_steps_tuned", 1 << 62)
             < sk.get("grid_steps_default", 0)
@@ -671,36 +645,10 @@ def check(baseline: dict, fresh: dict, tol: float) -> Gate:
             won,
             "ok" if won else "FAIL",
         )
-        moved = (
-            rad.get("route_default") == "fused-tiled"
-            and rad.get("route_tuned") == "fused"
-        )
-        gate._add(
-            "tune",
-            "radix.bf16_moves_resident_boundary",
-            True,
-            moved,
-            "ok" if moved else "FAIL",
-        )
-        err = rad.get("bf16_max_abs_err")
-        err_ok = err is not None and err <= 0.05
-        gate._add(
-            "tune",
-            "radix.bf16_max_abs_err<=0.05",
-            True,
-            err_ok,
-            "ok" if err_ok else "FAIL",
-        )
-        for sub, field in (
-            ("skewed", "wall_s_tuned"),
-            ("skewed", "wall_s_default"),
-            ("radix", "wall_s_f32_tiled"),
-            ("radix", "wall_s_bf16_tiled"),
-        ):
-            wt_b = bs.get(sub, {}).get(field)
-            wt_f = fs.get(sub, {}).get(field)
+        for field in ("wall_s_tuned", "wall_s_default"):
+            wt_b, wt_f = bsub.get(field), fsub.get(field)
             if wt_b is not None and wt_f is not None:
-                gate.time("tune", f"{sub}.{field}", wt_b, wt_f)
+                gate.time("tune", f"skewed.{field}", wt_b, wt_f)
 
     # --- fleet: replicated-serving curves exact, headlines gated ------
     pair = _section_pair(gate, "fleet", baseline, fresh)
@@ -764,10 +712,6 @@ def check(baseline: dict, fresh: dict, tol: float) -> Gate:
     pair = _section_pair(gate, "serve", baseline, fresh)
     if pair is not None:
         bs, fs = pair
-        gate.exact(
-            "serve", "resident_path_used",
-            bs["resident_path_used"], fs["resident_path_used"],
-        )
         for arm in ("static", "continuous"):
             for field in SERVE_EXACT:
                 gate.exact(
