@@ -15,22 +15,27 @@ A traffic file names its loop:
   the return of the step that served it.
 
 Inputs are seeded {0, 1} columns at the mix's ``density``, made by the
-benchmark's own generator. After the window a sample of the answers,
-drawn from the seed, is compared with the benchmark's own reference.
+benchmark's own generator. A sample of the answers, drawn from the seed,
+is compared with the benchmark's own reference after the window. The
+closed loop draws it as panels complete and keeps it on the device at a
+fixed size (``chipbench/yardstick/reservoir.py``), so the window holds
+no panel's output past its step, however many panels it runs.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
-from typing import Any, Callable
+from typing import Any
 
 import numpy as np
 
 from chipbench.yardstick import arrivals as _arrivals
 from chipbench.yardstick import radixnet as _yr
 from chipbench.yardstick.check import UNREADABLE, compare_columns
+from chipbench.yardstick.reservoir import Reservoir
 
 
 def span(name: str):
@@ -104,13 +109,42 @@ def _sample(n_done: int, k: int, seed: int) -> np.ndarray:
 # closed loop: the challenge stream
 
 
+@functools.cache
+def _answer():
+    """``(panel, kept, plan) -> (activity mask, kept')``: the panel's
+    per-input activity mask, and the kept sample (donated) with the
+    panel's admitted columns written in. ``plan`` is ``(width + 1, 2)``
+    int32: row 0 holds the number ``n`` of admitted columns, rows 1 to
+    ``n`` each a (panel column, slot) pair; the rest is padding."""
+    import jax
+    from jax import lax
+
+    def answer(out, kept, plan):
+        def put(r, kept):
+            col = lax.dynamic_slice_in_dim(out, plan[r, 0], 1, axis=1)
+            return lax.dynamic_update_slice_in_dim(kept, col, plan[r, 1], axis=1)
+
+        return (out > 0).any(axis=0), lax.fori_loop(1, plan[0, 0] + 1, put, kept)
+
+    return jax.jit(answer, donate_argnums=1)
+
+
+def _plan(cols: np.ndarray, slots: np.ndarray, width: int) -> np.ndarray:
+    plan = np.zeros((width + 1, 2), np.int32)
+    plan[0, 0] = len(cols)
+    plan[1 : len(cols) + 1, 0] = cols
+    plan[1 : len(cols) + 1, 1] = slots
+    return plan
+
+
 @dataclasses.dataclass
 class ClosedState:
     cell: Any
     engine: Any
     pool: np.ndarray  # (neurons, pool_inputs) host inputs
     panels: list  # the pool as device panels
-    active: Callable  # panel -> per-input activity mask
+    sample: Reservoir  # which completed inputs the check reads, from the seed
+    kept: Any  # (neurons, sample_inputs) device answers of the sample's slots
     phases: dict = dataclasses.field(default_factory=dict)  # set-up seconds by phase
 
     def describe(self) -> str:
@@ -126,16 +160,19 @@ class Closed:
     @staticmethod
     def prepare(cell, engine, *, seed: int, seconds: float) -> ClosedState:
         import jax
+        import jax.numpy as jnp
 
         t = cell.traffic
-        w = t["panel_width"]
+        w, k = t["panel_width"], t["sample_inputs"]
+        if t["pool_inputs"] % w:
+            raise ValueError(f"traffic {t['name']!r}: the pool is not whole panels")
         pool = _yr.radixnet_input_panel(
             cell.config["neurons"], t["pool_inputs"], density=t["density"], seed=seed
         )
         panels = [jax.device_put(pool[:, s : s + w]) for s in range(0, pool.shape[1], w)]
-        jax.block_until_ready(panels)
-        active = jax.jit(lambda y: (y > 0).any(axis=0))
-        return ClosedState(cell, engine, pool, panels, active)
+        kept = jnp.zeros((cell.config["neurons"], k), jnp.float32)
+        jax.block_until_ready((panels, kept))
+        return ClosedState(cell, engine, pool, panels, Reservoir(k, seed), kept)
 
     @staticmethod
     def warm(state: ClosedState) -> None:
@@ -145,8 +182,9 @@ class Closed:
         w = state.cell.traffic["panel_width"]
         state.engine.submit(state.panels[0])
         out, _ = state.engine.step(pad_to=w)
-        if out is not None:
-            np.asarray(state.active(out))
+        if out is not None:  # admits nothing: the sample is the window's
+            mask, state.kept = _answer()(out, state.kept, _plan([], [], w))
+            np.asarray(mask)
 
     @classmethod
     def setup(cls, cell, *, seed, seconds, wrap_engine=None):
@@ -164,15 +202,20 @@ class Closed:
 
     @staticmethod
     def window(state: ClosedState, *, seconds: float) -> Window:
+        """The closed loop for ``seconds``; it takes the state's sample
+        and hands it to the check in ``Window.answers``."""
         cfg, t = state.cell.config, state.cell.traffic
         w = t["panel_width"]
         n_pool = len(state.panels)
-        outs, masks, panel_s = [], [], []
+        answer = _answer()
+        sample, kept = state.sample, state.kept
+        state.sample = state.kept = None
+        panel_s = []
         failed = 0
         t0 = time.perf_counter()
         with span("window"):
             while True:
-                p = len(outs)
+                p = len(panel_s)
                 ts = time.perf_counter()
                 with span("panel"):
                     state.engine.submit(state.panels[p % n_pool])
@@ -180,51 +223,44 @@ class Closed:
                         out, stats = state.engine.step(pad_to=w)
                     if out is None or stats["failed"]:
                         failed += w
-                        out = mask = None
                     else:
                         with span("mask_readback"):
-                            mask = np.asarray(state.active(out))
-                outs.append(out)
-                masks.append(mask)
+                            cols, slots = sample.draw(w)
+                            mask, kept = answer(out, kept, _plan(cols, slots, w))
+                            mask = np.asarray(mask)
+                        sample.admit(cols, slots, (p % n_pool) * w, mask)
+                    out = None
                 now = time.perf_counter()
                 panel_s.append(now - ts)
                 if now - t0 >= seconds:
                     break
         elapsed = now - t0
-        done = sum(w for o in outs if o is not None)
+        done = sample.seen
         rate = _yr.FAN_IN * cfg["neurons"] * cfg["layers"] * done / elapsed
         ps = sorted(panel_s)
         return Window(
             seconds=elapsed,
-            attempted=len(outs) * w,
+            attempted=len(panel_s) * w,
             failed=failed,
             end_to_end={"edge_inputs_per_s": rate},
-            counters={"inputs": done, "panels": len(outs), "panel_width": w},
-            answers=(outs, masks),
+            counters={"inputs": done, "panels": len(panel_s), "panel_width": w},
+            answers=(sample, kept),
             note=(
-                f"{len(outs)} panels; panel seconds min {ps[0]:.6f} median "
+                f"{len(panel_s)} panels; panel seconds min {ps[0]:.6f} median "
                 f"{ps[len(ps) // 2]:.6f} max {ps[-1]:.6f}"
             ),
         )
 
     @staticmethod
     def sampled(state: ClosedState, win: Window, *, seed: int):
-        """(program outputs, program masks, inputs) of the seeded sample."""
-        outs, masks = win.answers
-        w = state.cell.traffic["panel_width"]
-        n_pool = len(state.panels)
-        done = [p for p, o in enumerate(outs) if o is not None]
-        pick = _sample(len(done) * w, state.cell.traffic["sample_inputs"], seed)
-        by_panel: dict[int, list[int]] = {}
-        for g in pick:
-            by_panel.setdefault(done[g // w], []).append(int(g % w))
-        neurons = state.cell.config["neurons"]
-        ys, ms, cols = [np.zeros((neurons, 0), np.float32)], [np.zeros(0, bool)], []
-        for p, js in by_panel.items():
-            ys.append(np.asarray(outs[p])[:, js])
-            ms.append(masks[p][js])
-            cols.extend((p % n_pool) * w + j for j in js)
-        return np.concatenate(ys, axis=1), np.concatenate(ms), state.pool[:, cols]
+        """(program outputs, program masks, inputs) of the sample the
+        window kept, drawn from the seed ``prepare`` was given."""
+        import jax
+
+        sample, kept = win.answers
+        n = sample.filled
+        y = np.asarray(jax.device_get(kept))[:, :n]
+        return y, sample.masks[:n], state.pool[:, sample.cols[:n]]
 
     @classmethod
     def check(cls, state: ClosedState, win: Window, *, seed: int, say) -> dict:

@@ -14,10 +14,11 @@ CELLS = ["challenge-16384x120", "challenge-1024x120", "serve-1024x120-open"]
 
 
 class Broken:
-    """The program's engine with its step's answers changed by ``fault``."""
+    """The program's engine with its step's answers changed by ``fault``,
+    which is also told how many steps the engine has run."""
 
     def __init__(self, engine, fault):
-        self._engine, self._fault = engine, fault
+        self._engine, self._fault, self._steps = engine, fault, 0
 
     def __getattr__(self, name):
         return getattr(self._engine, name)
@@ -27,20 +28,28 @@ class Broken:
         out, stats = self._engine.step(*args, **kwargs)
         if out is None:
             return out, stats
-        return self._fault(out, staged, stats)
+        self._steps += 1
+        return self._fault(out, staged, stats, step=self._steps)
 
 
-def altered(out, staged, stats):  # each answer handed to its neighbour's request
+def altered(out, staged, stats, step):  # each answer handed to its neighbour's request
     pairs = jnp.arange(out.shape[1]) ^ 1
     return out[:, jnp.minimum(pairs, out.shape[1] - 1)], stats
 
 
-def half_left_out(out, staged, stats):  # the back half of the batch never computed
+def half_left_out(out, staged, stats, step):  # the back half of the batch never computed
     return out.at[:, out.shape[1] // 2 :].set(0.0), stats
 
 
-def lost(out, staged, stats):  # the step fails and its answers never come
+def lost(out, staged, stats, step):  # the step fails and its answers never come
     return None, dict(stats, failed=True, error="planted")
+
+
+def late(out, staged, stats, step):
+    """Answers altered only after the first four steps (the warm-up's and
+    the window's first: more inputs than a run samples), so a sample
+    that stopped early in the window would find nothing wrong."""
+    return altered(out, staged, stats, step) if step > 4 else (out, stats)
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -52,7 +61,7 @@ def test_program_is_correct(name):
 
 
 @pytest.mark.parametrize("name", CELLS)
-@pytest.mark.parametrize("fault", ["altered", "half_left_out", "lost"])
+@pytest.mark.parametrize("fault", ["altered", "half_left_out", "lost", "late"])
 def test_broken_timed_path_is_not_correct(name, fault):
     plant = globals()[fault]
     res = run(tiny_cell(name), wrap_engine=lambda engine: Broken(engine, plant))
